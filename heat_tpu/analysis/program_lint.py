@@ -79,13 +79,16 @@ _HLO_COLLECTIVES: Dict[str, Tuple[str, ...]] = {
 }
 
 #: matches an HLO instruction *definition* of a collective, capturing the
-#: result shape, the op kind and the first operand shape, e.g.
-#: ``%all-gather = f32[32,4]{1,0} all-gather(f32[4,4]{1,0} %param), ...``
+#: result shape, the op kind and the first operand: its shape where the
+#: printer writes it inline, else its name (resolved by
+#: :func:`_operand_shape`), e.g.
+#: ``%all-gather = f32[32,4]{1,0} all-gather(f32[4,4]{1,0} %param), ...`` or
+#: ``%all-gather = f32[32,4]{1,0} all-gather(%fusion), ...``
 _COLLECTIVE_DEF = re.compile(
     r"=\s+(?:\([^)]*\)|(?P<rtype>\w+)\[(?P<rshape>[0-9,]*)\])\S*\s+"
     r"(?P<op>all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
     r"(?:-start)?\("
-    r"(?:\s*(?:\w+)\[(?P<oshape>[0-9,]*)\])?"
+    r"\s*(?:\w+\[(?P<oshape>[0-9,]*)\]\S*\s*)?(?P<oname>%[\w.\-]+)?"
 )
 
 _DIMENSIONS = re.compile(r"dimensions=\{(\d+)\}")
@@ -101,6 +104,21 @@ def _parse_shape(s: Optional[str]) -> Tuple[int, ...]:
     if not s:
         return ()
     return tuple(int(x) for x in s.split(",") if x)
+
+
+def _operand_shape(text: str, m: "re.Match") -> Tuple[int, ...]:
+    """First-operand shape of the collective matched by ``m``: the
+    inline shape, or — the HLO printer names operands without their
+    shape — the shape in the operand's own definition, the nearest one
+    above the use (names repeat across computations)."""
+    if m.group("oshape") is not None:
+        return _parse_shape(m.group("oshape"))
+    if not m.group("oname"):
+        return ()
+    defs = re.findall(
+        re.escape(m.group("oname")) + r"\s+=\s+\w+\[([0-9,]*)\]", text[: m.start()]
+    )
+    return _parse_shape(defs[-1]) if defs else ()
 
 
 def _comm_calls_snapshot() -> Dict[str, float]:
@@ -154,7 +172,7 @@ def analyze_compiled_text(
         found[op] = found.get(op, 0) + 1
         if op == "all-gather" and n_participants > 1:
             rshape = _parse_shape(m.group("rshape"))
-            oshape = _parse_shape(m.group("oshape"))
+            oshape = _operand_shape(text, m)
             dim_m = _DIMENSIONS.search(text, m.end(), m.end() + 400)
             dim = int(dim_m.group(1)) if dim_m else 0
             if (

@@ -103,7 +103,7 @@ class _KCluster(BaseEstimator, ClusteringMixin):
     def labels_(self) -> DNDarray:
         return self._labels
 
-    # fits store device scalars so fit() never blocks on the link; the
+    # fits store device scalars so fit() never blocks on a device->host sync; the
     # host conversion happens (once) on first access
     inertia_ = lazy_scalar_property("_inertia", float)
     n_iter_ = lazy_scalar_property("_n_iter", int)

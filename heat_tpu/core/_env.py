@@ -62,7 +62,6 @@ KNOBS = {
     "HEAT_TPU_J202_THRESHOLD": ("int", "1024", "reduced-extent threshold of the J202 low-precision-accumulation rule: a bf16/f16 reduction or scan over this many elements or more without f32 accumulation is flagged"),
     "HEAT_TPU_HBM_BUDGET_BYTES": ("int", "0", "per-device HBM budget for the static peak-memory estimator: a freshly compiled program whose predicted per-device peak exceeds this many bytes emits J301 (0 = budget check off)"),
     "HEAT_TPU_PREDICT_DTYPE": ("choice", "", "low-precision predict compute dtype for tolerance-policy estimators (bfloat16; empty = native float32); kinds whose POLICIES entry is bitwise or does not list the dtype keep serving native and emit one J204"),
-    "HEAT_TPU_COMPAT_FORCE": ("choice", "", "force one branch of the core/_compat.py jax-API resolver: 'legacy' uses the jax.experimental shard_map adapter even when jax.shard_map exists, 'native' requires the top-level API; empty = auto-detect (the compat-matrix CI lane sets this)"),
     "HEAT_TPU_PROTOCOL_CHECK": ("choice", "0", "runtime conformance of journal events against the declared control-plane protocols (analysis/protocols.py): 0 = off (one global read per emit), 1 = warn (H805 diagnostic + protocol:<actor> alert per illegal transition), raise = ProgramLintError at the offending emit site"),
     "HEAT_TPU_MODEL_CHECK_STATES": ("int", "200000", "bounded-model-checker state budget: the product state-space exploration of python -m heat_tpu.analysis.model_check aborts past this many distinct states"),
     # -- telemetry (heat_tpu/telemetry, docs/observability.md) ----------
@@ -179,11 +178,10 @@ KNOBS = {
     "HEAT_TPU_HSVD_PRECISION": ("choice", "high", "hsvd Gram-pass matmul precision: default | high | highest"),
     "HEAT_TPU_HSVD_SYRK": ("bool", "1", "one-HBM-read syrk kernel for hsvd Gram passes when supported"),
     "HEAT_TPU_HSVD_BATCHED": ("bool", "0", "opt-in batched (vmapped) leaf factorizations in the hsvd merge tree: one stacked gram+eigh over the equal-shape leaf blocks instead of the sequential per-leaf loop (the 'can't fuse eigh' A/B, scripts/bench.py hsvd)"),
-    "HEAT_TPU_COMPLEX": ("bool", "", "override the complex-on-TPU support probe (unset = probe per device kind)"),
     # -- sparse (heat_tpu/sparse) ---------------------------------------
     "HEAT_TPU_SPGEMM_DENSE_DENSITY": ("float", "0.5", "estimated-output-density threshold at which sparse@sparse matmul falls back from the output-sparse triplet ring to the GEMM-style dense route (estimate: 1 - exp(-nnz_A*nnz_B/(m*k*n)); 1.0 = always ring, 0.0 = always dense)"),
     # -- fft (docs/fft_roofline.md) -------------------------------------
-    "HEAT_TPU_PLANAR": ("bool", "", "planar (re, im) complex representation (unset = auto: TPU without complex support)"),
+    "HEAT_TPU_PLANAR": ("bool", "0", "planar (re, im) FFT engine: 1 = transforms run on two real planes (the leading-contraction engine and its Pallas stage kernels); 0 = native complex through jnp.fft"),
     "HEAT_TPU_FFT_PRECISION": ("choice", "highest", "FFT matmul precision: default | high | highest"),
     "HEAT_TPU_FFT_CUTOFF": ("int", "64", "extent cutoff below which planar FFT uses the direct DFT matmul"),
     "HEAT_TPU_FFT_DIRECT_CAP": ("int", "1024", "largest extent the direct DFT path may handle"),
@@ -195,7 +193,6 @@ KNOBS = {
     "HEAT_TPU_FFT_LEADING": ("bool", "1", "leading-axis (split-axis) FFT path"),
     # -- test / CI harness ----------------------------------------------
     "HEAT_TPU_TEST_DEVICES": ("int", "8", "virtual CPU mesh size the test suite forces (tests/conftest.py)"),
-    "HEAT_TPU_COMPILE_CACHE": ("path", "tests/.jax_cache", "persistent XLA compilation cache directory for the test suite (0 = off)"),
 }
 
 _FALSE_WORDS = ("0", "false", "no", "off")

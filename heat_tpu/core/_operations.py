@@ -64,9 +64,9 @@ def _out_split_binary(t1: DNDarray, t2: DNDarray, out_shape) -> Optional[int]:
 
 # ----------------------------------------------------------------------
 # planar (re, im) fast paths — keep complex chains like fftn(x)*H ->
-# ifftn on the mesh instead of silently round-tripping through the host
-# between every op on complex-less runtimes (VERDICT r3 #7).  The full
-# plane-preservation inventory lives in docs/planar_ops.md.
+# ifftn on the planes instead of composing a complex array between
+# every op (VERDICT r3 #7).  The full plane-preservation inventory
+# lives in docs/planar_ops.md.
 # ----------------------------------------------------------------------
 def _planar_rule(operation) -> Optional[str]:
     if operation is jnp.add or operation is jnp.subtract:
@@ -278,9 +278,9 @@ def __binary_op(
 
 def _fusable(*operands: DNDarray) -> bool:
     """Whether these operands may ride the lazy fusion path: fusion on,
-    no planar storage, no complex dtypes (complex arrays can be
-    host-backed on complex-less runtimes — their placement logic must
-    not be bypassed)."""
+    no planar storage, no complex dtypes (complex elementwise ops stay
+    on the plain cached-executable path; fusion has only ever been
+    exercised on real dtypes)."""
     if not dispatch.fusion_enabled():
         return False
     for t in operands:

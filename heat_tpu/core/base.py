@@ -256,8 +256,8 @@ def resumable_fit_loop(
 def lazy_scalar_property(attr: str, kind: type = float, doc: Optional[str] = None) -> property:
     """Property converting a stored device scalar to a host ``kind`` lazily.
 
-    Fits store 0-d device values in ``attr`` so they never block on the
-    device link; the host conversion happens once, on first access, and the
+    Fits store 0-d device values in ``attr`` so they never block on a
+    device->host sync; the host conversion happens once, on first access, and the
     converted value is cached back.  Shared by the cluster/PCA/Lasso/
     GaussianNB estimators (one pattern, one implementation)."""
 

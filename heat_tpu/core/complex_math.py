@@ -1,9 +1,8 @@
 """Complex-number operations, analog of heat/core/complex_math.py.
 
-Planar-backed complex arrays (``DNDarray._planar``, produced by the fft
-layer on complex-less accelerators) get plane-level fast paths: the result
-is computed from the (re, im) planes ON the device mesh instead of
-materializing a host complex array first."""
+Planar-backed complex arrays (``DNDarray._planar``, produced by the
+planar FFT engine) get plane-level fast paths: the result is computed
+from the (re, im) planes instead of composing a complex array first."""
 
 from __future__ import annotations
 

@@ -5,7 +5,7 @@ Every NumPy-level op funnels through the four wrappers in
 ``jnp`` calls: one Python dispatch + one XLA executable launch per op, so
 a chain like ``(a * b + c).sum()`` paid four launches — the measured
 bottleneck of the bench history (dpsgd only beats the dispatch floor by
-hand-batching steps, kmeans idles against the link-sync floor).  This
+hand-batching steps, kmeans idles against the host-sync floor).  This
 module gives the hot paths the two levers ``fusion.jit`` offers opt-in,
 without any user opt-in:
 

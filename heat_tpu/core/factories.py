@@ -106,25 +106,9 @@ def array(
     else:
         data = np.asarray(obj, order=order)
 
-    def _as_jax(d, jdtype=None):
-        # complex-less TPU runtimes: complex host data goes to the CPU
-        # backend (see dndarray._tpu_complex_ok); device placement of
-        # everything downstream follows the operand
-        from .dndarray import _tpu_complex_ok
-
-        probe = jdtype if jdtype is not None else getattr(d, "dtype", None)
-        if (
-            probe is not None
-            and jnp.issubdtype(probe, jnp.complexfloating)
-            and jax.default_backend() == "tpu"
-            and not _tpu_complex_ok()
-        ):
-            return jnp.asarray(d, dtype=jdtype, device=jax.devices("cpu")[0])
-        return jnp.asarray(d, dtype=jdtype)
-
     if dtype is not None:
         dtype = types.canonical_heat_type(dtype)
-        data = _as_jax(data, dtype.jax_type())
+        data = jnp.asarray(data, dtype=dtype.jax_type())
     else:
         # canonical defaults: python float data -> float32, ints -> int32,
         # unless the input already carries an explicit wider dtype
@@ -132,11 +116,11 @@ def array(
         # ndarrays do and keep it; only dtype-less python data narrows
         explicit = isinstance(obj, (np.ndarray, np.generic))
         if isinstance(data, np.ndarray) and data.dtype == np.float64 and not explicit:
-            data = _as_jax(data, jnp.float32)
+            data = jnp.asarray(data, dtype=jnp.float32)
         elif isinstance(data, np.ndarray) and data.dtype == np.int64 and not explicit:
-            data = _as_jax(data, jnp.int32)
+            data = jnp.asarray(data, dtype=jnp.int32)
         else:
-            data = _as_jax(data)
+            data = jnp.asarray(data)
         dtype = types.canonical_heat_type(data.dtype)
 
     while data.ndim < ndmin:

@@ -42,15 +42,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
-
-from ._compat import shard_map
-
-try:  # pallas TPU backend (present in all jax>=0.4.30 installs)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 __all__ = [
     "lloyd_update",
@@ -271,7 +266,7 @@ def _lloyd_acc(xp: jax.Array, centers: jax.Array, n_true) -> tuple:
         pl.BlockSpec((1, cols), lambda i, *_: (0, 0)),
         pl.BlockSpec((1, _LANES), lambda i, *_: (0, 0)),
     )
-    if pltpu is not None and not _interpret():
+    if not _interpret():
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs, out_specs=out_specs
         )

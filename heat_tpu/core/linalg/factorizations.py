@@ -40,11 +40,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..dndarray import DNDarray
 from .. import types
-from .._compat import shard_map as _shard_map
 
 __all__ = ["cholesky_dist", "det_dist", "inv_dist", "solve_dist", "supports_dist_factor"]
 

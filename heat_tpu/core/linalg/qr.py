@@ -36,12 +36,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import types
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
-from .._compat import shard_map as _shard_map
 
 __all__ = ["qr"]
 
@@ -143,8 +143,7 @@ def _tsqr_shard_map(A: DNDarray, compute_q: bool = True):
 @functools.lru_cache(maxsize=64)
 def _tsqr_fn(comm, compute_q: bool, m_true: int):
     """Jitted, cached TS-QR executable — rebuilding the shard_map per call
-    would retrace (and through a remote compile service, recompile) on
-    every invocation.  ``m_true > 0`` enables masking of canonical padding
+    would retrace (and recompile) on every invocation.  ``m_true > 0`` enables masking of canonical padding
     rows (the ragged case); 0 means the extent divides evenly."""
     mesh = comm.mesh
     axis = comm.axis_name

@@ -17,12 +17,12 @@ from typing import List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 
 from ..parallel.comm import sanitize_comm
 from . import types
 from .dndarray import DNDarray
 from .stride_tricks import sanitize_axis, sanitize_shape
-from ._compat import shard_map as _shard_map
 
 __all__ = [
     "balance",

@@ -40,8 +40,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-from ._compat import shard_map as _shard_map
 
 __all__ = [
     "sample_sort_1d",

@@ -34,11 +34,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.dndarray import DNDarray
 from ..parallel.comm import Communication, sanitize_comm
-from ..core._compat import shard_map as _shard_map
 
 __all__ = ["scaled_dot_product_attention", "ring_attention", "ulysses_attention"]
 
@@ -52,15 +52,7 @@ def _flash_available() -> bool:
     score tensor never materializes, so full-sequence local attention
     scales to lengths where the einsum path OOMs.  Opt out with
     HEAT_TPU_FLASH=0."""
-    if os.environ.get("HEAT_TPU_FLASH", "1") != "1":
-        return False
-    if jax.default_backend() != "tpu":
-        return False
-    try:
-        from jax.experimental.pallas.ops.tpu import flash_attention  # noqa: F401
-    except ImportError:  # pragma: no cover - jax always ships it on tpu
-        return False
-    return True
+    return os.environ.get("HEAT_TPU_FLASH", "1") == "1" and jax.default_backend() == "tpu"
 
 
 def _local_flash(q, k, v, scale, causal, n_true):
@@ -68,8 +60,9 @@ def _local_flash(q, k, v, scale, causal, n_true):
 
     ``q``/``k``/``v`` are (seq, heads, head_dim); padded tail positions
     (>= n_true) are isolated with segment ids so real tokens never attend
-    padding.  Raises at trace time (caught by callers, who fall back to
-    the einsum path) when the kernel rejects the shape."""
+    padding.  Raises at trace time when the kernel rejects the shape —
+    callers pass that on: whoever asked for flash gets the kernel or the
+    error, never a silent einsum."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         SegmentIds,
         flash_attention,
@@ -200,16 +193,12 @@ def _ulysses_body(q, k, v, *, comm, scale, causal, n_true, use_flash):
     kg = jax.lax.all_to_all(k, name, split_axis=1, concat_axis=0, tiled=True)
     vg = jax.lax.all_to_all(v, name, split_axis=1, concat_axis=0, tiled=True)
     seq = qg.shape[0]
-    og = None
     if use_flash:
         # each device now holds the FULL sequence for h/p heads — the
         # shape flash attention wants; the (h/p, seq, seq) score tensor
         # of the einsum path never materializes
-        try:
-            og = _local_flash(qg, kg, vg, scale, causal, n_true)
-        except Exception:  # lint: allow H501(trace-time shape rejection -> einsum fallback)
-            og = None
-    if og is None:
+        og = _local_flash(qg, kg, vg, scale, causal, n_true)
+    else:
         scores = (
             jnp.einsum(
                 "qhd,khd->hqk", qg.astype(jnp.float32), kg,
@@ -315,11 +304,8 @@ def scaled_dot_product_attention(
             # memory-bounded local kernel (opt-in): scales past the einsum
             # path's (h, seq, seq) materialization limit at the cost of
             # the kernel's default MXU precision
-            try:
-                out = _local_flash(qd, kd, vd, scale, causal, seq)
-                return DNDarray.from_dense(out, None, q.device, q.comm)
-            except Exception:  # lint: allow H501(kernel shape rejection -> einsum fallback)
-                pass  # kernel rejected the shape -> einsum path
+            out = _local_flash(qd, kd, vd, scale, causal, seq)
+            return DNDarray.from_dense(out, None, q.device, q.comm)
         scores = (
             jnp.einsum(
                 "qhd,khd->hqk", qd.astype(jnp.float32), kd,

@@ -365,8 +365,6 @@ class DataParallel:
                 # reverse-order or one fused collective) — the loss mean
                 # over equal shards equals the global batch mean, so the
                 # update matches the implicit schedule mathematically
-                from ..core._compat import shard_map
-
                 spec = P(comm.axis_name)
                 blocking = schedule == "fused"
 
@@ -374,13 +372,22 @@ class DataParallel:
                     def local_loss(p):
                         return loss_fn(apply(p, xl), yl)
 
+                    # the replicated parameters enter the body unvarying
+                    # (in_specs=P()); differentiating them as such makes
+                    # autodiff psum the cotangent over the mesh already.
+                    # Cast to varying so the gradient is the per-device
+                    # one and reduce_gradients is the ONLY reduction.
+                    params = jax.tree_util.tree_map(
+                        lambda p: jax.lax.pcast(p, comm.axis_name, to="varying"),
+                        params,
+                    )
                     loss, grads = jax.value_and_grad(local_loss)(params)
                     grads = reduce_gradients(grads, comm, blocking=blocking)
                     loss = comm.psum(loss) / comm.size
                     return loss, grads
 
                 def explicit_body(params, opt_state, xb, yb):
-                    loss, grads = shard_map(
+                    loss, grads = jax.shard_map(
                         local_step,
                         mesh=comm.mesh,
                         in_specs=(P(), spec, spec),
@@ -445,8 +452,8 @@ class DataParallel:
         batch (each batch sharded over the mesh axis exactly as in
         :meth:`step`).  A ``lax.scan`` threads (params, opt_state) through
         the fused forward/backward/update body, so per-step host dispatch
-        — the dominant cost of tiny steps on a remote or tunneled link —
-        is paid once per *stack* instead of once per step.  This is the
+        — the dominant cost of tiny steps — is paid once per *stack*
+        instead of once per step.  This is the
         TPU-native replacement for the reference's per-iteration python
         loop over ``DataParallel`` (data_parallel.py:150) +
         ``DataParallelOptimizer.step`` (dp_optimizer.py:851): steady-state

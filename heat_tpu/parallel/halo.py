@@ -17,10 +17,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from .comm import Communication
-from ..core._compat import shard_map as _shard_map
 
 __all__ = ["halo_exchange", "with_halos"]
 

@@ -1,4 +1,4 @@
-"""Typed failure taxonomy of the resilience layer.
+"""Typed failure classes of the resilience layer.
 
 The reference framework has exactly one failure mode: any raised
 exception aborts the whole SPMD program.  This module splits failure

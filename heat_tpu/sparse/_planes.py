@@ -34,8 +34,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..core._compat import pcast as _pcast
-from ..core._compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
+from jax.lax import pcast as _pcast
 
 __all__ = []
 

@@ -21,12 +21,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core import types
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
-from ..core._compat import shard_map as _shard_map
 
 __all__ = ["cdist", "cdist_small", "cdist_topk", "manhattan", "rbf"]
 

@@ -59,7 +59,6 @@ NAV = [
         ("Perf history", "docs/perf_history.md"),
         ("API coverage", "coverage_tables.md"),
         ("Changelog", "CHANGELOG.md"),
-        ("Round 5 notes", "docs/round5_notes.md"),
     ]),
 ]
 

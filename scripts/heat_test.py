@@ -2,12 +2,14 @@
 
 The reference's smoke test builds ``ht.arange(10, split=0)`` under mpirun
 and prints the local chunk and the global array on every rank.  The mesh
-analog: build the same split array over whatever devices are visible,
-print each device's shard and the global result.
+analog: build the same split array over whatever devices JAX finds (the
+attached TPU chip(s), or the CPU), print each device's shard and the
+global result.  One process owns the chip; for the full on-chip check
+run ``python chip_smoke.py`` instead.
 
-    python scripts/heat_test.py                      # one TPU chip
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        HEAT_TPU_SMOKE_CPU=1 python scripts/heat_test.py   # 8-device mesh
+    python scripts/heat_test.py                      # the devices JAX finds
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python scripts/heat_test.py                  # virtual 8-device mesh
 """
 
 import os
@@ -15,15 +17,13 @@ import sys
 
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__), "..")))
 
-if os.environ.get("HEAT_TPU_SMOKE_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import heat_tpu as ht
 
 
 def main() -> None:
+    from heat_tpu.core.compile_cache import use_compile_cache
+
+    use_compile_cache()
     comm = ht.get_comm()
     print(f"mesh: {comm.size} device(s): {[str(d) for d in comm.devices]}")
 

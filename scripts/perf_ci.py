@@ -1743,19 +1743,6 @@ def main():
 
     guarded("kernel_floors", bench_kernel_floors)
 
-    # compat-matrix smoke lane (ROADMAP 5a): the collective-wrapper test
-    # subset under BOTH core/_compat.py resolver branches (legacy
-    # experimental adapter AND the native top-level API, simulated when
-    # this jax lacks it) — gated as a hard-cap count: a red branch fails
-    # the same perf_gate run that guards the kernels
-    def bench_compat_matrix():
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        from compat_matrix import run_matrix
-
-        results["compat_matrix"] = run_matrix(quiet=True)
-
-    guarded("compat_matrix", bench_compat_matrix)
-
     # sanitized test lane: the threaded test subset (test_overlap /
     # test_introspection / test_telemetry) in a subprocess under
     # HEAT_TPU_TSAN=1 — gated as a hard-cap count: red tests or ANY

@@ -2,9 +2,9 @@
 four-step radix cutoff on the attached chip, validating accuracy against
 numpy at 128^3 before timing 512^3.
 
-Each config runs in a subprocess (the cutoff is an import-time constant,
-and complex-capability probing must not poison the parent stream — see
-the complex-less runtime notes).  Prints one JSON line per config.
+Each config runs in a subprocess (the cutoff is an import-time
+constant); the parent never touches JAX, so each child can own the
+chip in turn.  Prints one JSON line per config.
 
     python scripts/tune_fft.py            # full sweep
 """

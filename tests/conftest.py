@@ -25,12 +25,11 @@ jax.config.update("jax_enable_x64", True)
 # of unique (shape, dtype, mesh) programs on the virtual mesh); warm
 # reruns skip XLA entirely.  Run parallel with ``pytest -n auto`` (xdist)
 # — workers share this cache, and CI stays inside one timeout window.
-_CACHE_DIR = os.environ.get(
-    "HEAT_TPU_COMPILE_CACHE", os.path.join(os.path.dirname(__file__), ".jax_cache")
-)
-if _CACHE_DIR != "0":
-    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
+# JAX_COMPILATION_CACHE_DIR places it; unset, it is <checkout>/.jax_cache.
+from heat_tpu.core.compile_cache import use_compile_cache
+
+use_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 
 import numpy as np
 import pytest

@@ -1,0 +1,186 @@
+"""The main path's kernels and steps, compiled at real widths for the chip.
+
+The TPU's compiler is installed where the tests run; it compiles for a
+chip that is described (``v5e:2x2``) and not attached.  What it refuses
+here — a slice off the tiling, too much fast memory, a program that does
+not fit 16 GB, a kernel that cannot be partitioned — it would refuse on
+the chip, and costs no chip time to find.  Nothing runs: a compile that
+passes is not a chip run.
+
+All cases live in THIS file: the process that describes the topology
+holds the TPU library until it exits, so a second file (another xdist
+worker) would skip in silence.  The topology is described inside a
+module-scoped fixture, never at import, and compiles happen in the
+test's own process with the persistent cache off (a compile for a
+described chip can be written to the cache but not read back).
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+# __graft_entry__ (the CNN) lives at the repo root
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+#: what one v5e chip offers a program (``memory_stats()["bytes_limit"]``
+#: on the chip: 16,909,336,064)
+HBM_BYTES = 16_909_336_064
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # lint: allow H501(no TPU compiler here -> skip, never fail)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def comm4(topo):
+    """A 4-device communication over the described chips (its mesh is
+    what the program's own shard_maps and shardings are built from)."""
+    from heat_tpu.parallel.comm import Communication
+
+    return Communication(list(topo.devices))
+
+
+@pytest.fixture()
+def for_the_chip(monkeypatch):
+    """Steer the code that asks ``jax.default_backend()`` (interpret= and
+    kernel gates) onto its TPU branch, compile as the chip's process does
+    (x64 off: the suite's conftest turns it on, and Mosaic refuses the
+    int64 block indices that makes), and keep these compiles out of the
+    persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+        - m.alias_size_in_bytes
+    )
+
+
+def test_gram_syrk_north_star_shape(one_chip, for_the_chip):
+    from heat_tpu.core import kernels
+
+    compiled = jax.jit(kernels.gram_syrk).lower(
+        _sds((2**22, 128), jnp.float32, one_chip)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_rfft3_leading_512_cubed(one_chip, for_the_chip):
+    from heat_tpu.fft import _leading
+
+    compiled = jax.jit(lambda x: _leading.rfft3_leading(x, None)).lower(
+        _sds((512, 512, 512), jnp.float32, one_chip)
+    ).compile()
+    # the stage kernel and the extension kernel
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_local_flash_attention(one_chip, for_the_chip):
+    from heat_tpu.nn import attention
+
+    seq, heads, dim = 8192, 16, 128
+    x = _sds((seq, heads, dim), jnp.float32, one_chip)
+    compiled = jax.jit(
+        lambda q, k, v: attention._local_flash(q, k, v, dim**-0.5, False, seq)
+    ).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # far below the einsum path's (heads, seq, seq) f32 score tensor
+    assert _device_bytes(compiled) < heads * seq * seq * 4
+
+
+def test_lloyd_step_one_chip(one_chip, for_the_chip):
+    from heat_tpu.cluster import kmeans
+
+    n, f, k = 2**24, 16, 8
+    compiled = kmeans._lloyd_update.lower(
+        _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip), n_true=n, k=k
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_lloyd_step_four_chips(comm4, for_the_chip):
+    from heat_tpu.cluster import kmeans
+
+    n, f, k = 2**24, 16, 8
+    compiled = kmeans._lloyd_update.lower(
+        _sds((n, f), jnp.float32, comm4.sharding(0)),
+        _sds((k, f), jnp.float32, comm4.sharding(None)),
+        n_true=n, k=k,
+    ).compile()
+    # the per-cluster sums cross the chips
+    assert "all-reduce" in compiled.as_text()
+    # memory_analysis is per device: a quarter of the rows each
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_bucketed_data_parallel_step_four_chips(comm4, for_the_chip):
+    import optax
+
+    import heat_tpu as ht
+    from __graft_entry__ import _cnn
+
+    model = _cnn()
+    batch = 256
+    optimizer = optax.sgd(0.1)
+
+    def loss_fn(logits, y):
+        return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+
+    dp = ht.nn.DataParallel(model, comm=comm4, optimizer=optimizer, grad_reduction="bucketed")
+    dp._build(loss_fn)  # the jitted explicit step; places nothing
+    replicated = NamedSharding(comm4.mesh, P())
+    rows = NamedSharding(comm4.mesh, P(comm4.axis_name))
+    params = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 28, 28, 1), jnp.float32)
+    )
+    opt_state = jax.eval_shape(optimizer.init, params)
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(lambda s: _sds(s.shape, s.dtype, replicated), tree)
+
+    compiled = dp._train_step_explicit.lower(
+        on_mesh(params), on_mesh(opt_state),
+        _sds((batch, 28, 28, 1), jnp.float32, rows), _sds((batch,), jnp.int32, rows),
+    ).compile()
+    # reduce_gradients' hand-placed psum
+    assert "all-reduce" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+    n_params = sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(params))
+    assert n_params > 100_000  # the real CNN, not a stub

@@ -9,10 +9,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 import heat_tpu as ht
-from heat_tpu.core._compat import shard_map as _compat_shard_map
 
 
 @pytest.fixture(scope="module")
@@ -23,7 +23,7 @@ def comm():
 def _smap(comm, body, n_in=1, out=None):
     spec = P(comm.axis_name)
     return jax.jit(
-        _compat_shard_map(
+        _shard_map(
             body, mesh=comm.mesh, in_specs=(spec,) * n_in,
             out_specs=out if out is not None else spec,
         )
@@ -111,7 +111,7 @@ class TestHaloProgram:
 
         spec = P(comm.axis_name)
         got = jax.jit(
-            _compat_shard_map(body, mesh=comm.mesh, in_specs=(spec,), out_specs=spec)
+            _shard_map(body, mesh=comm.mesh, in_specs=(spec,), out_specs=spec)
         )(x)
         blocks = np.asarray(got).reshape(p, 5)
         for r in range(p):

@@ -11,10 +11,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 import heat_tpu as ht
-from heat_tpu.core._compat import shard_map as _compat_shard_map
 
 
 @pytest.fixture(scope="module")
@@ -25,7 +25,7 @@ def comm():
 def _smap(comm, body, n_in=1, out=None):
     spec = P(comm.axis_name)
     return jax.jit(
-        _compat_shard_map(
+        _shard_map(
             body, mesh=comm.mesh, in_specs=(spec,) * n_in,
             out_specs=out if out is not None else spec,
         )
@@ -83,7 +83,7 @@ class TestPrefixSubAxis:
         nodes, per = comm.size // 2, 2
         x = jnp.arange(comm.size, dtype=jnp.float32)
 
-        body = _compat_shard_map(
+        body = _shard_map(
             lambda v: h.pscan(v, axis_name=nx),
             mesh=h.mesh,
             in_specs=(P((gx, nx)),),

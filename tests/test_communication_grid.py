@@ -13,9 +13,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 import heat_tpu as ht
-from heat_tpu.core._compat import shard_map
 from heat_tpu.parallel.comm import Communication
 
 #: world sizes: the full test mesh plus two reshaped (surviving) worlds

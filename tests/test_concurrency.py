@@ -20,8 +20,8 @@ The contract under test (docs/static_analysis.md, "Concurrency rules"):
 * the telemetry server start/stop races and the flight-recorder
   excepthook re-entrancy are fixed (one bundle per crashing thread,
   distinct paths);
-* ``core/_compat.py`` resolves ``shard_map``/``psum_scatter``/``pcast``
-  on this runner's jax, including the ``check_vma`` kwarg translation.
+* the ``jax.shard_map`` kernels of the perf grid (sort, SpMM ring) run
+  on this runner's jax.
 """
 
 import json
@@ -871,57 +871,11 @@ class TestTsanEnvAndDump:
 
 
 # ----------------------------------------------------------------------
-# core/_compat: version-gated shard_map resolver
+# the shard_map kernels of the perf grid run on this jax
 # ----------------------------------------------------------------------
-class TestCompat:
-    def test_resolves_and_runs(self):
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from heat_tpu.core._compat import pcast, psum_scatter, shard_map
-
-        comm = ht.get_comm()
-        x = jnp.arange(float(comm.size * 2))
-
-        def body(xl):
-            return jax.lax.psum(xl, comm.axis_name)
-
-        out = jax.jit(
-            shard_map(
-                body, mesh=comm.mesh, in_specs=P(comm.axis_name), out_specs=P(comm.axis_name)
-            )
-        )(x)
-        np.testing.assert_allclose(
-            np.asarray(out)[:2], np.asarray(x).reshape(comm.size, 2).sum(0)
-        )
-        assert psum_scatter is not None
-        assert np.asarray(pcast(jnp.ones(3), ("a",), to="varying")).shape == (3,)
-
-    def test_check_vma_translated(self):
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from heat_tpu.core._compat import shard_map
-
-        comm = ht.get_comm()
-        x = jnp.arange(float(comm.size))
-
-        out = jax.jit(
-            shard_map(
-                lambda xl: xl * 2.0,
-                mesh=comm.mesh,
-                in_specs=P(comm.axis_name),
-                out_specs=P(comm.axis_name),
-                check_vma=False,
-            )
-        )(x)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(x) * 2.0)
-
+class TestShardMapKernels:
     def test_bench_ci_kernels_alive(self):
-        # the three kernels BENCH_CI previously recorded as `error` on
-        # runners whose jax lacks jax.shard_map
+        # two shard_map kernels of the BENCH_CI grid
         import scipy.sparse as sp
 
         ht.random.seed(0)

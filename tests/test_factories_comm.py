@@ -162,7 +162,7 @@ def test_collective_wrappers(ht):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._compat import shard_map
+    from jax import shard_map
 
     comm = ht.get_comm()
     n = comm.size

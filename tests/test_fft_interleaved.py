@@ -3,7 +3,7 @@ real transform, the complex-input engine behind fftn->filter->ifftn
 chains, and the conj-trick real ifftn — all against numpy across shapes
 and norms.  The representation invariant (no materialized (..., 2)
 tensor, no index-grid gathers) is what keeps the 512^3 transform at
-16.7 GB scheduled instead of 43.1 (docs/round5_notes.md).
+16.7 GB scheduled instead of 43.1 (docs/fft_roofline.md).
 """
 
 import os

@@ -241,19 +241,23 @@ class TestHermitianND:
         got = ht.fft.ihfft2(ht.array(b, split=0)).numpy()
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
-    def test_chain_matches_native_fftn(self, ht):
-        from heat_tpu.fft.fft import _chain_fftn
+    def test_hermitian_chain_axes_subset_vs_torch(self, ht):
+        import torch
 
         rng = np.random.default_rng(2)
         a = (rng.standard_normal((4, 5, 6)) + 1j * rng.standard_normal((4, 5, 6))).astype(
             np.complex64
         )
-        import jax.numpy as jnp
-
         for norm in (None, "ortho", "forward"):
-            got = np.asarray(_chain_fftn(jnp.asarray(a), None, None, norm))
-            want = np.fft.fftn(a, norm=norm or "backward")
+            want = torch.fft.hfftn(
+                torch.tensor(a), dim=(0, 2), norm=norm or "backward"
+            ).numpy()
+            got = ht.fft.hfftn(ht.array(a, split=1), axes=(0, 2), norm=norm).numpy()
             np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        b_ = rng.standard_normal((4, 5, 6)).astype(np.float32)
+        want = torch.fft.ihfftn(torch.tensor(b_), dim=(0, 2)).numpy()
+        got = ht.fft.ihfftn(ht.array(b_, split=1), axes=(0, 2)).numpy()
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
 class TestShardedWrites:

@@ -511,6 +511,32 @@ class TestBucketedGradReduction:
                 np.asarray(p_i[k]), np.asarray(p_b[k]), rtol=2e-5, atol=1e-7
             )
 
+    @pytest.mark.parametrize("schedule", ["implicit", "bucketed", "fused"])
+    def test_schedule_gradient_equals_single_device_gradient(self, schedule):
+        """One SGD step on a 4-device mesh moves the parameters by exactly
+        lr * (the gradient one device computes on the whole batch): the
+        explicit schedules differentiate per-device values and reduce ONCE
+        (under jax 0.9's varying-axes typing, differentiating the
+        replicated parameters inside the shard_map already sums the
+        cotangent over the mesh; reducing that again gave size x the
+        gradient)."""
+        import optax
+
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four devices")
+        comm = ht.parallel.Communication(jax.devices()[:4])
+        params, x, y, apply, loss_fn = _mlp_setup()
+        lr = 0.1
+        want = jax.grad(lambda p: loss_fn(apply(p, x), y))(params)
+        dp = ht.nn.DataParallel(
+            apply, comm=comm, optimizer=optax.sgd(lr), grad_reduction=schedule
+        )
+        dp.set_params(jax.tree_util.tree_map(lambda a: a.copy(), params))
+        dp.step(loss_fn, x, y)
+        for k in params:
+            got = (np.asarray(params[k]) - np.asarray(dp.params[k])) / lr
+            np.testing.assert_allclose(got, np.asarray(want[k]), rtol=1e-4, atol=1e-6)
+
     def test_hierarchical_two_stage_schedules_match(self):
         import optax
 

@@ -1,10 +1,11 @@
 """Plane-preservation guarantees (VERDICT r3 #7): elementwise chains on
-planar complex arrays stay on the mesh — fftn(x) * H -> ifftn never
-materializes host complex storage — and demotions are loud.
+planar complex arrays stay on the planes — fftn(x) * H -> ifftn never
+materializes a complex array — and materialization, when an op needs it,
+happens on the mesh.
 
-The planar representation is forced via HEAT_TPU_PLANAR=1 (the
-complex-less-runtime switch); materialization is trapped by poisoning
-DNDarray._DNDarray__materialize_planar for the duration.
+The planar engine is selected via HEAT_TPU_PLANAR=1; materialization is
+trapped by poisoning DNDarray._DNDarray__materialize_planar for the
+duration.
 """
 
 import os
@@ -97,38 +98,18 @@ def test_scalar_complex_div(planar_mode):
     np.testing.assert_allclose(np.asarray(got.numpy()), fa / (2.0 + 1.0j), atol=1e-10)
 
 
-def test_demotion_is_loud_midchain_only(planar_mode, monkeypatch):
-    import warnings
-
-    from heat_tpu.core import dndarray as dd
-
-    # force the complex-less-runtime branch (the CPU test backend supports
-    # complex, so the host-demotion path must be simulated)
-    monkeypatch.setattr(dd, "_tpu_complex_ok", lambda: False)
-    monkeypatch.setattr(dd.jax, "default_backend", lambda: "tpu")
-    dd._planar_demotions_warned.clear()
-    a = ht.fft.fft(ht.array(np.ones((4, 8), np.float32), split=0), axis=1)
+def test_materialization_stays_on_mesh(planar_mode):
+    """An op without a plane fast path materializes the complex array ON
+    the mesh (``jax.lax.complex`` of the two planes): same devices, same
+    sharding as the planes — never a host or CPU-backend copy."""
+    x_np = np.random.default_rng(3).standard_normal((16, 8)).astype(np.float32)
+    a = ht.fft.fft(ht.array(x_np, split=0), axis=1)
     assert a._planar is not None
-    # a framework op WITHOUT a plane fast path warns, naming the site
-    with pytest.warns(RuntimeWarning, match="demoted to HOST complex"):
-        try:
-            ht.sum(a)
-        except Exception:
-            pass  # the simulated-TPU path may fail downstream on CPU
-    # terminal fetches are intentional host transfers: silent
-    b = ht.fft.fft(ht.array(np.ones(8, np.float32), split=0))
-    dd._planar_demotions_warned.clear()
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", RuntimeWarning)
-        try:
-            b.numpy()
-        except RuntimeWarning:
-            raise
-        except Exception:
-            pass
-        try:
-            b.larray_padded  # direct user buffer access: intentional
-        except RuntimeWarning:
-            raise
-        except Exception:
-            pass
+    re, _ = a._planar
+    comp = a.larray_padded
+    assert comp.dtype == jnp.complex64
+    assert comp.devices() == re.devices()
+    assert comp.sharding.is_equivalent_to(re.sharding, comp.ndim)
+    np.testing.assert_allclose(
+        complex(ht.sum(a).item()), np.fft.fft(x_np, axis=1).sum(), rtol=1e-4, atol=1e-4
+    )

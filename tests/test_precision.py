@@ -21,8 +21,7 @@ rules"):
   --rules J2,J3`` batch-checks the served predict programs;
 * satellites: ``types.canonical_dtype`` property grid, the
   ``lint_gate.py --fix-stale`` pruning workflow over the now-empty
-  baseline, and the compat-matrix lane driving both ``core/_compat.py``
-  resolver branches.
+  baseline.
 """
 
 import ast
@@ -793,37 +792,3 @@ class TestLintGateFixStale:
         assert doc["violations"] == []  # pruned, NOT regenerated-with-new
         res2 = run_gate(paths=[str(d)], baseline_path=str(baseline), quiet=True)
         assert res2["fixed_count"] == 0 and res2["new_count"] == 1
-
-
-# ----------------------------------------------------------------------
-# satellite: compat-matrix lane (both resolver branches)
-# ----------------------------------------------------------------------
-class TestCompatMatrix:
-    def test_both_branches_green_on_wrapper_test(self, monkeypatch):
-        import compat_matrix
-
-        monkeypatch.setattr(
-            compat_matrix, "SUBSET",
-            ("tests/test_factories_comm.py::test_collective_wrappers",),
-        )
-        monkeypatch.setattr(compat_matrix, "DESELECT", ())
-        monkeypatch.setattr(compat_matrix, "DESELECT_NATIVE", ())
-        res = compat_matrix.run_matrix(quiet=True)
-        assert res["count"] == 0, res
-        assert res["branches"]["legacy"]["passed"] >= 1
-        assert res["branches"]["native"]["passed"] >= 1
-
-    def test_compat_force_validation(self):
-        code = (
-            "import os\n"
-            "os.environ['HEAT_TPU_COMPAT_FORCE'] = 'bogus'\n"
-            "try:\n"
-            "    import heat_tpu.core._compat\n"
-            "except ValueError as e:\n"
-            "    raise SystemExit(0)\n"
-            "raise SystemExit(1)\n"
-        )
-        r = subprocess.run([sys.executable, "-c", code], cwd=REPO_ROOT,
-                           env={**os.environ, "JAX_PLATFORMS": "cpu"},
-                           capture_output=True, text=True)
-        assert r.returncode == 0, r.stderr

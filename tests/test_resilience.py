@@ -328,14 +328,22 @@ class TestCheckpointer:
         assert inj.injected["checkpoint.save"] == [(0, "transient")]
         assert ck.latest_step() == 3
 
-    def test_corrupt_checkpoint_raises_checksum_error(self, tmp_path):
+    @pytest.mark.parametrize("where", ["zip_header", "payload", "tail"])
+    def test_corrupt_checkpoint_raises_checksum_error(self, tmp_path, where):
         d = str(tmp_path / "ck")
         ck = Checkpointer(d)
         ck.save(0, {"v": np.arange(16, dtype=np.float64)})
         npz = os.path.join(d, "step_0", "arrays.npz")
+        size = os.path.getsize(npz)
+        offset = {"zip_header": 20, "payload": size // 2, "tail": size - 2}[where]
         with open(npz, "r+b") as f:
-            f.seek(20)  # flip payload bytes (not the already-zero zip tail)
-            f.write(b"\xff\xff")
+            # COMPLEMENT the bytes: a fixed pattern can equal what is there
+            # (offset 20 is zip64's 0xffffffff size placeholder under
+            # numpy >= 2, so writing 0xff 0xff there changed nothing)
+            f.seek(offset)
+            old = f.read(2)
+            f.seek(offset)
+            f.write(bytes(b ^ 0xFF for b in old))
         with pytest.raises(rz.ChecksumError):
             ck.restore(0)
 

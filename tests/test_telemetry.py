@@ -32,17 +32,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import heat_tpu as ht
 from heat_tpu import telemetry
 from heat_tpu.telemetry import metrics as tm
 from heat_tpu.telemetry import spans as tspans
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pre-0.5 jax exposes it under experimental
-    from jax.experimental.shard_map import shard_map
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -400,7 +396,7 @@ class TestCommAccounting:
                 mesh=comm.mesh,
                 in_specs=P(comm.axis_name),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
         )(x)
         recs = [r for r in telemetry.get_spans() if r.name == "comm.all_gather"]
