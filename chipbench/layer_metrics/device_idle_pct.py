@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(run):
+    trace = run["trace"]
+    if not trace or not trace["busy_s"]:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / run["window_s"])
