@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from ..core import dispatch, kernels, types
 from ..core.dndarray import DNDarray
 from ..spatial import distance
+from ..telemetry.spans import span as _span
 from ._kcluster import _KCluster
 
 __all__ = ["KMeans"]
@@ -60,15 +61,18 @@ def _lloyd_loop(xp: jax.Array, centers: jax.Array, n_true: int, k: int, max_iter
 
 
 def _lloyd_body(xp, centers, n_true, k):
-    xc = xp @ centers.T
-    c2 = jnp.sum(centers * centers, axis=1)
-    labels = jnp.argmin(c2[None, :] - 2.0 * xc, axis=1)
-    valid = jax.lax.broadcasted_iota(jnp.int32, (xp.shape[0],), 0) < n_true
-    oh = jax.nn.one_hot(labels, k, dtype=xp.dtype) * valid.astype(xp.dtype)[:, None]
-    sums = oh.T @ xp
-    counts = jnp.sum(oh, axis=0)
-    new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None], centers)
-    shift = jnp.sum((new - centers) ** 2).astype(jnp.float32)
+    # the scopes name the two passes in the device trace; metadata only
+    with jax.named_scope("lloyd.assign"):
+        xc = xp @ centers.T
+        c2 = jnp.sum(centers * centers, axis=1)
+        labels = jnp.argmin(c2[None, :] - 2.0 * xc, axis=1)
+    with jax.named_scope("lloyd.update"):
+        valid = jax.lax.broadcasted_iota(jnp.int32, (xp.shape[0],), 0) < n_true
+        oh = jax.nn.one_hot(labels, k, dtype=xp.dtype) * valid.astype(xp.dtype)[:, None]
+        sums = oh.T @ xp
+        counts = jnp.sum(oh, axis=0)
+        new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None], centers)
+        shift = jnp.sum((new - centers) ** 2).astype(jnp.float32)
     return new, shift
 
 
@@ -86,19 +90,22 @@ def _lloyd_step(xp: jax.Array, centers: jax.Array, n_true: int, k: int):
 
     Returns (labels_padded, new_centers, shift, inertia).
     """
-    xc = xp @ centers.T  # (N, k) — MXU
-    c2 = jnp.sum(centers * centers, axis=1)
-    half_d2 = c2[None, :] - 2.0 * xc  # squared distance minus |x|^2 row term
-    labels = jnp.argmin(half_d2, axis=1)
-    valid = jax.lax.broadcasted_iota(jnp.int32, (xp.shape[0],), 0) < n_true
-    w = valid.astype(xp.dtype)
-    oh = jax.nn.one_hot(labels, k, dtype=xp.dtype) * w[:, None]
-    sums = oh.T @ xp  # (k, f) — MXU; GSPMD: psum across shards
-    counts = jnp.sum(oh, axis=0)
-    new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None], centers)
-    shift = jnp.sum((new - centers) ** 2)
-    x2 = jnp.sum(xp * xp, axis=1)
-    inertia = jnp.sum(w * (x2 + jnp.min(half_d2, axis=1)))
+    with jax.named_scope("lloyd.assign"):
+        xc = xp @ centers.T  # (N, k) — MXU
+        c2 = jnp.sum(centers * centers, axis=1)
+        half_d2 = c2[None, :] - 2.0 * xc  # squared distance minus |x|^2 row term
+        labels = jnp.argmin(half_d2, axis=1)
+    with jax.named_scope("lloyd.update"):
+        valid = jax.lax.broadcasted_iota(jnp.int32, (xp.shape[0],), 0) < n_true
+        w = valid.astype(xp.dtype)
+        oh = jax.nn.one_hot(labels, k, dtype=xp.dtype) * w[:, None]
+        sums = oh.T @ xp  # (k, f) — MXU; GSPMD: psum across shards
+        counts = jnp.sum(oh, axis=0)
+        new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None], centers)
+        shift = jnp.sum((new - centers) ** 2)
+    with jax.named_scope("lloyd.assign"):  # the inertia is the assignment's own sum
+        x2 = jnp.sum(xp * xp, axis=1)
+        inertia = jnp.sum(w * (x2 + jnp.min(half_d2, axis=1)))
     return labels, new, shift, inertia
 
 
@@ -199,18 +206,32 @@ class KMeans(_KCluster):
             raise ValueError(f"input needs to be a DNDarray, but was {type(x)}")
         if x.ndim != 2:
             raise ValueError(f"input needs to be 2D, but was {x.ndim}D")
+        # Host spans at the fit's phases; every launch below is asynchronous,
+        # so a span times the host's part (init, enqueue, wrap), not the device.
+        with _span("ht.cluster.KMeans.fit", rows=x.shape[0], features=x.shape[1],
+                   clusters=self.n_clusters, max_iter=self.max_iter):
+            self._fit(x)
+        return self
+
+    def _fit(self, x: DNDarray) -> None:
         xp = x.larray_padded
         if not types.heat_type_is_inexact(x.dtype):
             xp = xp.astype(jnp.float32)
+        dtype = xp.dtype
+
+        def init_centers():
+            with _span("kmeans.init"):
+                self._initialize_cluster_centers(x)
+            return self._cluster_centers._dense().astype(dtype)
+
         if self._resumable:
             # chunked checkpoint/resume path: the SAME `_lloyd_body`
             # iteration sequence as the fast path, run checkpoint_every
             # iterations per device program, centers checkpointed (and
             # divergence-guarded) between chunks.  A killed fit resumed
             # from its last checkpoint reproduces the uninterrupted
-            # result exactly.
-            dtype = xp.dtype
-
+            # result exactly.  (`kmeans.init` nests in `kmeans.loop` here:
+            # a resumed fit does not initialize.)
             def run_chunk(centers, n):
                 dispatch.record_external_dispatch()
                 with self._account_lloyd_psum(x, xp):
@@ -219,48 +240,39 @@ class KMeans(_KCluster):
                         self.n_clusters, n, float(self.tol),
                     )
 
-            def init_centers():
-                self._initialize_cluster_centers(x)
-                return self._cluster_centers._dense().astype(dtype)
-
-            centers, n_iter = self._run_resumable(run_chunk, init_centers, "kmeans.iter")
-            self._cluster_centers = DNDarray.from_dense(
-                jnp.asarray(centers, dtype), None, x.device, x.comm
-            )
-            self._n_iter = n_iter
-            labels, inertia = self._assign_padded(x)
-            self._inertia = inertia
-            self._labels = DNDarray.from_dense(labels[: x.shape[0]], x.split, x.device, x.comm)
-            return self
-        self._initialize_cluster_centers(x)
-        centers = self._cluster_centers._dense().astype(xp.dtype)
-        use_kernel = kernels.LLOYD_KERNEL and kernels.lloyd_supported(xp.shape[1], self.n_clusters)
-        if use_kernel:
+            with _span("kmeans.loop"):
+                centers, n_iter = self._run_resumable(run_chunk, init_centers, "kmeans.iter")
+                self._cluster_centers = DNDarray.from_dense(
+                    jnp.asarray(centers, dtype), None, x.device, x.comm
+                )
+        elif kernels.LLOYD_KERNEL and kernels.lloyd_supported(xp.shape[1], self.n_clusters):
+            init_centers()
             # the opt-in Pallas path iterates from the host (one sync/iter)
-            for i in range(self.max_iter):
-                shift = self._fused_step(x)
-                if float(shift) <= self.tol:
-                    break
+            with _span("kmeans.loop"):
+                for i in range(self.max_iter):
+                    shift = self._fused_step(x)
+                    if float(shift) <= self.tol:
+                        break
             n_iter = i + 1
         else:
+            centers = init_centers()
             # whole fit loop on-device, and the iteration count stays a
             # device scalar — fit() performs ZERO host syncs; n_iter_ and
             # inertia_ convert lazily on first access (one device->host
             # sync each, paid only if the caller looks).  ONE
             # dispatch for the whole fit, however many Lloyd iterations —
             # the dispatch-amortization invariant the micro-test pins.
-            dispatch.record_external_dispatch()
-            with self._account_lloyd_psum(x, xp):
-                new, n_iter_dev, _ = _lloyd_loop(
-                    xp, centers, x.shape[0], self.n_clusters, self.max_iter, float(self.tol)
-                )
-            self._cluster_centers = DNDarray.from_dense(new, None, x.device, x.comm)
-            n_iter = n_iter_dev
+            with _span("kmeans.loop"):
+                dispatch.record_external_dispatch()
+                with self._account_lloyd_psum(x, xp):
+                    new, n_iter, _ = _lloyd_loop(
+                        xp, centers, x.shape[0], self.n_clusters, self.max_iter, float(self.tol)
+                    )
+                self._cluster_centers = DNDarray.from_dense(new, None, x.device, x.comm)
 
         self._n_iter = n_iter
         # final assignment against the converged centers (the reference's
         # last pass only assigns, it does not move centers)
-        labels, inertia = self._assign_padded(x)
-        self._inertia = inertia
-        self._labels = DNDarray.from_dense(labels[: x.shape[0]], x.split, x.device, x.comm)
-        return self
+        with _span("kmeans.assign"):
+            labels, self._inertia = self._assign_padded(x)
+            self._labels = DNDarray.from_dense(labels[: x.shape[0]], x.split, x.device, x.comm)

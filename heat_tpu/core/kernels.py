@@ -270,9 +270,9 @@ def _lloyd_acc(xp: jax.Array, centers: jax.Array, n_true) -> tuple:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs, out_specs=out_specs
         )
-        accs, accc, acci = pl.pallas_call(kernel, out_shape=out_shapes, grid_spec=grid_spec)(
-            nt, xv, ck, c2
-        )
+        accs, accc, acci = pl.pallas_call(
+            kernel, out_shape=out_shapes, grid_spec=grid_spec, name="lloyd_update"
+        )(nt, xv, ck, c2)
     else:
         accs, accc, acci = pl.pallas_call(
             kernel,
@@ -281,6 +281,7 @@ def _lloyd_acc(xp: jax.Array, centers: jax.Array, n_true) -> tuple:
             in_specs=[pl.BlockSpec((1,), lambda i, *_: (0,))] + in_specs,
             out_specs=out_specs,
             interpret=True,
+            name="lloyd_update",
         )(nt, xv, ck, c2)
     return _unscramble(accs, accc, acci, f, k, kp)
 
@@ -415,6 +416,7 @@ def gram_syrk(x: jax.Array) -> jax.Array:
         out_specs=pl.BlockSpec((n, n), lambda i: (0, 0)),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=_interpret(),
+        name="gram_syrk",  # the device trace names the custom call by it (%gram_syrk.N)
     )
     g = call(head)
     if m0 < m:

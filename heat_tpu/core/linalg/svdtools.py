@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...telemetry.spans import span as _span
 from .. import types
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
@@ -124,14 +125,15 @@ def _hsvd_rank_jit(dense, trunc: int, p: int, no_of_merges: int, k: int, compute
     program."""
     dense = dense.astype(jnp.dtype(dtype_name))
     u, s, v, _disc, total_sq = _hsvd_body(dense, trunc, p, no_of_merges, compute_v, syrk_ok)
-    sv = s[:k]
-    approx_sq = jnp.sum(sv.astype(jnp.float32) ** 2)
-    rel_err = jnp.sqrt(
-        jnp.maximum(total_sq - approx_sq, 0.0) / jnp.maximum(total_sq, 1e-30)
-    )
-    if compute_v:
-        return u[:, :k], sv, v[:, :k], rel_err
-    return u[:, :k], sv, rel_err
+    with jax.named_scope("hsvd.truncate"):
+        sv = s[:k]
+        approx_sq = jnp.sum(sv.astype(jnp.float32) ** 2)
+        rel_err = jnp.sqrt(
+            jnp.maximum(total_sq - approx_sq, 0.0) / jnp.maximum(total_sq, 1e-30)
+        )
+        if compute_v:
+            return u[:, :k], sv, v[:, :k], rel_err
+        return u[:, :k], sv, rel_err
 
 
 def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, compute_v: bool, syrk_ok: bool = False):
@@ -159,22 +161,27 @@ def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, comput
         # kernel path needs a SINGLE-DEVICE operand (pallas_call is not
         # GSPMD-partitionable), so the caller gates ``syrk_ok`` on the
         # communication layout outside the jit.
-        g = _gram(dense, syrk_ok)
-        lam, v = jnp.linalg.eigh(g)
-        lam = lam[::-1]
-        v = v[:, ::-1]
-        kk = min(trunc, n)
-        disc = jnp.sum(jnp.maximum(lam[kk:].astype(jnp.float32), 0.0))
-        total_sq = jnp.sum(jnp.maximum(lam.astype(jnp.float32), 0.0))
-        lam_k = jnp.maximum(lam[:kk], 0.0)
-        eps = float(jnp.finfo(dense.dtype).eps)
-        keep = lam_k > eps * jnp.maximum(lam_k[0], 1e-30)
-        s_fin = jnp.where(keep, jnp.sqrt(lam_k), 0.0)
-        inv_s = jnp.where(keep, 1.0 / jnp.maximum(jnp.sqrt(lam_k), 1e-30), 0.0)
-        u_fin = (
-            jnp.matmul(dense, v[:, :kk], precision=jax.lax.Precision.HIGHEST)
-            * inv_s[None, :]
-        )
+        # The scopes are names for the device trace (op_name metadata);
+        # they change no operation of the compiled program.
+        with jax.named_scope("hsvd.gram"):
+            g = _gram(dense, syrk_ok)
+        with jax.named_scope("hsvd.eigh"):
+            lam, v = jnp.linalg.eigh(g)
+            lam = lam[::-1]
+            v = v[:, ::-1]
+            kk = min(trunc, n)
+            disc = jnp.sum(jnp.maximum(lam[kk:].astype(jnp.float32), 0.0))
+            total_sq = jnp.sum(jnp.maximum(lam.astype(jnp.float32), 0.0))
+            lam_k = jnp.maximum(lam[:kk], 0.0)
+            eps = float(jnp.finfo(dense.dtype).eps)
+            keep = lam_k > eps * jnp.maximum(lam_k[0], 1e-30)
+            s_fin = jnp.where(keep, jnp.sqrt(lam_k), 0.0)
+            inv_s = jnp.where(keep, 1.0 / jnp.maximum(jnp.sqrt(lam_k), 1e-30), 0.0)
+        with jax.named_scope("hsvd.project"):
+            u_fin = (
+                jnp.matmul(dense, v[:, :kk], precision=jax.lax.Precision.HIGHEST)
+                * inv_s[None, :]
+            )
         v_fin = v[:, :kk] if compute_v else None
         return u_fin, s_fin, v_fin, disc, total_sq
 
@@ -263,61 +270,74 @@ def _hsvd(
     no_of_merges: int = 2,
 ):
     m, n = A.shape
-    comm = A.comm
-    dtype = jnp.float32 if not types.heat_type_is_inexact(A.dtype) else A.dtype.jax_type()
+    attrs = {"rank": maxrank} if rtol is None else {"rtol": rtol}
+    # Host spans at the layer boundaries: the root is the API layer,
+    # ``hsvd.dispatch`` the jit cache lookup and enqueue (trace and compile
+    # on a miss), ``hsvd.wrap`` the results' re-placement.  What the root
+    # holds beside them is this function's own host work.
+    with _span("ht.linalg.hsvd", rows=m, cols=n, split=A.split, **attrs):
+        comm = A.comm
+        dtype = jnp.float32 if not types.heat_type_is_inexact(A.dtype) else A.dtype.jax_type()
 
-    if maxrank is None:
-        maxrank = min(m, n)
-    trunc = min(maxrank + safetyshift, m)
-    p = comm.size if A.split == 1 else 1
+        if maxrank is None:
+            maxrank = min(m, n)
+        trunc = min(maxrank + safetyshift, m)
+        p = comm.size if A.split == 1 else 1
+        dense = A._dense()
+        env_cfg = _hsvd_env_cfg()
+        u_split = A.split if A.split == 0 else None
+        v_split = A.split if A.split == 1 else None
 
-    if rtol is None:
-        # fixed-rank fast path: cast, factorization, truncation and the
-        # error estimate are ONE device program — no eager dispatches
-        # around the factorization
-        k = min(maxrank, trunc)
-        outs = _hsvd_rank_jit(
-            A._dense(), trunc, p, no_of_merges, k, compute_sv, str(jnp.dtype(dtype)),
-            syrk_ok=comm.size == 1, env_cfg=_hsvd_env_cfg(),
-        )
-        U = DNDarray.from_dense(outs[0], A.split if A.split == 0 else None, A.device, comm)
-        if compute_sv:
-            u_k, sv, v_k, rel_err = outs
+        if rtol is None:
+            # fixed-rank fast path: cast, factorization, truncation and the
+            # error estimate are ONE device program — no eager dispatches
+            # around the factorization
+            k = min(maxrank, trunc)
+            with _span("hsvd.dispatch", path="rank"):
+                outs = _hsvd_rank_jit(
+                    dense, trunc, p, no_of_merges, k, compute_sv, str(jnp.dtype(dtype)),
+                    syrk_ok=comm.size == 1, env_cfg=env_cfg,
+                )
+            with _span("hsvd.wrap"):
+                U = DNDarray.from_dense(outs[0], u_split, A.device, comm)
+                if not compute_sv:
+                    return U, outs[2]
+                S = DNDarray.from_dense(outs[1], None, A.device, comm)
+                V = DNDarray.from_dense(outs[2], v_split, A.device, comm)
+            return U, S, V, outs[3]
+
+        dense = dense.astype(dtype)
+        with _span("hsvd.dispatch", path="rtol"):
+            u_fin, s_fin, v_fin, discarded_sq, total_sq = _hsvd_core(
+                dense, trunc, p, no_of_merges, syrk_ok=comm.size == 1, env_cfg=env_cfg,
+            )
+
+        # rtol path: smallest k with (energy discarded by leaf/merge
+        # truncations + energy of the dropped tail of s_fin) <= rtol^2 *
+        # ||A||_F^2 — k is a host shape decision, so this path syncs once
+        kept = jnp.cumsum(s_fin.astype(jnp.float32) ** 2)
+        resid = jnp.sum(s_fin.astype(jnp.float32) ** 2) - kept + discarded_sq
+        ok = np.asarray(resid <= (rtol**2) * total_sq)
+        k = int(np.argmax(ok)) + 1 if ok.any() else int(s_fin.shape[0])
+        k = min(k, maxrank)
+        u_k = u_fin[:, :k]
+        sv = s_fin[:k]
+
+        # relative error estimate ||A - U U^T A||_F / ||A||_F (svdtools.py:430+)
+        approx_sq = jnp.sum(sv**2)
+        rel_err = jnp.sqrt(jnp.maximum(total_sq - approx_sq, 0.0) / jnp.maximum(total_sq, 1e-30))
+
+        # the error estimate stays a lazy 0-d jax scalar: float()-ing it here
+        # would force a device->host round trip inside every hsvd call;
+        # callers convert on use
+        v_k = v_fin[:, :k] if compute_sv else None
+        with _span("hsvd.wrap"):
+            U = DNDarray.from_dense(u_k, u_split, A.device, comm)
+            if not compute_sv:
+                return U, rel_err
             S = DNDarray.from_dense(sv, None, A.device, comm)
-            V = DNDarray.from_dense(v_k, A.split if A.split == 1 else None, A.device, comm)
-            return U, S, V, rel_err
-        _, _, rel_err = outs
-        return U, rel_err
-
-    dense = A._dense().astype(dtype)
-    u_fin, s_fin, v_fin, discarded_sq, total_sq = _hsvd_core(
-        dense, trunc, p, no_of_merges, syrk_ok=comm.size == 1,
-        env_cfg=_hsvd_env_cfg(),
-    )
-
-    # rtol path: smallest k with (energy discarded by leaf/merge
-    # truncations + energy of the dropped tail of s_fin) <= rtol^2 *
-    # ||A||_F^2 — k is a host shape decision, so this path syncs once
-    kept = jnp.cumsum(s_fin.astype(jnp.float32) ** 2)
-    resid = jnp.sum(s_fin.astype(jnp.float32) ** 2) - kept + discarded_sq
-    ok = np.asarray(resid <= (rtol**2) * total_sq)
-    k = int(np.argmax(ok)) + 1 if ok.any() else int(s_fin.shape[0])
-    k = min(k, maxrank)
-    U = DNDarray.from_dense(u_fin[:, :k], A.split if A.split == 0 else None, A.device, comm)
-    sv = s_fin[:k]
-
-    # relative error estimate ||A - U U^T A||_F / ||A||_F (svdtools.py:430+)
-    approx_sq = jnp.sum(sv**2)
-    rel_err = jnp.sqrt(jnp.maximum(total_sq - approx_sq, 0.0) / jnp.maximum(total_sq, 1e-30))
-
-    # the error estimate stays a lazy 0-d jax scalar: float()-ing it here
-    # would force a device->host round trip inside every hsvd call;
-    # callers convert on use
-    if compute_sv:
-        S = DNDarray.from_dense(sv, None, A.device, comm)
-        V = DNDarray.from_dense(v_fin[:, :k], A.split if A.split == 1 else None, A.device, comm)
+            V = DNDarray.from_dense(v_k, v_split, A.device, comm)
         return U, S, V, rel_err
-    return U, rel_err
 
 
 def _gram(blk: jnp.ndarray, syrk_ok: bool = False) -> jnp.ndarray:

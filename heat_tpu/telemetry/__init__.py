@@ -106,7 +106,7 @@ from .tracing import (
     tracez_report,
     use_context,
 )
-from .profiling import annotate, monitor, start_trace, stop_trace, trace
+from .profiling import annotate, idle_by_span, monitor, start_trace, stop_trace, trace
 from .aggregate import (
     gather_snapshots,
     merge_snapshots,
@@ -202,6 +202,7 @@ __all__ = [
     "gauge",
     "get_spans",
     "histogram",
+    "idle_by_span",
     "merge_snapshots",
     "monitor",
     "record_span",

@@ -15,14 +15,21 @@ backward-compatible alias).
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import functools
+import glob
+import os
 import time
 from typing import Callable, Optional
 
 import jax
 
-__all__ = ["annotate", "monitor", "start_trace", "stop_trace", "trace"]
+__all__ = ["annotate", "idle_by_span", "monitor", "start_trace", "stop_trace", "trace"]
+
+#: what an idle stretch is given to when no program span is open: the
+#: caller's own code (its loop, its ``block_until_ready``)
+OUTSIDE = "outside ht.*"
 
 
 def start_trace(log_dir: str) -> None:
@@ -84,3 +91,117 @@ def monitor(name: Optional[str] = None):
 def _is_jax_tree(x) -> bool:
     leaves = jax.tree_util.tree_leaves(x)
     return any(isinstance(l, jax.Array) for l in leaves)
+
+
+def _busy(events) -> list:
+    """Merged busy intervals [[start, end], ...] of one device's operations
+    (a ``while`` holds its body's operations, so they overlap)."""
+    merged = []
+    for start, end in sorted((s, s + d) for _, s, d in events):
+        if merged and start <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], end)
+        else:
+            merged.append([start, end])
+    return merged
+
+
+def _innermost(spans) -> list:
+    """One thread's properly nested spans, flattened: [(start, end, name)]
+    sorted and disjoint, each stretch named by the innermost span open in it."""
+    segs, stack, cursor = [], [], 0.0  # stack of (name, end)
+
+    def close(until):
+        nonlocal cursor
+        while stack and stack[-1][1] <= until:
+            name, end = stack.pop()
+            if end > cursor:
+                segs.append((cursor, end, name))
+                cursor = end
+
+    for name, start, dur in sorted(spans, key=lambda e: (e[1], -e[2])):
+        close(start)
+        if stack and start > cursor:
+            segs.append((cursor, start, stack[-1][0]))
+        cursor = start
+        stack.append((name, start + dur))
+    close(float("inf"))
+    return segs
+
+
+def attribute_idle(device_ops, thread_spans) -> dict:
+    """The pure part of :func:`idle_by_span`, on lists of ``(name,
+    start_ns, duration_ns)`` of one clock: ``device_ops`` holds one list a
+    device, ``thread_spans`` the program spans of the calling thread.
+
+    The traced span of time runs from the first operation of any device to
+    the last one's end.  Each device's idle gaps in it are split where spans
+    begin and end, and each stretch goes to the innermost span open in it, or
+    to ``outside ht.*``.  Idle seconds add up over the devices."""
+    device_ops = [ops for ops in device_ops if ops]
+    if not device_ops:
+        return {"idle": [], "traced_s": 0.0, "busy_share": 0.0, "longest": None}
+    t0 = min(s for ops in device_ops for _, s, _ in ops)
+    t1 = max(s + d for ops in device_ops for _, s, d in ops)
+    segs = _innermost(thread_spans)
+    seg_starts = [seg[0] for seg in segs]
+    tally, longest, busy_ns = {}, (0.0, OUTSIDE), 0.0  # tally: name -> [idle ns, gaps]
+    for ops in device_ops:
+        merged = _busy(ops)
+        busy_ns += sum(e - s for s, e in merged)
+        edges = [t0] + [t for pair in merged for t in pair] + [t1]
+        for a, b in zip(edges[0::2], edges[1::2]):  # the gaps: before, between and after
+            got = {}
+            for s, e, name in segs[max(bisect.bisect_right(seg_starts, a) - 1, 0):]:
+                if s >= b:
+                    break
+                if min(e, b) > max(s, a):
+                    got[name] = got.get(name, 0.0) + min(e, b) - max(s, a)
+            got[OUTSIDE] = (b - a) - sum(got.values())
+            for name, ns in got.items():
+                if ns > 0:
+                    entry = tally.setdefault(name, [0.0, 0])
+                    entry[0] += ns
+                    entry[1] += 1
+                    longest = max(longest, (ns / 1e9, name))
+    return {
+        "idle": sorted(((n, ns / 1e9, gaps) for n, (ns, gaps) in tally.items()), key=lambda r: -r[1]),
+        "traced_s": (t1 - t0) / 1e9,
+        "busy_share": busy_ns / (len(device_ops) * (t1 - t0)),
+        "longest": longest,
+    }
+
+
+def idle_by_span(trace_dir: str) -> dict:
+    """The device's idle time by what the host was doing, from the newest
+    trace under ``trace_dir`` (as :func:`start_trace` / ``jax.profiler``
+    leaves it; host tracer level 1 or more keeps the spans' annotations).
+
+    Both planes come from one profiler session and so share its clock: the
+    union of the ``XLA Ops`` of each ``/device:TPU:*`` plane is busy time,
+    and every idle gap is given to the innermost program span open on the
+    calling thread (the ``/host:CPU`` line with most span events), or to
+    ``outside ht.*``.  A program span is an event named as a span in this
+    process's ring, so call it in the process that was traced.  Returns
+    ``{"idle": [(span name, idle seconds, gaps)] largest first, "traced_s",
+    "busy_share", "longest": (seconds, span name) of one stretch}``."""
+    from jax.profiler import ProfileData
+
+    from .spans import get_spans
+
+    paths = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise FileNotFoundError(f"no xplane under {trace_dir}")
+    names = {rec.name for rec in get_spans()}
+    device_ops, threads = [], []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if plane.name.startswith("/device:TPU:"):
+            device_ops += [
+                [(e.name, e.start_ns, e.duration_ns) for e in line.events]
+                for line in plane.lines if line.name == "XLA Ops"
+            ]
+        elif plane.name == "/host:CPU":
+            threads += [
+                [(e.name, e.start_ns, e.duration_ns) for e in line.events if e.name in names]
+                for line in plane.lines
+            ]
+    return attribute_idle(device_ops, max(threads, key=len, default=[]))

@@ -16,6 +16,7 @@ described chip can be written to the cache but not read back).
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -99,6 +100,57 @@ def test_gram_syrk_north_star_shape(one_chip, for_the_chip):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _entry_instructions(compiled) -> list:
+    """[(name, opcode, result shape, operand names)] of the entry computation."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", compiled.as_text(), re.S | re.M).group(1)
+    found = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([^ ]+) = (.*?) ([a-z][a-z\-]*)\(([^)]*)\)", line)
+        if m:
+            found.append((m.group(1), m.group(3), re.sub(r"\{[^}]*\}", "", m.group(2)),
+                          re.findall(r"%([^ ,)]+)", m.group(4))))
+    return found
+
+
+def test_hsvd_rank_program_names_its_kernel_and_keeps_its_passes(one_chip, for_the_chip):
+    """The benchmark's cell, 12,582,912 x 128 at rank 10: the trace finds
+    the Gram kernel by the name `gram_syrk_ms` reads, and the operations that
+    read or write an array of 12,582,912 rows (the passes over A and U) are
+    the three of the parent commit (PR 24), opcode and shape; named scopes
+    and spans added none."""
+    from heat_tpu.core.linalg import svdtools
+
+    rows = 12_582_912
+    compiled = svdtools._hsvd_rank_jit.lower(
+        _sds((rows, 128), jnp.float32, one_chip), 15, 1, 2, 10, True, "float32",
+        syrk_ok=True, env_cfg=("", "", ""),
+    ).compile()
+    instructions = _entry_instructions(compiled)
+    kernels_found = [name for name, opcode, _, _ in instructions
+                     if opcode == "custom-call" and name.startswith("gram_syrk")]
+    assert len(kernels_found) == 1, [i[:3] for i in instructions if i[1] == "custom-call"]
+    tall = {name for name, _, shape, _ in instructions if str(rows) in shape}
+    passes = [(opcode, shape) for name, opcode, shape, operands in instructions
+              if opcode not in ("parameter", "tuple") and (name in tall or tall & set(operands))]
+    assert passes == [
+        ("custom-call", "f32[128,128]"),    # gram_syrk reads A
+        ("fusion", f"f32[{rows},15]"),      # A V at the working width
+        ("fusion", f"f32[{rows},10]"),      # slice to rank 10 and 1/s: reads and writes U
+    ]
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_lloyd_kernel_carries_its_name(one_chip, for_the_chip):
+    from heat_tpu.core import kernels
+
+    n, f, k = 2**24, 16, 8
+    compiled = kernels._lloyd_single.lower(
+        _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip), n_true=n
+    ).compile()
+    assert [name for name, opcode, _, _ in _entry_instructions(compiled)
+            if opcode == "custom-call" and name.startswith("lloyd_update")]
 
 
 def test_rfft3_leading_512_cubed(one_chip, for_the_chip):

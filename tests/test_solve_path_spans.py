@@ -1,0 +1,232 @@
+"""Spans at the layer boundaries of the two solve paths the chip benchmark
+times (``ht.linalg.hsvd*``, ``KMeans.fit``), the operator's function that
+reads them beside the device plane (``idle_by_span``), and the benchmark's
+three per-layer readers.  All on the CPU: counts, names and containment,
+never a time.
+"""
+
+import glob
+import os
+import sys
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+import heat_tpu as ht
+from heat_tpu import telemetry
+from heat_tpu.parallel.comm import Communication
+from heat_tpu.telemetry.profiling import OUTSIDE, attribute_idle, idle_by_span
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO_ROOT)
+
+from chipbench.run import load_py  # noqa: E402
+
+ROWS, COLS = 512, 16
+
+
+@pytest.fixture()
+def one_device():
+    """One device, as on one chip: no collective is accounted, so the
+    ring holds the solve path's spans and nothing else."""
+    ht.use_comm(Communication(jax.devices()[:1]))
+    prev = telemetry.set_tracing(True)
+    try:
+        yield
+    finally:
+        telemetry.set_tracing(prev)
+        telemetry.clear_spans()
+        ht.use_comm(ht.WORLD)
+
+
+def _data():
+    x = np.random.default_rng(7).standard_normal((ROWS, COLS)).astype(np.float32)
+    return ht.array(x * np.geomspace(1.0, 1e-2, COLS, dtype=np.float32), split=0)
+
+
+def _hsvd_rank(a):
+    U, S, V, err = ht.linalg.hsvd_rank(a, 4, compute_sv=True)
+    return [U.numpy(), S.numpy(), V.numpy(), np.asarray(err)]
+
+
+def _hsvd_rtol(a):
+    U, S, V, err = ht.linalg.hsvd_rtol(a, 1e-1, compute_sv=True)
+    return [U.numpy(), S.numpy(), V.numpy(), np.asarray(err)]
+
+
+def _kmeans(a):
+    km = ht.cluster.KMeans(n_clusters=3, init="random", max_iter=5, random_state=2).fit(a)
+    return [km.cluster_centers_.numpy(), km.labels_.numpy(), np.asarray(km.inertia_)]
+
+
+#: solve -> (root, its attributes, children in the order they open)
+SOLVES = {
+    "hsvd_rank": (_hsvd_rank, "ht.linalg.hsvd", {"rows": ROWS, "cols": COLS, "split": 0, "rank": 4},
+                  [("hsvd.dispatch", {"path": "rank"}), ("hsvd.wrap", {})]),
+    "hsvd_rtol": (_hsvd_rtol, "ht.linalg.hsvd", {"rows": ROWS, "cols": COLS, "split": 0, "rtol": 1e-1},
+                  [("hsvd.dispatch", {"path": "rtol"}), ("hsvd.wrap", {})]),
+    "kmeans": (_kmeans, "ht.cluster.KMeans.fit", {"rows": ROWS, "features": COLS, "clusters": 3, "max_iter": 5},
+               [("kmeans.init", {}), ("kmeans.loop", {}), ("kmeans.assign", {})]),
+}
+
+
+def _end(rec):
+    return rec.start_ns + rec.duration_ns
+
+
+@pytest.mark.parametrize("solve", sorted(SOLVES))
+def test_solve_leaves_the_tables_spans(one_device, solve):
+    fn, root_name, root_attrs, children = SOLVES[solve]
+    a = _data()
+    fn(a)  # the first call compiles: `dispatch.compile` of the eager helpers lands here
+    telemetry.clear_spans()
+    fn(a)
+    spans = sorted(telemetry.get_spans(), key=lambda r: r.start_ns)
+    assert [r.name for r in spans] == [root_name] + [name for name, _ in children]
+    root, kids = spans[0], spans[1:]
+    assert root.depth == 0 and root.attrs == root_attrs
+    assert all(isinstance(v, (int, float, str, type(None))) for v in root.attrs.values())
+    for kid, (_, attrs), after in zip(kids, children, [root.start_ns] + [_end(k) for k in kids]):
+        assert kid.depth == 1 and kid.attrs == attrs
+        assert after <= kid.start_ns and _end(kid) <= _end(root)  # inside the root, one after the other
+    assert {r.thread_id for r in spans} == {threading.get_ident()}
+    assert {r.trace_id for r in spans} == {None}  # no identifier beside thread, depth and time
+
+
+def test_resumable_fit_initializes_inside_its_loop(one_device, tmp_path):
+    a = _data()
+    ht.cluster.KMeans(n_clusters=3, init="random", max_iter=4, random_state=2,
+                      checkpoint_every=2, checkpoint_dir=str(tmp_path)).fit(a)
+    by_name = {r.name: r for r in telemetry.get_spans()}
+    loop, init = by_name["kmeans.loop"], by_name["kmeans.init"]
+    assert (loop.depth, init.depth, by_name["kmeans.assign"].depth) == (1, 2, 1)
+    assert loop.start_ns <= init.start_ns and _end(init) <= _end(loop)
+
+
+@pytest.mark.parametrize("solve", sorted(SOLVES))
+def test_tracing_off_leaves_nothing_and_changes_no_result(one_device, solve):
+    fn = SOLVES[solve][0]
+    a = _data()
+    traced = fn(a)
+    telemetry.clear_spans()
+    telemetry.set_tracing(False)
+    plain = fn(a)
+    assert telemetry.get_spans() == []
+    for t, p in zip(traced, plain):
+        np.testing.assert_array_equal(t, p)
+
+
+@pytest.mark.parametrize("solve,root,child", [("hsvd_rank", "ht.linalg.hsvd", "hsvd.dispatch"),
+                                              ("kmeans", "ht.cluster.KMeans.fit", "kmeans.loop")])
+def test_spans_land_in_the_profilers_host_plane(one_device, tmp_path, solve, root, child):
+    """Under the options ``chipbench/run.py`` traces with, the annotation of
+    each span is kept, on the calling thread's line, the child in the root."""
+    from jax.profiler import ProfileData
+
+    fn = SOLVES[solve][0]
+    a = _data()
+    fn(a)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        fn(a)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    (host,) = [p for p in ProfileData.from_file(path).planes if p.name == "/host:CPU"]
+    lines = [[(e.name, e.start_ns, e.duration_ns) for e in line.events if e.name in (root, child)]
+             for line in host.lines]
+    (line,) = [evs for evs in lines if evs]  # one thread holds them all
+    (r,) = [e for e in line if e[0] == root]
+    (c,) = [e for e in line if e[0] == child]
+    assert r[1] <= c[1] and c[1] + c[2] <= r[1] + r[2]
+    # no device plane on the CPU: the operator's function finds nothing to split
+    assert idle_by_span(str(tmp_path)) == {"idle": [], "traced_s": 0.0, "busy_share": 0.0, "longest": None}
+
+
+def test_idle_by_span_wants_a_trace(tmp_path):
+    with pytest.raises(FileNotFoundError):
+        idle_by_span(str(tmp_path))
+
+
+# ---------------------------------------------------------------- attribute_idle
+# one solve on the host's thread: root 100..300, dispatch 110..150, wrap 200..260
+HOST = [("ht.linalg.hsvd", 100.0, 200.0), ("hsvd.dispatch", 110.0, 40.0), ("hsvd.wrap", 200.0, 60.0)]
+
+
+def _ops(*intervals):
+    return [(f"%op.{i}", float(s), float(e - s)) for i, (s, e) in enumerate(intervals)]
+
+
+IDLE_CASES = {
+    # the device's gap 120..140 lies wholly inside the dispatch span
+    "inside_a_child": ([_ops((0, 120), (140, 400))], {"hsvd.dispatch": (20, 1)}, 400, 380 / 400,
+                       (20, "hsvd.dispatch")),
+    # 140..220 straddles dispatch (10), the root's own time (50) and wrap (20)
+    "straddles_spans": ([_ops((0, 140), (220, 400))],
+                        {"ht.linalg.hsvd": (50, 1), "hsvd.wrap": (20, 1), "hsvd.dispatch": (10, 1)}, 400, 320 / 400,
+                        (50, "ht.linalg.hsvd")),
+    # 20..60 and 320..380: no span is open, the caller's own code
+    "outside_every_span": ([_ops((0, 20), (60, 320), (380, 400))], {OUTSIDE: (100, 2)}, 400, 300 / 400,
+                           (60, OUTSIDE)),
+    # two devices over one traced span 0..400: the second starts late (0..50 outside) and idles 120..140
+    "two_devices": ([_ops((0, 400)), _ops((50, 120), (140, 400))],
+                    {OUTSIDE: (50, 1), "hsvd.dispatch": (20, 1)}, 400, (400 + 330) / 800, (50, OUTSIDE)),
+    # a `while` holds its body's operations: nested events are one busy interval
+    "nested_operations": ([_ops((0, 130), (10, 60), (70, 120), (145, 400))], {"hsvd.dispatch": (15, 1)}, 400,
+                          385 / 400, (15, "hsvd.dispatch")),
+    "no_device_plane": ([], {}, 0, 0.0, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(IDLE_CASES))
+def test_attribute_idle(case):
+    device_ops, want, traced_ns, busy_share, longest = IDLE_CASES[case]
+    got = attribute_idle(device_ops, HOST)
+    assert {name: (pytest.approx(s * 1e9), gaps) for name, s, gaps in got["idle"]} == want
+    assert [s for _, s, _ in got["idle"]] == sorted((s for _, s, _ in got["idle"]), reverse=True)
+    assert got["traced_s"] == pytest.approx(traced_ns / 1e9) and got["busy_share"] == pytest.approx(busy_share)
+    assert got["longest"] == (longest and (pytest.approx(longest[0] / 1e9), longest[1]))
+
+
+# ------------------------------------------------------------------- the readers
+def _ring(solves, dispatch_ns=400_000, root_ns=1_000_000, warmup=2):
+    """A ring as a run leaves it: ``warmup`` solves, then the window's."""
+    telemetry.clear_spans()
+    for i in range(warmup + solves):
+        t = i * 10_000_000
+        telemetry.record_span("hsvd.dispatch", t + 100_000, 5 * dispatch_ns if i < warmup else dispatch_ns)
+        telemetry.record_span("hsvd.wrap", t + 700_000, 200_000)
+        telemetry.record_span("ht.linalg.hsvd", t, 5 * root_ns if i < warmup else root_ns, rows=1)
+
+
+TOP_OPS = [["%fusion.2 fusion f32[12582912,15]", 0.9], ["%gram_syrk.1 custom-call:tpu_custom_call f32[128,128]", 0.5]]
+
+#: (reader, case) -> what the ring / the trace holds, and the reading wanted (None: nothing read, with a note)
+READER_CASES = {
+    ("dispatch_enqueue_ms", "read"): (lambda: _ring(5), 5, TOP_OPS, 0.4),
+    ("dispatch_enqueue_ms", "ring_wrapped"): (lambda: _ring(3, warmup=0), 5, TOP_OPS, None),
+    ("dispatch_enqueue_ms", "tracing_off"): (telemetry.clear_spans, 5, TOP_OPS, None),
+    ("api_host_ms", "read"): (lambda: _ring(5), 5, TOP_OPS, 0.6),
+    ("api_host_ms", "ring_wrapped"): (lambda: _ring(3, warmup=0), 5, TOP_OPS, None),
+    ("api_host_ms", "tracing_off"): (telemetry.clear_spans, 5, TOP_OPS, None),
+    ("gram_syrk_ms", "read"): (telemetry.clear_spans, 5, TOP_OPS, 100.0),
+    ("gram_syrk_ms", "kernel_not_taken"): (telemetry.clear_spans, 5, TOP_OPS[:1], None),
+    ("gram_syrk_ms", "no_device_plane"): (telemetry.clear_spans, 5, [], None),
+}
+
+
+@pytest.mark.parametrize("reader,case", sorted(READER_CASES))
+def test_layer_metric_readers(one_device, reader, case):
+    fill, solves, top_ops, want = READER_CASES[(reader, case)]
+    fill()
+    run = {"trace": {"top_ops": top_ops, "busy_s": 1.0}, "solves": solves, "window_s": 2.0, "notes": {}}
+    got = load_py("layer_metrics", reader).read(run)
+    if want is None:
+        assert got is None and reader in run["notes"]
+    else:
+        assert got == pytest.approx(want) and run["notes"] == {}
