@@ -122,9 +122,19 @@ def _hsvd_rank_jit(dense, trunc: int, p: int, no_of_merges: int, k: int, compute
     error estimate — one device program, zero per-call eager dispatches.
     The eager version of this tail (astype + four slices + two reductions
     + re-placements) is a dozen host dispatches around one device
-    program."""
+    program.
+
+    The final rank ``k`` is static here, so it goes down to ``_hsvd_body``
+    as the output width: the full-height products are taken at ``k``
+    columns and ``U`` is written once.  Nothing below reads columns ``k``
+    to ``trunc`` of ``U`` (the error estimate needs ``s`` and ``total_sq``
+    only), and at the working width they cost a second pass over all ``m``
+    rows to slice away.  ``u`` comes back at ``k`` columns, so
+    ``hsvd.truncate`` touches nothing of ``m`` rows."""
     dense = dense.astype(jnp.dtype(dtype_name))
-    u, s, v, _disc, total_sq = _hsvd_body(dense, trunc, p, no_of_merges, compute_v, syrk_ok)
+    u, s, v, _disc, total_sq = _hsvd_body(
+        dense, trunc, p, no_of_merges, compute_v, syrk_ok, out_width=k
+    )
     with jax.named_scope("hsvd.truncate"):
         sv = s[:k]
         approx_sq = jnp.sum(sv.astype(jnp.float32) ** 2)
@@ -132,11 +142,17 @@ def _hsvd_rank_jit(dense, trunc: int, p: int, no_of_merges: int, k: int, compute
             jnp.maximum(total_sq - approx_sq, 0.0) / jnp.maximum(total_sq, 1e-30)
         )
         if compute_v:
-            return u[:, :k], sv, v[:, :k], rel_err
-        return u[:, :k], sv, rel_err
+            return u, sv, v[:, :k], rel_err
+        return u, sv, rel_err
 
 
-def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, compute_v: bool, syrk_ok: bool = False):
+def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, compute_v: bool, syrk_ok: bool = False, out_width: Optional[int] = None):
+    """(u_fin, s_fin, v_fin, discarded_sq, total_sq).  ``out_width`` is the
+    static number of columns the caller will keep of the full-height
+    results (``u_fin``; in the merge tree ``v_fin`` too); ``None`` keeps
+    the working width, for the rtol path, whose rank is a host decision
+    made after the program returns.  ``s_fin`` always has the working
+    width."""
     m, n = dense.shape
 
     # leaf level: column blocks = the canonical shards of the split axis
@@ -161,6 +177,13 @@ def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, comput
         # kernel path needs a SINGLE-DEVICE operand (pallas_call is not
         # GSPMD-partitionable), so the caller gates ``syrk_ok`` on the
         # communication layout outside the jit.
+        # The projection is taken at ``ko`` columns, the rank path's final
+        # rank: sliced after, the extra trunc - k columns cost a second
+        # pass over all m rows of U.  Multiplying by 1/s AFTER the product
+        # keeps every kept column's arithmetic (scaling V first differs in
+        # the last bit), and XLA fuses the multiply into the product's
+        # output.  s, disc, total_sq and V come from the n x n eigh at the
+        # working width as before: they are tiny.
         # The scopes are names for the device trace (op_name metadata);
         # they change no operation of the compiled program.
         with jax.named_scope("hsvd.gram"):
@@ -177,10 +200,11 @@ def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, comput
             keep = lam_k > eps * jnp.maximum(lam_k[0], 1e-30)
             s_fin = jnp.where(keep, jnp.sqrt(lam_k), 0.0)
             inv_s = jnp.where(keep, 1.0 / jnp.maximum(jnp.sqrt(lam_k), 1e-30), 0.0)
+        ko = kk if out_width is None else min(out_width, kk)
         with jax.named_scope("hsvd.project"):
             u_fin = (
-                jnp.matmul(dense, v[:, :kk], precision=jax.lax.Precision.HIGHEST)
-                * inv_s[None, :]
+                jnp.matmul(dense, v[:, :ko], precision=jax.lax.Precision.HIGHEST)
+                * inv_s[None, :ko]
             )
         v_fin = v[:, :kk] if compute_v else None
         return u_fin, s_fin, v_fin, disc, total_sq
@@ -228,7 +252,11 @@ def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, comput
         factors, disc, _ = _level(cats)
         discarded_sq = discarded_sq + disc
 
+    # The leaves and merges above keep ``trunc``: the safety shift is what
+    # makes the merged subspace accurate.  Only the final factorization's
+    # full-height products narrow to the caller's ``out_width``.
     us = factors[0]
+    ko = us.shape[1] if out_width is None else min(out_width, us.shape[1])
     if us.shape[0] >= us.shape[1]:
         # final factorization through the Gram matrix as well — us is
         # (m, <= trunc), so eigh is tiny and the two matmuls ride the MXU
@@ -245,16 +273,22 @@ def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, comput
         keep = lam_fin > eps * jnp.maximum(lam_fin[0], 1e-30)
         s_fin = jnp.where(keep, jnp.sqrt(lam_fin), 0.0)
         inv_s = jnp.where(keep, 1.0 / jnp.maximum(jnp.sqrt(lam_fin), 1e-30), 0.0)
-        u_fin = jnp.matmul(us, v_eig, precision=jax.lax.Precision.HIGHEST) * inv_s[None, :]
+        u_fin = (
+            jnp.matmul(us, v_eig[:, :ko], precision=jax.lax.Precision.HIGHEST)
+            * inv_s[None, :ko]
+        )
     else:
         u_fin, s_fin, _ = jnp.linalg.svd(us, full_matrices=False)
+        u_fin = u_fin[:, :ko]
 
-    # V = A^T U diag(1/s) at full width (sliced by the host); skipped
-    # entirely when the caller doesn't want V — it is a second full-size
-    # MXU matmul
+    # V = A^T U diag(1/s) at U's width; skipped entirely when the caller
+    # doesn't want V — it is a second full-size MXU matmul
     if compute_v:
         inv_sv = jnp.where(s_fin > 0, 1.0 / jnp.maximum(s_fin, 1e-30), 0.0)
-        v_fin = jnp.matmul(dense.T, u_fin, precision=jax.lax.Precision.HIGHEST) * inv_sv[None, :]
+        v_fin = (
+            jnp.matmul(dense.T, u_fin, precision=jax.lax.Precision.HIGHEST)
+            * inv_sv[None, :ko]
+        )
     else:
         v_fin = None
     return u_fin, s_fin, v_fin, discarded_sq, total_sq

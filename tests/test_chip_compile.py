@@ -114,17 +114,20 @@ def _entry_instructions(compiled) -> list:
     return found
 
 
-def test_hsvd_rank_program_names_its_kernel_and_keeps_its_passes(one_chip, for_the_chip):
+@pytest.mark.parametrize("trunc", [15, 10], ids=["trunc15", "trunc_eq_k"])
+def test_hsvd_rank_program_names_its_kernel_and_makes_two_passes(one_chip, for_the_chip, trunc):
     """The benchmark's cell, 12,582,912 x 128 at rank 10: the trace finds
     the Gram kernel by the name `gram_syrk_ms` reads, and the operations that
     read or write an array of 12,582,912 rows (the passes over A and U) are
-    the three of the parent commit (PR 24), opcode and shape; named scopes
-    and spans added none."""
+    two: the Gram, and A V at the final rank with 1/s fused into its output.
+    That fusion is the program's first result (no copy, slice or layout
+    change after it) and the program keeps no temporary of U's size.  With
+    ``trunc == k`` (``safetyshift=0``, the bypass) the passes are the same."""
     from heat_tpu.core.linalg import svdtools
 
     rows = 12_582_912
     compiled = svdtools._hsvd_rank_jit.lower(
-        _sds((rows, 128), jnp.float32, one_chip), 15, 1, 2, 10, True, "float32",
+        _sds((rows, 128), jnp.float32, one_chip), trunc, 1, 2, 10, True, "float32",
         syrk_ok=True, env_cfg=("", "", ""),
     ).compile()
     instructions = _entry_instructions(compiled)
@@ -132,13 +135,15 @@ def test_hsvd_rank_program_names_its_kernel_and_keeps_its_passes(one_chip, for_t
                      if opcode == "custom-call" and name.startswith("gram_syrk")]
     assert len(kernels_found) == 1, [i[:3] for i in instructions if i[1] == "custom-call"]
     tall = {name for name, _, shape, _ in instructions if str(rows) in shape}
-    passes = [(opcode, shape) for name, opcode, shape, operands in instructions
+    passes = [(name, opcode, shape) for name, opcode, shape, operands in instructions
               if opcode not in ("parameter", "tuple") and (name in tall or tall & set(operands))]
-    assert passes == [
+    assert [p[1:] for p in passes] == [
         ("custom-call", "f32[128,128]"),    # gram_syrk reads A
-        ("fusion", f"f32[{rows},15]"),      # A V at the working width
-        ("fusion", f"f32[{rows},10]"),      # slice to rank 10 and 1/s: reads and writes U
+        ("fusion", f"f32[{rows},10]"),      # A V at rank 10, times 1/s: reads A, writes U once
     ]
+    (root,) = [operands for _, opcode, _, operands in instructions if opcode == "tuple"]
+    assert root[0] == passes[1][0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
     assert _device_bytes(compiled) < HBM_BYTES
 
 

@@ -235,3 +235,175 @@ def test_hsvd_float64_high_condition(ht):
     np.testing.assert_allclose(np.asarray(s._dense()), sv, rtol=1e-8)
     rec = u.numpy() @ np.diag(np.asarray(s._dense())) @ v.numpy().T
     assert np.linalg.norm(A - rec) / np.linalg.norm(A) < 1e-8
+
+
+# --- the fixed-rank path projects at the final rank (PR 26) -----------------
+# _hsvd_rank_jit hands its static k down to _hsvd_body, which takes the
+# full-height products at k columns; _hsvd_core (the rtol path) keeps the
+# working width.  The kept columns must be _hsvd_core's.
+
+
+def _hsvd_matrix(m, n, rank=None, seed=0):
+    rng = np.random.default_rng(seed)
+    if rank is not None:  # rank-deficient: the keep mask zeroes columns past `rank`
+        return (rng.standard_normal((m, rank)) @ rng.standard_normal((rank, n))).astype(np.float32)
+    return (rng.standard_normal((m, n)) * np.geomspace(1.0, 1e-3, n)).astype(np.float32)
+
+
+def _assert_columns_close(got, want, tol=1e-6):
+    """Each column of ``got`` within ``tol`` of the column's norm from ``want``'s."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape, (got.shape, want.shape)
+    dist = np.linalg.norm(got - want, axis=0)
+    assert (dist <= tol * np.linalg.norm(want, axis=0)).all(), dist
+
+
+def _rank_against_core(a, trunc, p, k, syrk_ok=False):
+    import jax.numpy as jnp
+
+    from heat_tpu.core.linalg.svdtools import _hsvd_core, _hsvd_rank_jit
+
+    dense = jnp.asarray(a)
+    u, s, v, err = _hsvd_rank_jit(dense, trunc, p, 2, k, True, "float32", syrk_ok=syrk_ok)
+    u_w, s_w, v_w, _disc, total_sq = _hsvd_core(dense, trunc, p, 2, syrk_ok=syrk_ok)
+    want_err = jnp.sqrt(
+        jnp.maximum(total_sq - jnp.sum(s_w[:k].astype(jnp.float32) ** 2), 0.0)
+        / jnp.maximum(total_sq, 1e-30)
+    )
+    return (u, s, v, err), (u_w[:, :k], s_w[:k], v_w[:, :k], want_err)
+
+
+@pytest.mark.parametrize(
+    "m, n, maxrank, safetyshift, rank, syrk_ok",
+    [
+        (6155, 128, 10, 5, None, False),  # the shape tried in sizing
+        (6155, 128, 10, 5, None, True),   # through gram_syrk's gate
+        (500, 64, 10, 0, None, False),    # k == trunc: the bypass
+        (300, 12, 10, 5, None, False),    # trunc > n
+        (300, 8, 12, 5, None, False),     # maxrank > n: k is wider than U can be
+        (2000, 64, 10, 5, 5, False),      # keep mask zeroes columns 5..9, inside the first k
+        (2000, 64, 10, 0, 5, False),      # the same with k == trunc
+    ],
+)
+def test_hsvd_rank_single_leaf_keeps_cores_columns(m, n, maxrank, safetyshift, rank, syrk_ok):
+    a = _hsvd_matrix(m, n, rank, seed=m + n)
+    trunc = min(maxrank + safetyshift, m)
+    k = min(maxrank, trunc)
+    (u, s, v, err), (u_w, s_w, v_w, err_w) = _rank_against_core(a, trunc, 1, k, syrk_ok)
+    assert u.shape == (m, min(k, n)) and v.shape == (n, min(k, n)) and s.shape == (min(k, n),)
+    _assert_columns_close(u, u_w)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(s_w))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(v_w))
+    np.testing.assert_array_equal(np.asarray(err), np.asarray(err_w))
+    if rank is not None:  # dropped directions are zero columns, value and vector together
+        dropped = np.asarray(s) == 0
+        assert dropped[rank:].any() and not dropped[:rank].any()
+        assert (np.asarray(u)[:, dropped] == 0).all()
+
+
+@pytest.mark.parametrize(
+    "m, n, p, maxrank, safetyshift",
+    [
+        (512, 128, 4, 10, 5),   # two merge levels
+        (512, 96, 3, 10, 5),    # an odd leaf count
+        (512, 128, 8, 10, 0),   # k == trunc in the tree
+        (40, 64, 1, 10, 5),     # one wide leaf: the generic path without a merge
+        (200, 64, 4, 20, 5),    # trunc wider than a leaf (16 columns)
+    ],
+)
+def test_hsvd_rank_merge_tree_keeps_cores_columns(m, n, p, maxrank, safetyshift):
+    a = _hsvd_matrix(m, n, seed=m + n + p)
+    trunc = min(maxrank + safetyshift, m)
+    k = min(maxrank, trunc)
+    (u, s, v, err), (u_w, s_w, v_w, err_w) = _rank_against_core(a, trunc, p, k)
+    assert u.shape == (m, k) and v.shape == (n, k)
+    _assert_columns_close(u, u_w)
+    _assert_columns_close(v, v_w)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(s_w))
+    np.testing.assert_allclose(np.asarray(err), np.asarray(err_w), rtol=1e-6)
+
+
+@pytest.mark.parametrize("split", [0, 1])
+def test_hsvd_rank_public_api_keeps_cores_columns(split):
+    """``split=1`` on the suite's mesh is the merge tree over the mesh's column
+    blocks; ``split=0`` there is the single leaf with the XLA Gram."""
+    import jax.numpy as jnp
+
+    from heat_tpu.core.linalg.svdtools import _hsvd_core
+
+    a = _hsvd_matrix(1024, 128, seed=split)
+    A = ht.array(a, split=split)
+    U, S, V, err = ht.linalg.hsvd_rank(A, 10, compute_sv=True)
+    p = A.comm.size if split == 1 else 1
+    u_w, s_w, v_w, _disc, _total = _hsvd_core(
+        A._dense().astype(jnp.float32), 15, p, 2, syrk_ok=A.comm.size == 1
+    )
+    assert U.shape == (1024, 10) and V.shape == (128, 10)
+    _assert_columns_close(U.numpy(), u_w[:, :10])
+    _assert_columns_close(V.numpy(), v_w[:, :10])
+    np.testing.assert_array_equal(S.numpy(), np.asarray(s_w[:10]))
+    rec = U.numpy() @ np.diag(S.numpy()) @ V.numpy().T
+    assert np.linalg.norm(a - rec) / np.linalg.norm(a) <= 1.01 * float(err) + 1e-5
+
+
+def _tall_equations(jaxpr, rows):
+    """(primitive, columns) of every equation, nested programs included, that
+    yields a 2-d array of ``rows`` rows; the jit calls themselves left out."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        for sub in subs:
+            found += _tall_equations(sub, rows)
+        if subs:
+            continue
+        for out in eqn.outvars:
+            shape = getattr(out.aval, "shape", ())
+            if len(shape) == 2 and shape[0] == rows:
+                found.append((eqn.primitive.name, shape[1]))
+    return found
+
+
+@pytest.mark.parametrize(
+    "p, leaves_and_merges",
+    [
+        (1, []),
+        # two column blocks, their U s factors, the concatenation, its U s factor
+        (2, [("slice", 64), ("slice", 64), ("dot_general", 15), ("dot_general", 15),
+             ("concatenate", 30), ("dot_general", 15)]),
+    ],
+)
+def test_hsvd_rank_path_writes_nothing_tall_wider_than_k(p, leaves_and_merges):
+    """At (8192, 128), trunc 15, k 10: past the leaf and merge levels no
+    equation of the rank path yields 8,192 rows at more than 10 columns; the
+    rtol path's program still has its (m, trunc) result."""
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.core.linalg import svdtools
+
+    x = jax.ShapeDtypeStruct((8192, 128), jnp.float32)
+    rank_path = _tall_equations(jax.make_jaxpr(
+        lambda d: svdtools._hsvd_rank_jit(d, 15, p, 2, 10, True, "float32"))(x).jaxpr, 8192)
+    rtol_path = _tall_equations(jax.make_jaxpr(
+        lambda d: svdtools._hsvd_core(d, 15, p, 2))(x).jaxpr, 8192)
+    assert [e for e in rank_path if e[1] > 10] == leaves_and_merges
+    assert rank_path[len(leaves_and_merges):] == [("dot_general", 10), ("mul", 10)]
+    assert rtol_path == leaves_and_merges + [("dot_general", 15), ("mul", 15)]
+
+
+def test_hsvd_rank_with_k_equal_trunc_is_the_rtol_paths_program():
+    """``safetyshift=0``: nothing to narrow, so the body's equations are the
+    ones it has without an output width."""
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.core.linalg import svdtools
+
+    x = jax.ShapeDtypeStruct((8192, 128), jnp.float32)
+    for p in (1, 2):
+        narrowed = jax.make_jaxpr(
+            lambda d: svdtools._hsvd_body(d, 10, p, 2, True, out_width=10))(x)
+        plain = jax.make_jaxpr(lambda d: svdtools._hsvd_body(d, 10, p, 2, True))(x)
+        assert str(narrowed) == str(plain)
