@@ -75,7 +75,12 @@ __all__ = [
 POLICIES = {
     # KMeans predict is an argmin over euclidean distances: tolerant to
     # bf16 rounding of the cross term (norms and accumulation stay f32 —
-    # see spatial/distance.py), so it serves under a tolerance contract
+    # see spatial/distance.py), so it serves under a tolerance contract.
+    # The FIT's precision is not a policy but part of the program, the same
+    # on every backend and under every scope (cluster/kmeans.py::_half_d2):
+    # points rounded to bfloat16 in both Lloyd products, centers float32 in
+    # |c|^2 and in the cross term alike, float32 sums.  No mode here and no
+    # scope there switches it.
     "KMeans": {"mode": "tolerance", "rtol": 0.02, "compute_dtypes": ("float32", "bfloat16")},
     # median/medoid geometry ties break on exact comparisons; low
     # precision can flip a tie permanently -> bitwise only

@@ -60,20 +60,55 @@ def _lloyd_loop(xp: jax.Array, centers: jax.Array, n_true: int, k: int, max_iter
     return c, i, shift
 
 
+def _half_d2(xb, centers):
+    """``|c|^2 - 2 x.c`` for bfloat16 points ``xb``: the squared distance of
+    every row to every center, less the row's ``|x|^2``, which cannot change
+    the argmin.
+
+    The arithmetic is written here and not left to the backend's default (a
+    float32 product rounds both operands to bfloat16 on the MXU and neither on
+    the CPU).  The points enter rounded to bfloat16: one stream of half the
+    bytes, and a rounding that falls on either side of every boundary alike.
+    The centers enter in float32, in ``|c|^2`` and in the cross term, whose
+    product asks ``HIGH`` on their side (on the MXU several bfloat16 terms of
+    the centers against the one stream of points: measured as near float64 as
+    a float32 product at ``HIGHEST``; exact on the CPU): both terms come from
+    the SAME centers.  With the centers rounded to bfloat16 in the cross term
+    alone, or in both terms, every boundary lies off by some
+    ``x . (c - bf16(c))``, and 30 iterations over 10^8 overlapping points end
+    4e-3 to 1e-2 from the exact fit's centers, 40 times this form's distance
+    (PERF.md, PR 27)."""
+    c = centers.astype(jnp.float32)
+    xc = jax.lax.dot_general(
+        xb, c, (((1,), (1,)), ((), ())),
+        precision=(jax.lax.Precision.DEFAULT, jax.lax.Precision.HIGH),
+        preferred_element_type=jnp.float32,
+    )
+    return jnp.sum(c * c, axis=1)[None, :] - 2.0 * xc
+
+
+def _cluster_means(xb, labels, centers, n_true, k):
+    """Per-cluster means of the bfloat16 points ``xb`` (the update's product
+    takes the operands the MXU's default gave it: exact one-hot rows, rounded
+    points, float32 sums), an empty cluster keeping its center.  Returns
+    (new centers, which rows are real and not padding, the squared shift)."""
+    valid = jax.lax.broadcasted_iota(jnp.int32, (xb.shape[0],), 0) < n_true
+    oh = jax.nn.one_hot(labels, k, dtype=xb.dtype) * valid.astype(xb.dtype)[:, None]
+    sums = jax.lax.dot_general(oh, xb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    counts = jnp.sum(oh, axis=0, dtype=jnp.float32)
+    means = (sums / jnp.maximum(counts, 1.0)[:, None]).astype(centers.dtype)
+    new = jnp.where(counts[:, None] > 0, means, centers)
+    return new, valid, jnp.sum((new - centers) ** 2)
+
+
 def _lloyd_body(xp, centers, n_true, k):
+    xb = xp.astype(jnp.bfloat16)  # does not change in the loop: the compiler makes the copy once, before it
     # the scopes name the two passes in the device trace; metadata only
     with jax.named_scope("lloyd.assign"):
-        xc = xp @ centers.T
-        c2 = jnp.sum(centers * centers, axis=1)
-        labels = jnp.argmin(c2[None, :] - 2.0 * xc, axis=1)
+        labels = jnp.argmin(_half_d2(xb, centers), axis=1)
     with jax.named_scope("lloyd.update"):
-        valid = jax.lax.broadcasted_iota(jnp.int32, (xp.shape[0],), 0) < n_true
-        oh = jax.nn.one_hot(labels, k, dtype=xp.dtype) * valid.astype(xp.dtype)[:, None]
-        sums = oh.T @ xp
-        counts = jnp.sum(oh, axis=0)
-        new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None], centers)
-        shift = jnp.sum((new - centers) ** 2).astype(jnp.float32)
-    return new, shift
+        new, _, shift = _cluster_means(xb, labels, centers, n_true, k)
+    return new, shift.astype(jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("n_true", "k"))
@@ -90,22 +125,15 @@ def _lloyd_step(xp: jax.Array, centers: jax.Array, n_true: int, k: int):
 
     Returns (labels_padded, new_centers, shift, inertia).
     """
+    xb = xp.astype(jnp.bfloat16)
     with jax.named_scope("lloyd.assign"):
-        xc = xp @ centers.T  # (N, k) — MXU
-        c2 = jnp.sum(centers * centers, axis=1)
-        half_d2 = c2[None, :] - 2.0 * xc  # squared distance minus |x|^2 row term
+        half_d2 = _half_d2(xb, centers)  # (N, k) — MXU; squared distance minus |x|^2 row term
         labels = jnp.argmin(half_d2, axis=1)
-    with jax.named_scope("lloyd.update"):
-        valid = jax.lax.broadcasted_iota(jnp.int32, (xp.shape[0],), 0) < n_true
-        w = valid.astype(xp.dtype)
-        oh = jax.nn.one_hot(labels, k, dtype=xp.dtype) * w[:, None]
-        sums = oh.T @ xp  # (k, f) — MXU; GSPMD: psum across shards
-        counts = jnp.sum(oh, axis=0)
-        new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None], centers)
-        shift = jnp.sum((new - centers) ** 2)
+    with jax.named_scope("lloyd.update"):  # GSPMD: the sums' psum across shards
+        new, valid, shift = _cluster_means(xb, labels, centers, n_true, k)
     with jax.named_scope("lloyd.assign"):  # the inertia is the assignment's own sum
         x2 = jnp.sum(xp * xp, axis=1)
-        inertia = jnp.sum(w * (x2 + jnp.min(half_d2, axis=1)))
+        inertia = jnp.sum(valid.astype(xp.dtype) * (x2 + jnp.min(half_d2, axis=1)))
     return labels, new, shift, inertia
 
 

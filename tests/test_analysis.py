@@ -499,7 +499,16 @@ class TestProgramLint:
             with comm.account_implicit("psum", nbytes, site="kmeans.lloyd"):
                 return _lloyd_body(xp_, centers_, int(x.shape[0]), k)
 
-        assert analyze(launch, xp, centers) == []
+        # PR 27: the fit rounds the points to bfloat16, once, for both of
+        # its products, and says so in the program (kmeans._half_d2).  At
+        # the jaxpr an explicit astype and a silent narrowing look alike,
+        # so the caller declares it; undeclared, J201 names that one cast
+        # of the input and nothing else
+        assert analyze(launch, xp, centers, allowed_narrowing=("bfloat16",)) == []
+        undeclared = [d for d in analyze(launch, xp, centers) if d.rule.startswith("J2")]
+        assert [(d.rule, d.details) for d in undeclared] == [
+            ("J201", {"from": xp.dtype.name, "to": "bfloat16", "is_input": True})
+        ]
 
     def test_emit_flows_into_telemetry_and_ring(self):
         before = telemetry.snapshot().get("analysis.diags.J101", 0)

@@ -192,6 +192,74 @@ def test_lloyd_step_one_chip(one_chip, for_the_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def _computation_instructions(text: str, name: str) -> list:
+    """[(name, opcode, result shape, operand names)] of the computation
+    called ``name`` in a compiled program's text."""
+    block = re.search(r"^%" + re.escape(name) + r" [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    found = []
+    for line in block.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([^ ]+) = (.*?) ([a-z][a-z\-]*)\(([^)]*)\)", line)
+        if m:
+            found.append((m.group(1), m.group(3), re.sub(r"\{[^}]*\}", "", m.group(2)),
+                          re.findall(r"%([^ ,)]+)", m.group(4))))
+    return found
+
+
+def test_lloyd_loop_at_the_benchmark_cell(one_chip, for_the_chip):
+    """The KMeans cell, 10^8 x 16 points and 8 clusters, 30 iterations: the
+    whole fit loop fits the chip beside the resident points (6.4 GB of
+    arguments, and the bfloat16 copy, the labels and the row mask as
+    temporaries: a second copy of the points, or a materialised float32
+    product, does not), and one iteration makes three passes that READ an
+    array of 10^8 rows: the assignment (the copy), the counts (labels and
+    mask) and the update (copy, labels and mask).  A fourth operation only
+    writes one, the row mask, rebuilt every iteration.  A later PR that adds
+    a pass, or removes one, is seen here without a chip."""
+    from heat_tpu.cluster import kmeans
+
+    n, f, k = 100_000_000, 16, 8
+    compiled = kmeans._lloyd_loop.lower(
+        _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip),
+        n_true=n, k=k, max_iter=30, tol=-1.0,
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < n * f * 4 + 2**20
+    assert memory.temp_size_in_bytes < 3_900_000_000  # copy 3.2 GB + labels 0.4 + their minima and the mask 0.2
+    assert _device_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    (body,) = set(re.findall(r" while\([^)]*\), condition=%[^ ,]+, body=%([^ ,]+)", text))
+    instructions = _computation_instructions(text, body)
+    tall = {name for name, _, shape, _ in instructions if str(n) in shape}
+    moving = [(name, opcode, shape, operands) for name, opcode, shape, operands in instructions
+              if opcode not in ("parameter", "tuple", "get-tuple-element")]
+    readers = [(opcode, shape) for _, opcode, shape, operands in moving if tall & set(operands)]
+    assert readers == [
+        ("fusion", f"(bf16[{n}], s32[{n}])"),  # lloyd.assign: the one product, the argmin and its minimum
+        ("fusion", f"f32[{k}]"),               # lloyd.update: the counts
+        ("fusion", f"(f32[], f32[{k},{f}])"),  # lloyd.update: the sums, the new centers and the shift
+    ], readers
+    writers_only = [(opcode, shape) for name, opcode, shape, operands in moving
+                    if name in tall and not tall & set(operands)]
+    assert writers_only == [("fusion", f"bf16[{n}]")], writers_only  # the row mask, without inputs
+    assert text.count("operand_precision={default,high}") == 1  # the assignment's product, as written
+
+
+def test_lloyd_final_assignment_at_the_benchmark_cell(one_chip, for_the_chip):
+    """The fit's last pass at the cell's size: labels for every row and the
+    inertia, beside the resident points."""
+    from heat_tpu.cluster import kmeans
+
+    n, f, k = 100_000_000, 16, 8
+    compiled = kmeans._lloyd_step.lower(
+        _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip), n_true=n, k=k
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert n * 4 <= memory.output_size_in_bytes < n * 4 + 2**20  # the labels
+    assert memory.temp_size_in_bytes < 1_000_000_000
+    assert _device_bytes(compiled) < HBM_BYTES
+    assert compiled.as_text().count("operand_precision={default,high}") == 1
+
+
 def test_lloyd_step_four_chips(comm4, for_the_chip):
     from heat_tpu.cluster import kmeans
 

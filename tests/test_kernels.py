@@ -59,15 +59,22 @@ def test_kmeans_kernel_flag_end_to_end(ht, monkeypatch):
     """KMeans produces the same clustering through both step paths."""
     from heat_tpu.core import kernels
 
-    ht.random.seed(7)
-    x = ht.random.randn(500, 16, split=0)
+    # four separated blobs: on structureless noise no tolerance holds
+    # between two arithmetics, one flipped row sends the 30 iterations
+    # elsewhere.  Since PR 27 the XLA path rounds the points to bfloat16
+    # (kmeans._half_d2) and the Pallas path does not, so with the same
+    # clustering each center is the mean of ~125 rows rounded by up to
+    # 2^-9 |x| (|x| <= 12): within 12 * 2^-9 / sqrt(125) = 2e-3
+    rng = np.random.default_rng(7)
+    offsets = 8.0 * np.eye(4, 16, dtype=np.float32)[rng.integers(0, 4, 500)]
+    x = ht.array(rng.standard_normal((500, 16)).astype(np.float32) + offsets, split=0)
     km_xla = ht.cluster.KMeans(n_clusters=4, init="kmeans++", max_iter=30, random_state=0)
     km_xla.fit(x)
     monkeypatch.setattr(kernels, "LLOYD_KERNEL", True)
     km_pal = ht.cluster.KMeans(n_clusters=4, init="kmeans++", max_iter=30, random_state=0)
     km_pal.fit(x)
     np.testing.assert_allclose(
-        km_xla.cluster_centers_.numpy(), km_pal.cluster_centers_.numpy(), atol=1e-4
+        km_xla.cluster_centers_.numpy(), km_pal.cluster_centers_.numpy(), atol=2e-3
     )
 
 

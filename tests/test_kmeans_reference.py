@@ -1,0 +1,176 @@
+"""KMeans.fit against a plain float32 Lloyd written here, and the arithmetic
+of its assignment pinned (PR 27).
+
+The fit's assignment is ``argmin_j |c_j|^2 - 2 x.c_j`` with the points rounded
+to bfloat16 and the centers float32 in BOTH terms, on every backend
+(``cluster/kmeans.py::_half_d2``).  Before PR 27 the product was left to the
+backend's default: exact on the CPU, where these tests run, and on the MXU
+both operands rounded to bfloat16 while ``|c|^2`` came from the float32
+centers, which put every boundary off by ``x . (c - bf16(c))``.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import heat_tpu as ht
+from heat_tpu.cluster import kmeans
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from chipbench.run import load_py  # noqa: E402
+
+
+def _bf16(a):
+    """float32 values rounded to bfloat16, as float32."""
+    return np.asarray(jnp.asarray(a, jnp.float32).astype(jnp.bfloat16).astype(jnp.float32))
+
+
+def _plain_lloyd(x, centers, iters):
+    """Exact Lloyd: float32 data, distances and means in float64."""
+    x64, c = x.astype(np.float64), centers.astype(np.float64)
+    for _ in range(iters + 1):  # the last pass only assigns
+        d = ((x64[:, None, :] - c[None, :, :]) ** 2).sum(axis=2)
+        labels = d.argmin(axis=1)
+        final = c
+        c = np.stack([x64[labels == j].mean(axis=0) if np.any(labels == j) else c[j] for j in range(len(c))])
+        c = c.astype(np.float32).astype(np.float64)  # the fit carries float32 centers
+    return final, labels, d.min(axis=1).sum()
+
+
+def _blobs(seed, n, f, k, spread):
+    rng = np.random.default_rng(seed)
+    true = spread * rng.standard_normal((k, f))
+    x = (true[rng.integers(0, k, n)] + rng.standard_normal((n, f))).astype(np.float32)
+    init = (true + 0.25 * rng.standard_normal((k, f))).astype(np.float32)
+    return x, init
+
+
+@pytest.mark.parametrize("split", [None, 0])
+@pytest.mark.parametrize("seed", [3, 11])
+def test_fit_matches_plain_float32_lloyd(seed, split):
+    """Seeded overlapping blobs (unit noise, centers N(0, 1) a coordinate,
+    so neighbouring blobs share a few percent of their points), 10 iterations
+    from the same initial centers.  Tolerances, with their reason: rounding a
+    point to bfloat16 moves ``x.c`` by up to 2^-9 |x||c|, so the rows within
+    that of a boundary, a few in a thousand here, may take the neighbour's
+    label; one such row moves a center of n/k rows by about |x - c| k / n =
+    1e-3 at this size, the flips fall on either side of a boundary alike, and
+    ten iterations amplify them little on blobs this far apart: centers
+    within 1e-2 (1.6e-3 to 7.0e-3 over six seeds, 2e-3 on these two), under
+    1% of the labels differ (2e-4 to 1.2e-3), the inertia within 1e-3 of the
+    plain fit's (1e-6 to 4e-5)."""
+    n, f, k, iters = 13_001, 8, 4, 10  # 13,001 does not divide over the 8 test devices
+    x, init = _blobs(seed, n, f, k, spread=1.0)
+    want_c, want_labels, want_inertia = _plain_lloyd(x, init, iters)
+    km = ht.cluster.KMeans(n_clusters=k, init=ht.array(init), max_iter=iters, tol=-1.0).fit(ht.array(x, split=split))
+    assert km.n_iter_ == iters
+    dist = np.linalg.norm(km.cluster_centers_.numpy().astype(np.float64) - want_c, axis=1).max()
+    assert dist < 1e-2, dist
+    assert np.mean(km.labels_.numpy() != want_labels) < 1e-2
+    assert abs(km.inertia_ - want_inertia) / want_inertia < 1e-3
+
+
+def _planted():
+    """Two centers and a boundary point on which the arithmetic before PR 27
+    errs, the witness the benchmark's KMeans driver probes a program with.
+    ``c0``'s first coordinate rounds from 1.003 to 1.0 in bfloat16; the point
+    is exact in bfloat16 and lies far along that coordinate, so the rounded
+    cross term loses ``2 * 8 * 0.003`` against ``|c0|^2`` and hands the point
+    to ``c1``."""
+    driver = load_py("drivers", "kmeans_fit")
+    return driver.WITNESS_X, driver.WITNESS_CENTERS
+
+
+def test_planted_boundary_point_exposes_rounded_cross_term():
+    """The witness is real: exactly, the planted point is nearest ``c0``;
+    with the product's operands rounded and ``|c|^2`` from the unrounded
+    centers (what the MXU's default made of the parent's program) it lands
+    in cluster 1, and so it does with both terms from the rounded centers."""
+    x, centers = _planted()
+    assert np.array_equal(_bf16(x), x)  # rounding the points is not what decides it
+    exact = ((x[:, None, :].astype(np.float64) - centers[None].astype(np.float64)) ** 2).sum(axis=2)
+    assert exact[0].argmin() == 0
+    c2 = (centers.astype(np.float64) ** 2).sum(axis=1)
+    rounded = _bf16(centers).astype(np.float64)
+    parent = c2[None, :] - 2.0 * x.astype(np.float64) @ rounded.T
+    assert parent[0].argmin() == 1
+    both_rounded = (rounded ** 2).sum(axis=1)[None, :] - 2.0 * x.astype(np.float64) @ rounded.T
+    assert both_rounded[0].argmin() == 1
+
+
+@pytest.mark.parametrize("through", ["step", "body", "fit"])
+def test_planted_boundary_point_goes_to_its_nearest_center(through):
+    """The program puts the planted point where the exact distances put it:
+    in the final assignment (``_lloyd_step``), in the loop's body (a point
+    wrongly given to ``c1`` would pull ``c1`` towards 8) and through the
+    public ``fit``."""
+    x, centers = _planted()
+    n, k = x.shape[0], centers.shape[0]
+    if through == "step":
+        labels, *_ = kmeans._lloyd_step(jnp.asarray(x), jnp.asarray(centers), n, k)
+        assert list(np.asarray(labels)) == [0, 0, 1, 0]
+    elif through == "body":
+        new, _ = kmeans._lloyd_body(jnp.asarray(x), jnp.asarray(centers), n, k)
+        np.testing.assert_allclose(np.asarray(new), [x[[0, 1, 3]].mean(axis=0), x[2]], rtol=1e-6)
+    else:
+        km = ht.cluster.KMeans(n_clusters=k, init=ht.array(centers), max_iter=1, tol=-1.0).fit(ht.array(x, split=0))
+        np.testing.assert_allclose(km.cluster_centers_.numpy(), [x[[0, 1, 3]].mean(axis=0), x[2]], rtol=1e-6)
+
+
+def test_benchmark_driver_refuses_a_program_that_rounds_the_centers(monkeypatch):
+    """The KMeans cell's driver probes the program with the witness before it
+    makes its data: this program passes, and one whose cross term takes
+    bfloat16 centers (the MXU's default before PR 27, written out here so
+    that the CPU rounds too) exits with the reason."""
+    driver = load_py("drivers", "kmeans_fit")
+    driver._refuse_rounded_centers(ht)
+
+    def rounded(xb, centers):
+        xc = jnp.matmul(xb, centers.astype(jnp.bfloat16).T, preferred_element_type=jnp.float32)
+        return jnp.sum(centers * centers, axis=1)[None, :] - 2.0 * xc
+
+    monkeypatch.setattr(kmeans, "_half_d2", rounded)
+    jax.clear_caches()  # the jitted Lloyd programs hold the traced `_half_d2`
+    try:
+        with pytest.raises(SystemExit, match="rounds the centers"):
+            driver._refuse_rounded_centers(ht)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("which", ["body", "step"])
+def test_lloyd_programs_make_one_product_of_the_data_each_way(which):
+    """The jaxpr of an iteration: the data is rounded to bfloat16 once, and
+    takes part in two products, the assignment's (bfloat16 points against
+    float32 centers, ``HIGH`` on the centers' side, float32 out) and the
+    update's (a bfloat16 one-hot against the bfloat16 points, float32 out).
+    The precision is in the program, not left to the backend's default."""
+    n, f, k = 4096, 16, 8
+    xp, centers = jnp.zeros((n, f), jnp.float32), jnp.zeros((k, f), jnp.float32)
+    fn = kmeans._lloyd_body if which == "body" else kmeans._lloyd_step.__wrapped__
+    eqns = list(_equations(jax.make_jaxpr(lambda a, b: fn(a, b, n, k))(xp, centers).jaxpr))
+    narrowed = [e for e in eqns if e.primitive.name == "convert_element_type"
+                and e.invars[0].aval.shape == (n, f) and e.outvars[0].aval.dtype == jnp.bfloat16]
+    assert len(narrowed) == 1
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 2
+    assign, update = dots
+    assert [v.aval.dtype for v in assign.invars] == [jnp.bfloat16, jnp.float32]
+    assert [v.aval.shape for v in assign.invars] == [(n, f), (k, f)]
+    assert tuple(assign.params["precision"]) == (jax.lax.Precision.DEFAULT, jax.lax.Precision.HIGH)
+    assert [v.aval.dtype for v in update.invars] == [jnp.bfloat16, jnp.bfloat16]
+    assert [v.aval.shape for v in update.invars] == [(n, k), (n, f)]
+    assert all(e.params["preferred_element_type"] == jnp.float32 for e in dots)
