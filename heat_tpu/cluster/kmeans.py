@@ -27,13 +27,20 @@ __all__ = ["KMeans"]
 def _lloyd_update(xp: jax.Array, centers: jax.Array, n_true: int, k: int):
     """Trimmed Lloyd iteration: centroid update + shift ONLY.
 
-    Measured on v5e: materializing labels/inertia/|x|^2 inside the
-    iteration costs ~6x (extra HBM passes); the fit loop needs none of
-    them until convergence, so the hot step computes exactly two passes
-    over x (distance matmul, one-hot sums matmul) and two (N, k)
-    intermediates.  Labels and inertia come from one final `_lloyd_step`.
+    An iteration needs no inertia and no ``|x|^2``, so it is the assignment
+    (the distance product, the argmin and its minimum) and the update (the
+    one-hot against the points, and the counts).  This program is ONE
+    iteration, the fallback behind the opt-in kernel gate: as compiled for a
+    v5e it holds no copy of the points, the convert to bfloat16 is fused into
+    both products, which read the float32 points, and the counts are a pass
+    over the labels.  The fit runs the same body inside `_lloyd_loop` over a
+    bfloat16 copy made once: there an iteration is two passes over the copy
+    and nothing else of that length (`tests/test_chip_compile.py` pins them;
+    7.65 + 4.77 ms at 10^8 x 16, 8 clusters, PERF.md, PR 28), three where
+    rows are padded and the row mask is written.  Labels and inertia come
+    from one final `_lloyd_step`.
     """
-    return _lloyd_body(xp, centers, n_true, k)
+    return _lloyd_body(xp, centers, n_true, k, resident=False)
 
 
 @partial(jax.jit, static_argnames=("n_true", "k", "max_iter", "tol"))
@@ -52,7 +59,7 @@ def _lloyd_loop(xp: jax.Array, centers: jax.Array, n_true: int, k: int, max_iter
 
     def body(carry):
         c, i, _ = carry
-        new, shift = _lloyd_body(xp, c, n_true, k)
+        new, shift = _lloyd_body(xp, c, n_true, k, resident=True)
         return new, i + 1, shift
 
     init = (centers, jnp.int32(0), jnp.asarray(jnp.inf, jnp.float32))
@@ -87,27 +94,59 @@ def _half_d2(xb, centers):
     return jnp.sum(c * c, axis=1)[None, :] - 2.0 * xc
 
 
-def _cluster_means(xb, labels, centers, n_true, k):
-    """Per-cluster means of the bfloat16 points ``xb`` (the update's product
-    takes the operands the MXU's default gave it: exact one-hot rows, rounded
-    points, float32 sums), an empty cluster keeping its center.  Returns
-    (new centers, which rows are real and not padding, the squared shift)."""
-    valid = jax.lax.broadcasted_iota(jnp.int32, (xb.shape[0],), 0) < n_true
-    oh = jax.nn.one_hot(labels, k, dtype=xb.dtype) * valid.astype(xb.dtype)[:, None]
-    sums = jax.lax.dot_general(oh, xb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    counts = jnp.sum(oh, axis=0, dtype=jnp.float32)
+def _real_rows(n, n_true):
+    """Which of ``n`` padded rows are real: the first ``n_true``."""
+    return jax.lax.broadcasted_iota(jnp.int32, (n,), 0) < n_true
+
+
+def _cluster_means(xb, labels, centers, n_true, k, resident):
+    """Per-cluster means of the bfloat16 points ``xb``, an empty cluster
+    keeping its center; returns (new centers, the squared shift).  The sums
+    are the product of the exact bfloat16 one-hot against the rounded points,
+    summed in float32 (the operands the MXU's default gave the update).  Where
+    the counts come from is a static fact of the caller:
+
+    * ``resident`` (the fit loop, which holds ``xb`` as a copy in memory): a
+      column of ones appended to the points, so ONE product reads the copy
+      and the labels once and its last column is the counts, sums of exact
+      1 x 1 terms.  The compiler fuses the column into the product, and no
+      pass sums the one-hot a second time (1.1 ms an iteration at 10^8 x 16).
+    * not ``resident`` (one iteration alone, `_lloyd_update` and
+      `_lloyd_step`, where the compiler fuses the convert to bfloat16 into
+      each product and holds no copy): a column between the convert and the
+      product makes it write the copy, 3.2 GB and 5.7 ms more at 10^8 x 16.
+      There the counts are the one-hot's own sum, a pass over the labels (the
+      one-hot against itself as a third product reads 3.4 ms for that 1.1,
+      PERF.md, PR 28).
+
+    Either is exact in float32 while a cluster holds under 2^24 rows a chip.
+    The row mask exists only where rows are padded, another static fact; pad
+    rows are not guaranteed zero, so there the one-hot is masked."""
+    n, f = xb.shape
+    oh = jax.nn.one_hot(labels, k, dtype=xb.dtype)
+    if n != n_true:
+        oh = oh * _real_rows(n, n_true).astype(xb.dtype)[:, None]
+    over_rows = (((0,), (0,)), ((), ()))
+    if resident:
+        x1 = jnp.concatenate([xb, jnp.ones((n, 1), xb.dtype)], axis=1)
+        sums1 = jax.lax.dot_general(oh, x1, over_rows, preferred_element_type=jnp.float32)
+        sums, counts = sums1[:, :f], sums1[:, f]
+    else:
+        sums = jax.lax.dot_general(oh, xb, over_rows, preferred_element_type=jnp.float32)
+        counts = jnp.sum(oh, axis=0, dtype=jnp.float32)
     means = (sums / jnp.maximum(counts, 1.0)[:, None]).astype(centers.dtype)
     new = jnp.where(counts[:, None] > 0, means, centers)
-    return new, valid, jnp.sum((new - centers) ** 2)
+    return new, jnp.sum((new - centers) ** 2)
 
 
-def _lloyd_body(xp, centers, n_true, k):
-    xb = xp.astype(jnp.bfloat16)  # does not change in the loop: the compiler makes the copy once, before it
+def _lloyd_body(xp, centers, n_true, k, resident):
+    # does not change in the loop: the compiler makes the copy once, before it; alone, it fuses the convert into each product
+    xb = xp.astype(jnp.bfloat16)
     # the scopes name the two passes in the device trace; metadata only
     with jax.named_scope("lloyd.assign"):
         labels = jnp.argmin(_half_d2(xb, centers), axis=1)
     with jax.named_scope("lloyd.update"):
-        new, _, shift = _cluster_means(xb, labels, centers, n_true, k)
+        new, shift = _cluster_means(xb, labels, centers, n_true, k, resident)
     return new, shift.astype(jnp.float32)
 
 
@@ -130,10 +169,12 @@ def _lloyd_step(xp: jax.Array, centers: jax.Array, n_true: int, k: int):
         half_d2 = _half_d2(xb, centers)  # (N, k) — MXU; squared distance minus |x|^2 row term
         labels = jnp.argmin(half_d2, axis=1)
     with jax.named_scope("lloyd.update"):  # GSPMD: the sums' psum across shards
-        new, valid, shift = _cluster_means(xb, labels, centers, n_true, k)
+        new, shift = _cluster_means(xb, labels, centers, n_true, k, resident=False)
     with jax.named_scope("lloyd.assign"):  # the inertia is the assignment's own sum
-        x2 = jnp.sum(xp * xp, axis=1)
-        inertia = jnp.sum(valid.astype(xp.dtype) * (x2 + jnp.min(half_d2, axis=1)))
+        d2 = jnp.sum(xp * xp, axis=1) + jnp.min(half_d2, axis=1)
+        if xp.shape[0] != n_true:
+            d2 = _real_rows(xp.shape[0], n_true).astype(xp.dtype) * d2
+        inertia = jnp.sum(d2)
     return labels, new, shift, inertia
 
 

@@ -205,26 +205,30 @@ def _computation_instructions(text: str, name: str) -> list:
     return found
 
 
-def test_lloyd_loop_at_the_benchmark_cell(one_chip, for_the_chip):
+@pytest.mark.parametrize("pad", [0, 3], ids=["every_row_real", "three_pad_rows"])
+def test_lloyd_loop_at_the_benchmark_cell(one_chip, for_the_chip, pad):
     """The KMeans cell, 10^8 x 16 points and 8 clusters, 30 iterations: the
     whole fit loop fits the chip beside the resident points (6.4 GB of
-    arguments, and the bfloat16 copy, the labels and the row mask as
+    arguments, and the bfloat16 copy, the labels and their minima as
     temporaries: a second copy of the points, or a materialised float32
-    product, does not), and one iteration makes three passes that READ an
-    array of 10^8 rows: the assignment (the copy), the counts (labels and
-    mask) and the update (copy, labels and mask).  A fourth operation only
-    writes one, the row mask, rebuilt every iteration.  A later PR that adds
-    a pass, or removes one, is seen here without a chip."""
+    product, does not), and one iteration makes TWO passes that read an
+    array of 10^8 rows: the assignment (the copy) and the update (copy and
+    labels), whose one product carries the counts in its last column.  No
+    operation only writes such an array where every row is real; where rows
+    are padded (``n_true = n - 3``) one does, the row mask, once an
+    iteration, and the update reads it; the counts are no pass of their own
+    in either form.  A later PR that adds a pass, or removes one, is seen
+    here without a chip."""
     from heat_tpu.cluster import kmeans
 
     n, f, k = 100_000_000, 16, 8
     compiled = kmeans._lloyd_loop.lower(
         _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip),
-        n_true=n, k=k, max_iter=30, tol=-1.0,
+        n_true=n - pad, k=k, max_iter=30, tol=-1.0,
     ).compile()
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes < n * f * 4 + 2**20
-    assert memory.temp_size_in_bytes < 3_900_000_000  # copy 3.2 GB + labels 0.4 + their minima and the mask 0.2
+    assert memory.temp_size_in_bytes < 3_900_000_000  # copy 3.2 GB + labels 0.4 + their minima (and the mask) 0.2
     assert _device_bytes(compiled) < HBM_BYTES
     text = compiled.as_text()
     (body,) = set(re.findall(r" while\([^)]*\), condition=%[^ ,]+, body=%([^ ,]+)", text))
@@ -232,21 +236,26 @@ def test_lloyd_loop_at_the_benchmark_cell(one_chip, for_the_chip):
     tall = {name for name, _, shape, _ in instructions if str(n) in shape}
     moving = [(name, opcode, shape, operands) for name, opcode, shape, operands in instructions
               if opcode not in ("parameter", "tuple", "get-tuple-element")]
-    readers = [(opcode, shape) for _, opcode, shape, operands in moving if tall & set(operands)]
+    readers = [(opcode, shape, len(tall & set(operands))) for _, opcode, shape, operands in moving
+               if tall & set(operands)]
     assert readers == [
-        ("fusion", f"(bf16[{n}], s32[{n}])"),  # lloyd.assign: the one product, the argmin and its minimum
-        ("fusion", f"f32[{k}]"),               # lloyd.update: the counts
-        ("fusion", f"(f32[], f32[{k},{f}])"),  # lloyd.update: the sums, the new centers and the shift
+        ("fusion", f"(bf16[{n}], s32[{n}])", 1),           # lloyd.assign: the one product, the argmin and its minimum
+        ("fusion", f"f32[{k},{f + 1}]", 3 if pad else 2),  # lloyd.update: sums and counts; copy, labels (and mask)
     ], readers
     writers_only = [(opcode, shape) for name, opcode, shape, operands in moving
                     if name in tall and not tall & set(operands)]
-    assert writers_only == [("fusion", f"bf16[{n}]")], writers_only  # the row mask, without inputs
+    assert writers_only == ([("fusion", f"bf16[{n}]")] if pad else []), writers_only  # the row mask, without inputs
     assert text.count("operand_precision={default,high}") == 1  # the assignment's product, as written
 
 
 def test_lloyd_final_assignment_at_the_benchmark_cell(one_chip, for_the_chip):
     """The fit's last pass at the cell's size: labels for every row and the
-    inertia, beside the resident points."""
+    inertia, beside the resident points.  It holds no bfloat16 copy of the
+    points: the convert is fused into each of its two products (so the update
+    takes its counts from the one-hot's own sum, a pass over the labels, and
+    not from a column of ones between the convert and the product, which
+    makes the compiler write the copy: 3.2 GB more, PR 28), it writes no row
+    mask where every row is real, and it stays under the loop's peak."""
     from heat_tpu.cluster import kmeans
 
     n, f, k = 100_000_000, 16, 8
@@ -256,21 +265,34 @@ def test_lloyd_final_assignment_at_the_benchmark_cell(one_chip, for_the_chip):
     memory = compiled.memory_analysis()
     assert n * 4 <= memory.output_size_in_bytes < n * 4 + 2**20  # the labels
     assert memory.temp_size_in_bytes < 1_000_000_000
-    assert _device_bytes(compiled) < HBM_BYTES
+    assert _device_bytes(compiled) < n * f * 4 + 3_800_000_000  # the loop's arguments and temporaries
+    results = [(opcode, shape) for _, opcode, shape, _ in _entry_instructions(compiled)]
+    assert not [r for r in results if r[1].startswith(f"bf16[{n},")], results
+    assert ("fusion", f"f32[{k}]") in results  # the counts, over the labels alone
+    assert ("fusion", f"bf16[{n}]") not in results  # no row mask
     assert compiled.as_text().count("operand_precision={default,high}") == 1
 
 
-def test_lloyd_step_four_chips(comm4, for_the_chip):
+@pytest.mark.parametrize("program", ["update", "loop"])
+def test_lloyd_step_four_chips(comm4, for_the_chip, program):
+    """Rows split over four chips: whatever the update sums crosses the chips
+    together, in ONE all-reduce a program (an iteration): in the fit loop the
+    ``(k, f + 1)`` product, sums and counts; in one iteration alone the sums
+    and the counts, combined."""
     from heat_tpu.cluster import kmeans
 
     n, f, k = 2**24, 16, 8
-    compiled = kmeans._lloyd_update.lower(
+    fn, more = ((kmeans._lloyd_loop, {"max_iter": 30, "tol": -1.0}) if program == "loop"
+                else (kmeans._lloyd_update, {}))
+    compiled = fn.lower(
         _sds((n, f), jnp.float32, comm4.sharding(0)),
         _sds((k, f), jnp.float32, comm4.sharding(None)),
-        n_true=n, k=k,
+        n_true=n, k=k, **more,
     ).compile()
-    # the per-cluster sums cross the chips
-    assert "all-reduce" in compiled.as_text()
+    reduces = re.findall(r"= (.*?) all-reduce(?:-start)?\(", compiled.as_text())
+    assert len(reduces) == 1, reduces
+    if program == "loop":
+        assert re.sub(r"\{[^}]*\}", "", reduces[0]) == f"f32[{k},{f + 1}]", reduces
     # memory_analysis is per device: a quarter of the rows each
     assert _device_bytes(compiled) < HBM_BYTES
 

@@ -11,6 +11,7 @@ centers, which put every boundary off by ``x . (c - bf16(c))``.
 
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -102,19 +103,19 @@ def test_planted_boundary_point_exposes_rounded_cross_term():
     assert both_rounded[0].argmin() == 1
 
 
-@pytest.mark.parametrize("through", ["step", "body", "fit"])
+@pytest.mark.parametrize("through", ["step", "body", "update", "fit"])
 def test_planted_boundary_point_goes_to_its_nearest_center(through):
     """The program puts the planted point where the exact distances put it:
-    in the final assignment (``_lloyd_step``), in the loop's body (a point
-    wrongly given to ``c1`` would pull ``c1`` towards 8) and through the
-    public ``fit``."""
+    in the final assignment (``_lloyd_step``), in the loop's body and in one
+    iteration alone (a point wrongly given to ``c1`` would pull ``c1`` towards
+    8) and through the public ``fit``."""
     x, centers = _planted()
     n, k = x.shape[0], centers.shape[0]
     if through == "step":
         labels, *_ = kmeans._lloyd_step(jnp.asarray(x), jnp.asarray(centers), n, k)
         assert list(np.asarray(labels)) == [0, 0, 1, 0]
-    elif through == "body":
-        new, _ = kmeans._lloyd_body(jnp.asarray(x), jnp.asarray(centers), n, k)
+    elif through in ("body", "update"):
+        new, _ = kmeans._lloyd_body(jnp.asarray(x), jnp.asarray(centers), n, k, resident=through == "body")
         np.testing.assert_allclose(np.asarray(new), [x[[0, 1, 3]].mean(axis=0), x[2]], rtol=1e-6)
     else:
         km = ht.cluster.KMeans(n_clusters=k, init=ht.array(centers), max_iter=1, tol=-1.0).fit(ht.array(x, split=0))
@@ -151,26 +152,100 @@ def _equations(jaxpr):
             yield from _equations(sub)
 
 
-@pytest.mark.parametrize("which", ["body", "step"])
-def test_lloyd_programs_make_one_product_of_the_data_each_way(which):
+@pytest.mark.parametrize("pad", [0, 3], ids=["every_row_real", "three_pad_rows"])
+@pytest.mark.parametrize("which", ["body", "update", "step"])
+def test_lloyd_programs_make_one_product_of_the_data_each_way(which, pad):
     """The jaxpr of an iteration: the data is rounded to bfloat16 once, and
     takes part in two products, the assignment's (bfloat16 points against
     float32 centers, ``HIGH`` on the centers' side, float32 out) and the
     update's (a bfloat16 one-hot against the bfloat16 points, float32 out).
-    The precision is in the program, not left to the backend's default."""
+    In the loop's body the points carry a column of ones, the product's last
+    column is the counts and nothing sums the one-hot in a reduction of its
+    own; in one iteration alone (``_lloyd_update``, ``_lloyd_step``) the
+    product is of the points as they are and the counts are the one-hot's
+    sum.  The precision is in the program, not left to the backend's default.
+    An iota over the rows (the row mask) exists only where rows are padded."""
     n, f, k = 4096, 16, 8
     xp, centers = jnp.zeros((n, f), jnp.float32), jnp.zeros((k, f), jnp.float32)
-    fn = kmeans._lloyd_body if which == "body" else kmeans._lloyd_step.__wrapped__
-    eqns = list(_equations(jax.make_jaxpr(lambda a, b: fn(a, b, n, k))(xp, centers).jaxpr))
+    fn = kmeans._lloyd_step.__wrapped__ if which == "step" else partial(kmeans._lloyd_body, resident=which == "body")
+    eqns = list(_equations(jax.make_jaxpr(lambda a, b: fn(a, b, n - pad, k))(xp, centers).jaxpr))
     narrowed = [e for e in eqns if e.primitive.name == "convert_element_type"
                 and e.invars[0].aval.shape == (n, f) and e.outvars[0].aval.dtype == jnp.bfloat16]
     assert len(narrowed) == 1
     dots = [e for e in eqns if e.primitive.name == "dot_general"]
-    assert len(dots) == 2
     assign, update = dots
     assert [v.aval.dtype for v in assign.invars] == [jnp.bfloat16, jnp.float32]
     assert [v.aval.shape for v in assign.invars] == [(n, f), (k, f)]
     assert tuple(assign.params["precision"]) == (jax.lax.Precision.DEFAULT, jax.lax.Precision.HIGH)
     assert [v.aval.dtype for v in update.invars] == [jnp.bfloat16, jnp.bfloat16]
-    assert [v.aval.shape for v in update.invars] == [(n, k), (n, f)]
+    assert [v.aval.shape for v in update.invars] == [(n, k), (n, f + 1 if which == "body" else f)]
     assert all(e.params["preferred_element_type"] == jnp.float32 for e in dots)
+    one_hot_sums = [e for e in eqns if e.primitive.name == "reduce_sum" and e.invars[0].aval.shape == (n, k)]
+    assert len(one_hot_sums) == (0 if which == "body" else 1)
+    row_masks = [e for e in eqns if e.primitive.name == "iota" and e.outvars[0].aval.shape == (n,)]
+    assert len(row_masks) == (0 if not pad else 2 if which == "step" else 1)  # the step masks its inertia too
+
+
+_cluster_means = jax.jit(kmeans._cluster_means, static_argnums=(3, 4, 5))  # static in every caller
+
+
+def _means_case(f, pad, n_true=20_001, k=5):
+    """Points around 3 (so that a mean is far from 0 and a relative tolerance
+    means something), labels that leave the last cluster empty, and ``pad``
+    rows after the real ones that are NOT zero and carry a real cluster's
+    label, as a pending elementwise chain can leave them."""
+    rng = np.random.default_rng(100 * f + pad)
+    x = (3.0 + rng.standard_normal((n_true + pad, f))).astype(np.float32)
+    labels = rng.integers(0, k - 1, n_true + pad).astype(np.int32)
+    x[n_true:], labels[n_true:] = 7.5, 2
+    centers = rng.standard_normal((k, f)).astype(np.float32)
+    counts = np.bincount(labels[:n_true], minlength=k)
+    onehot = np.eye(k)[labels[:n_true]]
+    sums = onehot.T @ _bf16(x[:n_true]).astype(np.float64)
+    want = np.where(counts[:, None] > 0, sums / np.maximum(counts, 1)[:, None], centers)
+    return x, labels, centers, counts, want
+
+
+@pytest.mark.parametrize("resident", [True, False], ids=["column_of_ones", "one_hot_summed"])
+@pytest.mark.parametrize("pad", [0, 5], ids=["every_row_real", "pad_rows_filled"])
+@pytest.mark.parametrize("f", [3, 16, 17])
+def test_cluster_means_sums_and_counts(f, pad, resident):
+    """Both forms of the update (the fit loop's one product with a column of
+    ones, and one iteration's product and sum) against a float64 ``onehot.T @ bf16(x)``
+    over ``np.bincount``'s counts.  A cluster holds some 5,000 rows here, so a
+    count off by one moves its mean by 2e-4 of itself, 200 times the
+    tolerance: the counts are ``np.bincount``'s.  The empty cluster keeps its
+    center to the bit and filled pad rows change nothing."""
+    x, labels, centers, counts, want = _means_case(f, pad)
+    n_true, k = x.shape[0] - pad, centers.shape[0]
+    assert counts[-1] == 0 and counts[:-1].min() > 4000
+    new, shift = _cluster_means(
+        jnp.asarray(x).astype(jnp.bfloat16), jnp.asarray(labels), jnp.asarray(centers), n_true, k, resident)
+    new = np.asarray(new)
+    np.testing.assert_allclose(new[:-1], want[:-1], rtol=1e-6)
+    assert np.array_equal(new[-1], centers[-1])
+    np.testing.assert_allclose(float(shift), ((want - centers) ** 2).sum(), rtol=1e-5)
+
+
+@pytest.mark.parametrize("resident", [True, False], ids=["column_of_ones", "one_hot_summed"])
+def test_cluster_means_over_four_devices_matches_one_device(resident):
+    """Rows split over a four-device mesh, three of them padding (20,001 rows
+    do not divide by four): GSPMD's psum of the products gives the one-device
+    means, so the counts crossed the devices exactly."""
+    from heat_tpu.parallel.comm import Communication
+
+    f, pad = 16, 3
+    x, labels, centers, _, want = _means_case(f, pad)
+    n_true, k = x.shape[0] - pad, centers.shape[0]
+    comm = Communication(jax.devices()[:4])
+    assert comm.size == 4
+    xb = jnp.asarray(x).astype(jnp.bfloat16)
+    one, one_shift = _cluster_means(xb, jnp.asarray(labels), jnp.asarray(centers), n_true, k, resident)
+    four, four_shift = _cluster_means(
+        jax.device_put(xb, comm.sharding(0)), jax.device_put(jnp.asarray(labels), comm.sharding(0)),
+        jax.device_put(jnp.asarray(centers), comm.sharding(None)), n_true, k, resident)
+    assert len(four.sharding.device_set) == 4
+    np.testing.assert_allclose(np.asarray(four), np.asarray(one), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(four)[:-1], want[:-1], rtol=1e-6)
+    assert np.array_equal(np.asarray(four)[-1], centers[-1])
+    np.testing.assert_allclose(float(four_shift), float(one_shift), rtol=1e-5)
