@@ -1,8 +1,9 @@
 """KMeans clustering, analog of heat/cluster/kmeans.py (kmeans.py:14).
 
 The centroid update — a one-hot masked matmul + sum in the reference,
-followed by an Allreduce across the sample-split axis — is a single
-segment-sum expression on the sharded global array; XLA emits the psum.
+followed by an Allreduce across the sample-split axis — is one product of
+the one-hot against the sharded global array (`_cluster_means`); XLA emits
+the psum.
 """
 
 from __future__ import annotations
@@ -14,33 +15,13 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
-from ..core import dispatch, kernels, types
+from ..core import dispatch, types
 from ..core.dndarray import DNDarray
 from ..spatial import distance
 from ..telemetry.spans import span as _span
 from ._kcluster import _KCluster
 
 __all__ = ["KMeans"]
-
-
-@partial(jax.jit, static_argnames=("n_true", "k"))
-def _lloyd_update(xp: jax.Array, centers: jax.Array, n_true: int, k: int):
-    """Trimmed Lloyd iteration: centroid update + shift ONLY.
-
-    An iteration needs no inertia and no ``|x|^2``, so it is the assignment
-    (the distance product, the argmin and its minimum) and the update (the
-    one-hot against the points, and the counts).  This program is ONE
-    iteration, the fallback behind the opt-in kernel gate: as compiled for a
-    v5e it holds no copy of the points, the convert to bfloat16 is fused into
-    both products, which read the float32 points, and the counts are a pass
-    over the labels.  The fit runs the same body inside `_lloyd_loop` over a
-    bfloat16 copy made once: there an iteration is two passes over the copy
-    and nothing else of that length (`tests/test_chip_compile.py` pins them;
-    7.65 + 4.77 ms at 10^8 x 16, 8 clusters, PERF.md, PR 28), three where
-    rows are padded and the row mask is written.  Labels and inertia come
-    from one final `_lloyd_step`.
-    """
-    return _lloyd_body(xp, centers, n_true, k, resident=False)
 
 
 @partial(jax.jit, static_argnames=("n_true", "k", "max_iter", "tol"))
@@ -50,7 +31,15 @@ def _lloyd_loop(xp: jax.Array, centers: jax.Array, n_true: int, k: int, max_iter
     A Python loop checking ``float(shift) <= tol`` costs one device->host
     round trip per iteration; here
     the convergence test runs on-device and the host syncs exactly once,
-    after the loop.  Returns (centers, n_iter, last_shift).
+    after the loop.  An iteration needs no inertia and no ``|x|^2``, so it is
+    the assignment (the distance product, the argmin and its minimum) and the
+    update (the one-hot against the points and a column of ones), over a
+    bfloat16 copy of the points made once, before the loop: two passes over
+    the copy and nothing else of that length (`tests/test_chip_compile.py`
+    pins them; 7.65 + 4.77 ms at 10^8 x 16, 8 clusters, PERF.md, PR 28),
+    three where rows are padded and the row mask is written.  Labels and
+    inertia come from one final `_lloyd_step`.  Returns (centers, n_iter,
+    last_shift).
     """
 
     def cond(carry):
@@ -59,7 +48,7 @@ def _lloyd_loop(xp: jax.Array, centers: jax.Array, n_true: int, k: int, max_iter
 
     def body(carry):
         c, i, _ = carry
-        new, shift = _lloyd_body(xp, c, n_true, k, resident=True)
+        new, shift = _lloyd_body(xp, c, n_true, k)
         return new, i + 1, shift
 
     init = (centers, jnp.int32(0), jnp.asarray(jnp.inf, jnp.float32))
@@ -111,10 +100,10 @@ def _cluster_means(xb, labels, centers, n_true, k, resident):
       and the labels once and its last column is the counts, sums of exact
       1 x 1 terms.  The compiler fuses the column into the product, and no
       pass sums the one-hot a second time (1.1 ms an iteration at 10^8 x 16).
-    * not ``resident`` (one iteration alone, `_lloyd_update` and
-      `_lloyd_step`, where the compiler fuses the convert to bfloat16 into
-      each product and holds no copy): a column between the convert and the
-      product makes it write the copy, 3.2 GB and 5.7 ms more at 10^8 x 16.
+    * not ``resident`` (one iteration alone, `_lloyd_step`, where the
+      compiler fuses the convert to bfloat16 into each product and holds no
+      copy): a column between the convert and the product makes it write the
+      copy, 3.2 GB and 5.7 ms more at 10^8 x 16.
       There the counts are the one-hot's own sum, a pass over the labels (the
       one-hot against itself as a third product reads 3.4 ms for that 1.1,
       PERF.md, PR 28).
@@ -139,14 +128,14 @@ def _cluster_means(xb, labels, centers, n_true, k, resident):
     return new, jnp.sum((new - centers) ** 2)
 
 
-def _lloyd_body(xp, centers, n_true, k, resident):
-    # does not change in the loop: the compiler makes the copy once, before it; alone, it fuses the convert into each product
+def _lloyd_body(xp, centers, n_true, k):
+    # does not change in the loop: the compiler makes the copy once, before it
     xb = xp.astype(jnp.bfloat16)
     # the scopes name the two passes in the device trace; metadata only
     with jax.named_scope("lloyd.assign"):
         labels = jnp.argmin(_half_d2(xb, centers), axis=1)
     with jax.named_scope("lloyd.update"):
-        new, shift = _cluster_means(xb, labels, centers, n_true, k, resident)
+        new, shift = _cluster_means(xb, labels, centers, n_true, k, resident=True)
     return new, shift.astype(jnp.float32)
 
 
@@ -160,7 +149,10 @@ def _lloyd_step(xp: jax.Array, centers: jax.Array, n_true: int, k: int):
     keeps the whole iteration on-device: assignment needs only
     ``|c|^2 - 2 x@c.T`` (the ``|x|^2`` row term cannot change the argmin),
     both matmuls ride the MXU, and under a sharded ``xp`` GSPMD turns the
-    segment sums into a single psum over the sample axis.
+    segment sums into a single psum over the sample axis.  Alone, it holds
+    no bfloat16 copy of the points: the compiler fuses the convert into each
+    product, so the update takes its counts from the one-hot's own sum
+    (`_cluster_means`, not ``resident``, has why).
 
     Returns (labels_padded, new_centers, shift, inertia).
     """
@@ -221,43 +213,6 @@ class KMeans(_KCluster):
         nbytes = (k * int(xp.shape[1]) + k) * xp.dtype.itemsize
         return x.comm.account_implicit("psum", nbytes, site="kmeans.lloyd")
 
-    def _update_centroids(self, x: DNDarray, matching_centroids: DNDarray) -> DNDarray:
-        """New centers = per-cluster mean (kmeans.py:80-120)."""
-        dense = x._dense()
-        if not types.heat_type_is_inexact(x.dtype):
-            dense = dense.astype(jnp.float32)
-        labels = matching_centroids._dense()
-        k = self.n_clusters
-        sums = jax.ops.segment_sum(dense, labels, num_segments=k)
-        counts = jax.ops.segment_sum(jnp.ones((dense.shape[0],), dense.dtype), labels, num_segments=k)
-        old = self._cluster_centers._dense()
-        new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1.0), old)
-        return DNDarray.from_dense(new, None, x.device, x.comm)
-
-    def _fused_step(self, x: DNDarray):
-        """Run one fused Lloyd iteration; returns the center shift and
-        updates ``self._cluster_centers``.
-
-        Default path is the trimmed XLA program (`_lloyd_update`); the
-        single-HBM-pass Pallas kernel (core/kernels.py) is opt-in via
-        HEAT_TPU_LLOYD_KERNEL=1 — on v5e it measures VPU-bound and loses
-        to XLA's multi-pass (see kernels.py for the numbers).  Labels are
-        deliberately not produced — the fit loop only needs them once,
-        after convergence (``_assign_padded``).
-        """
-        xp = x.larray_padded
-        if not types.heat_type_is_inexact(x.dtype):
-            xp = xp.astype(jnp.float32)
-        centers = self._cluster_centers._dense().astype(xp.dtype)
-        dispatch.record_external_dispatch()  # one launch per Lloyd step
-        with self._account_lloyd_psum(x, xp):
-            if kernels.LLOYD_KERNEL and kernels.lloyd_supported(xp.shape[1], self.n_clusters):
-                new, shift, _ = kernels.lloyd_update(x, centers)
-            else:
-                new, shift = _lloyd_update(xp, centers, x.shape[0], self.n_clusters)
-        self._cluster_centers = DNDarray.from_dense(new, None, x.device, x.comm)
-        return shift
-
     def _assign_padded(self, x: DNDarray):
         """Labels + inertia against the current centers (one cheap pass)."""
         xp = x.larray_padded
@@ -314,15 +269,6 @@ class KMeans(_KCluster):
                 self._cluster_centers = DNDarray.from_dense(
                     jnp.asarray(centers, dtype), None, x.device, x.comm
                 )
-        elif kernels.LLOYD_KERNEL and kernels.lloyd_supported(xp.shape[1], self.n_clusters):
-            init_centers()
-            # the opt-in Pallas path iterates from the host (one sync/iter)
-            with _span("kmeans.loop"):
-                for i in range(self.max_iter):
-                    shift = self._fused_step(x)
-                    if float(shift) <= self.tol:
-                        break
-            n_iter = i + 1
         else:
             centers = init_centers()
             # whole fit loop on-device, and the iteration count stays a

@@ -174,7 +174,6 @@ KNOBS = {
     "HEAT_TPU_GRAD_BUCKET_MB": ("float", "4", "byte bound (MiB) of one bucketed gradient-reduction psum"),
     "HEAT_TPU_FLASH": ("bool", "1", "flash-attention kernel for local attention on TPU (0 = einsum path)"),
     # -- kernels / linalg -----------------------------------------------
-    "HEAT_TPU_LLOYD_KERNEL": ("bool", "0", "opt-in fused Pallas Lloyd iteration (VPU-bound on v5e; see core/kernels.py)"),
     "HEAT_TPU_HSVD_PRECISION": ("choice", "high", "hsvd Gram-pass matmul precision: default | high | highest"),
     "HEAT_TPU_HSVD_SYRK": ("bool", "1", "one-HBM-read syrk kernel for hsvd Gram passes when supported"),
     "HEAT_TPU_HSVD_BATCHED": ("bool", "0", "opt-in batched (vmapped) leaf factorizations in the hsvd merge tree: one stacked gram+eigh over the equal-shape leaf blocks instead of the sequential per-leaf loop (the 'can't fuse eigh' A/B, scripts/bench.py hsvd)"),

@@ -497,7 +497,7 @@ class TestProgramLint:
         def launch(xp_, centers_):
             nbytes = (k * f + k) * xp_.dtype.itemsize
             with comm.account_implicit("psum", nbytes, site="kmeans.lloyd"):
-                return _lloyd_body(xp_, centers_, int(x.shape[0]), k, resident=True)
+                return _lloyd_body(xp_, centers_, int(x.shape[0]), k)
 
         # PR 27: the fit rounds the points to bfloat16, once, for both of
         # its products, and says so in the program (kmeans._half_d2).  At

@@ -147,17 +147,6 @@ def test_hsvd_rank_program_names_its_kernel_and_makes_two_passes(one_chip, for_t
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def test_lloyd_kernel_carries_its_name(one_chip, for_the_chip):
-    from heat_tpu.core import kernels
-
-    n, f, k = 2**24, 16, 8
-    compiled = kernels._lloyd_single.lower(
-        _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip), n_true=n
-    ).compile()
-    assert [name for name, opcode, _, _ in _entry_instructions(compiled)
-            if opcode == "custom-call" and name.startswith("lloyd_update")]
-
-
 def test_rfft3_leading_512_cubed(one_chip, for_the_chip):
     from heat_tpu.fft import _leading
 
@@ -186,7 +175,7 @@ def test_lloyd_step_one_chip(one_chip, for_the_chip):
     from heat_tpu.cluster import kmeans
 
     n, f, k = 2**24, 16, 8
-    compiled = kmeans._lloyd_update.lower(
+    compiled = kmeans._lloyd_step.lower(
         _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip), n_true=n, k=k
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
@@ -273,17 +262,18 @@ def test_lloyd_final_assignment_at_the_benchmark_cell(one_chip, for_the_chip):
     assert compiled.as_text().count("operand_precision={default,high}") == 1
 
 
-@pytest.mark.parametrize("program", ["update", "loop"])
+@pytest.mark.parametrize("program", ["step", "loop"])
 def test_lloyd_step_four_chips(comm4, for_the_chip, program):
-    """Rows split over four chips: whatever the update sums crosses the chips
-    together, in ONE all-reduce a program (an iteration): in the fit loop the
-    ``(k, f + 1)`` product, sums and counts; in one iteration alone the sums
-    and the counts, combined."""
+    """Rows split over four chips: whatever a program sums over the rows
+    crosses the chips together, in ONE all-reduce a program (an iteration):
+    in the fit loop the ``(k, f + 1)`` product, sums and counts; in the final
+    pass the sums, the counts and the inertia, combined into one tuple (what
+    the compiled text shows, PR 29; PR 28 read it on the chip)."""
     from heat_tpu.cluster import kmeans
 
     n, f, k = 2**24, 16, 8
     fn, more = ((kmeans._lloyd_loop, {"max_iter": 30, "tol": -1.0}) if program == "loop"
-                else (kmeans._lloyd_update, {}))
+                else (kmeans._lloyd_step, {}))
     compiled = fn.lower(
         _sds((n, f), jnp.float32, comm4.sharding(0)),
         _sds((k, f), jnp.float32, comm4.sharding(None)),
@@ -291,8 +281,8 @@ def test_lloyd_step_four_chips(comm4, for_the_chip, program):
     ).compile()
     reduces = re.findall(r"= (.*?) all-reduce(?:-start)?\(", compiled.as_text())
     assert len(reduces) == 1, reduces
-    if program == "loop":
-        assert re.sub(r"\{[^}]*\}", "", reduces[0]) == f"f32[{k},{f + 1}]", reduces
+    reduced = re.sub(r"\{[^}]*\}", "", reduces[0])
+    assert reduced == (f"f32[{k},{f + 1}]" if program == "loop" else f"(f32[{k},{f}], f32[{k}], f32[])"), reduces
     # memory_analysis is per device: a quarter of the rows each
     assert _device_bytes(compiled) < HBM_BYTES
 

@@ -9,9 +9,9 @@ both operands rounded to bfloat16 while ``|c|^2`` came from the float32
 centers, which put every boundary off by ``x . (c - bf16(c))``.
 """
 
+import itertools
 import os
 import sys
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -103,19 +103,19 @@ def test_planted_boundary_point_exposes_rounded_cross_term():
     assert both_rounded[0].argmin() == 1
 
 
-@pytest.mark.parametrize("through", ["step", "body", "update", "fit"])
+@pytest.mark.parametrize("through", ["step", "body", "fit"])
 def test_planted_boundary_point_goes_to_its_nearest_center(through):
     """The program puts the planted point where the exact distances put it:
-    in the final assignment (``_lloyd_step``), in the loop's body and in one
-    iteration alone (a point wrongly given to ``c1`` would pull ``c1`` towards
-    8) and through the public ``fit``."""
+    in the final assignment (``_lloyd_step``), in the loop's body (a point
+    wrongly given to ``c1`` would pull ``c1`` towards 8) and through the
+    public ``fit``."""
     x, centers = _planted()
     n, k = x.shape[0], centers.shape[0]
     if through == "step":
         labels, *_ = kmeans._lloyd_step(jnp.asarray(x), jnp.asarray(centers), n, k)
         assert list(np.asarray(labels)) == [0, 0, 1, 0]
-    elif through in ("body", "update"):
-        new, _ = kmeans._lloyd_body(jnp.asarray(x), jnp.asarray(centers), n, k, resident=through == "body")
+    elif through == "body":
+        new, _ = kmeans._lloyd_body(jnp.asarray(x), jnp.asarray(centers), n, k)
         np.testing.assert_allclose(np.asarray(new), [x[[0, 1, 3]].mean(axis=0), x[2]], rtol=1e-6)
     else:
         km = ht.cluster.KMeans(n_clusters=k, init=ht.array(centers), max_iter=1, tol=-1.0).fit(ht.array(x, split=0))
@@ -153,7 +153,7 @@ def _equations(jaxpr):
 
 
 @pytest.mark.parametrize("pad", [0, 3], ids=["every_row_real", "three_pad_rows"])
-@pytest.mark.parametrize("which", ["body", "update", "step"])
+@pytest.mark.parametrize("which", ["body", "step"])
 def test_lloyd_programs_make_one_product_of_the_data_each_way(which, pad):
     """The jaxpr of an iteration: the data is rounded to bfloat16 once, and
     takes part in two products, the assignment's (bfloat16 points against
@@ -161,13 +161,13 @@ def test_lloyd_programs_make_one_product_of_the_data_each_way(which, pad):
     update's (a bfloat16 one-hot against the bfloat16 points, float32 out).
     In the loop's body the points carry a column of ones, the product's last
     column is the counts and nothing sums the one-hot in a reduction of its
-    own; in one iteration alone (``_lloyd_update``, ``_lloyd_step``) the
-    product is of the points as they are and the counts are the one-hot's
-    sum.  The precision is in the program, not left to the backend's default.
-    An iota over the rows (the row mask) exists only where rows are padded."""
+    own; in one iteration alone (``_lloyd_step``) the product is of the
+    points as they are and the counts are the one-hot's sum.  The precision
+    is in the program, not left to the backend's default.  An iota over the
+    rows (the row mask) exists only where rows are padded."""
     n, f, k = 4096, 16, 8
     xp, centers = jnp.zeros((n, f), jnp.float32), jnp.zeros((k, f), jnp.float32)
-    fn = kmeans._lloyd_step.__wrapped__ if which == "step" else partial(kmeans._lloyd_body, resident=which == "body")
+    fn = kmeans._lloyd_step.__wrapped__ if which == "step" else kmeans._lloyd_body
     eqns = list(_equations(jax.make_jaxpr(lambda a, b: fn(a, b, n - pad, k))(xp, centers).jaxpr))
     narrowed = [e for e in eqns if e.primitive.name == "convert_element_type"
                 and e.invars[0].aval.shape == (n, f) and e.outvars[0].aval.dtype == jnp.bfloat16]
@@ -249,3 +249,155 @@ def test_cluster_means_over_four_devices_matches_one_device(resident):
     np.testing.assert_allclose(np.asarray(four)[:-1], want[:-1], rtol=1e-6)
     assert np.array_equal(np.asarray(four)[-1], centers[-1])
     np.testing.assert_allclose(float(four_shift), float(one_shift), rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# One iteration against NumPy over a sweep of shapes (PR 29).  The sweep and
+# the three properties under it (an empty cluster, filled pad rows, rows over
+# the mesh) stood in tests/test_kernels.py against the Pallas Lloyd kernel;
+# they are properties of Lloyd, so they moved here with `_numpy_lloyd` when the
+# kernel went, and now hold the two programs that are left.
+
+EPS = float(np.finfo(np.float32).eps)
+
+
+def _means(x, c, lbl):
+    """Per-cluster means of float64 rows; an empty cluster keeps its center."""
+    return np.stack([x[lbl == j].mean(0) if (lbl == j).any() else c[j] for j in range(c.shape[0])])
+
+
+def _numpy_lloyd(x, c):
+    """One Lloyd iteration in float64: every row's squared distance to every
+    center, and the new centers."""
+    x, c = x.astype(np.float64), c.astype(np.float64)
+    d = ((x[:, None, :] - c[None, :, :]) ** 2).sum(-1)
+    return d, _means(x, c, d.argmin(1))
+
+
+def _one_iteration(through, xp, centers, n_true):
+    """(new centers, labels or None, inertia or None) of one iteration of the
+    program: the final pass, or the fit loop held to one iteration."""
+    k = centers.shape[0]
+    if through == "step":
+        labels, new, _, inertia = kmeans._lloyd_step(jnp.asarray(xp), jnp.asarray(centers), n_true, k)
+        return np.asarray(new), np.asarray(labels)[:n_true], float(inertia)
+    new, n_iter, _ = kmeans._lloyd_loop(jnp.asarray(xp), jnp.asarray(centers), n_true, k, 1, -1.0)
+    assert int(n_iter) == 1
+    return np.asarray(new), None, None
+
+
+def _check_against_numpy(x, centers, new=None, labels=None, inertia=None):
+    """The program's stated arithmetic is exact Lloyd on the points rounded to
+    bfloat16 (the centers stay float32), so NumPy gets the rounded points and
+    every tolerance is float32's rounding of that arithmetic, derived here:
+
+    * a term ``|c|^2 - 2 xb.c`` is ``f`` products and ``f + 1`` additions: off
+      by at most ``(f + 2) eps (|c|^2 + 2 sum_l |xb_l c_l|)``.  A row whose two
+      nearest centers lie further apart than twice that is decided and carries
+      NumPy's label; any other row may carry either of the two, so without the
+      program's labels (the loop returns none) the new centers have to be the
+      means under one of those assignments: a handful of rows, enumerated.
+    * a mean is a float32 sum of at most ``n`` exact bfloat16 values; its
+      rounding errors add as a random walk (Higham and Mary 2019: sqrt(n) u
+      for n u), so a center lies within ``4 sqrt(n) eps max|xb|``: 6e-5 at
+      1,003 rows, where a row in the wrong cluster moves a center by 1e-2.
+    * `_lloyd_step` takes ``|x|^2`` from the UNROUNDED points and the
+      assignment's minimum from the rounded ones, so its inertia is NumPy's on
+      the rounded points plus ``sum |x|^2 - |xb|^2`` (up to 2^-8 of
+      ``sum |x|^2``), to the rounding of each row's terms and of the float32 sum
+      over the rows: ``(f + 2 + 4 sqrt(n)) eps sum (|x| + max|c|)^2``."""
+    n, f = x.shape
+    xb, c = _bf16(x).astype(np.float64), centers.astype(np.float64)
+    d, _ = _numpy_lloyd(xb, c)
+    two = np.argsort(d, axis=1)[:, :2]
+    gap = np.diff(np.take_along_axis(d, two, axis=1), axis=1)[:, 0]
+    slack = (f + 2) * EPS * ((c * c).sum(axis=1)[None, :] + 2 * np.abs(xb) @ np.abs(c).T).max(axis=1)
+    open_rows = np.flatnonzero(gap <= 2 * slack)
+    want_labels = two[:, 0].copy()
+    if labels is not None:
+        assert np.all((labels == two[:, 0]) | (labels == two[:, 1]))
+        assert np.array_equal(np.delete(labels, open_rows), np.delete(want_labels, open_rows))
+        candidates = [labels]
+    else:
+        assert len(open_rows) <= 10, len(open_rows)
+        candidates = []
+        for pick in itertools.product((0, 1), repeat=len(open_rows)):
+            want_labels[open_rows] = two[open_rows, list(pick)]
+            candidates.append(want_labels.copy())
+    if new is not None:
+        off = min(np.abs(new - _means(xb, c, lbl)).max() for lbl in candidates)
+        assert off <= 4 * np.sqrt(n) * EPS * np.abs(xb).max(), off
+    if inertia is not None:
+        x64 = x.astype(np.float64)
+        want = d.min(axis=1).sum() + (x64 ** 2).sum() - (xb ** 2).sum()
+        size = (np.linalg.norm(x64, axis=1) + np.linalg.norm(c, axis=1).max()) ** 2
+        assert abs(inertia - want) <= (f + 2 + 4 * np.sqrt(n)) * EPS * size.sum(), (inertia, want)
+
+
+def _padded(x, fill=0.0):
+    """Rows padded to a multiple of 32 (the mesh's quantum), pad rows ``fill``."""
+    xp = np.full((-(-x.shape[0] // 32) * 32, x.shape[1]), fill, np.float32)
+    xp[: x.shape[0]] = x
+    return xp
+
+
+# the shapes the kernel's two sweeps used: f from 1 to 128, k from 2 to 16 and
+# not a power of two, n off the padding quantum; the last is 40,000 rows of 64
+SHAPES = [(1003, 16, 8), (517, 8, 5), (130, 4, 7), (999, 16, 12), (96, 128, 8), (64, 64, 2),
+          (517, 128, 4), (517, 128, 13), (517, 64, 2), (517, 32, 8), (517, 16, 3), (517, 8, 9),
+          (517, 4, 16), (517, 2, 2), (517, 1, 4), (40_000, 64, 3)]
+
+
+@pytest.mark.parametrize("through", ["step", "loop"])
+@pytest.mark.parametrize("n,f,k", SHAPES, ids=["x".join(map(str, s)) for s in SHAPES])
+def test_one_iteration_matches_numpy_lloyd(n, f, k, through):
+    """Labels, new centers and inertia of the final pass, and the new centers
+    of the fit loop's one iteration, against NumPy over the sweep."""
+    rng = np.random.default_rng(1000 * f + k)
+    x = rng.standard_normal((n, f)).astype(np.float32)
+    centers = rng.standard_normal((k, f)).astype(np.float32)
+    new, labels, inertia = _one_iteration(through, _padded(x), centers, n)
+    _check_against_numpy(x, centers, new, labels, inertia)
+
+
+@pytest.mark.parametrize("through", ["step", "loop"])
+def test_empty_cluster_keeps_its_center(through):
+    """A cluster that takes no point keeps its center to the bit (the
+    ``where`` guard of `_cluster_means`), and nothing is NaN."""
+    x = np.zeros((64, 16), np.float32)  # every point at the origin
+    centers = np.stack([np.zeros(16), np.full(16, 100.0)]).astype(np.float32)
+    new, labels, inertia = _one_iteration(through, x, centers, 64)
+    assert not np.isnan(new).any() and (inertia is None or inertia == 0.0)
+    assert np.array_equal(new[1], centers[1])
+    _check_against_numpy(x, centers, new, labels, inertia)
+
+
+@pytest.mark.parametrize("through", ["step", "loop"])
+def test_filled_pad_rows_contribute_nothing(through):
+    """40 real rows in a 64-row buffer whose pad rows hold 1e6, not zero:
+    sums, counts and inertia are those of the 40 rows alone (the row mask)."""
+    rng = np.random.default_rng(9)
+    n, f, k = 40, 16, 5
+    x = rng.standard_normal((n, f)).astype(np.float32)
+    centers = rng.standard_normal((k, f)).astype(np.float32)
+    new, labels, inertia = _one_iteration(through, _padded(x, fill=1e6), centers, n)
+    _check_against_numpy(x, centers, new, labels, inertia)
+
+
+def test_one_iteration_of_fit_over_the_mesh_matches_numpy_lloyd():
+    """1,003 rows split over the 8 test devices (uneven: the last shard is
+    padded), one iteration of the public ``fit``: the centers are NumPy's
+    after one iteration, the labels and the inertia its assignment against
+    those centers, so the psum of sums, counts and inertia crossed the
+    devices whole."""
+    n, f, k = 1003, 16, 8
+    rng = np.random.default_rng(1)
+    x = rng.standard_normal((n, f)).astype(np.float32)
+    centers = rng.standard_normal((k, f)).astype(np.float32)
+    hx = ht.array(x, split=0)
+    assert hx.comm.size == 8 and hx.larray_padded.shape[0] > n
+    km = ht.cluster.KMeans(n_clusters=k, init=ht.array(centers), max_iter=1, tol=-1.0).fit(hx)
+    assert km.n_iter_ == 1
+    new = km.cluster_centers_.numpy()
+    _check_against_numpy(x, centers, new)
+    _check_against_numpy(x, new, labels=km.labels_.numpy(), inertia=km.inertia_)  # the final pass moves no center
