@@ -172,8 +172,9 @@ def _hsvd_body(dense: jnp.ndarray, trunc: int, p: int, no_of_merges: int, comput
         # instead of two: the r4 profile showed this config bandwidth-
         # bound on those reads (VERDICT r4 #4).  The Gram itself goes
         # through the Pallas syrk kernel where supported — XLA's generic
-        # dot streams x twice (lhs x.T + rhs x; measured 5.7 ms where one
-        # read is 3.3 ms), the kernel reads each row tile once.  The
+        # dot streams x twice (lhs x.T + rhs x), the kernel reads each row
+        # tile once, at the memory's own rate (8.51 ms for the cell's
+        # 6.44 GB, chip run, PR 30).  The
         # kernel path needs a SINGLE-DEVICE operand (pallas_call is not
         # GSPMD-partitionable), so the caller gates ``syrk_ok`` on the
         # communication layout outside the jit.
@@ -400,12 +401,14 @@ def _gram_precision():
     """Matmul precision for hsvd's Gram passes.
 
     Default HIGH = compensated bf16x3 (each f32 operand split into hi+lo
-    bfloat16, three MXU passes) — ~1e-6 relative error on G, half the MXU
-    time of the 6-pass HIGHEST policy, and the hsvd truncation error
-    dominates it by orders of magnitude for any rank-truncated use
-    (VERDICT r4 #4's sanctioned bf16-accumulate move).  Every non-Gram
-    matmul in the pipeline stays HIGHEST; set HEAT_TPU_HSVD_PRECISION=
-    highest to force full f32 throughout."""
+    bfloat16; three terms, which XLA takes as three MXU passes and
+    ``kernels.gram_syrk`` as two, the third being the second's transpose)
+    — ~3e-6 relative error on G (the dropped lo^T lo reads the diagonal
+    that much low), half the MXU time of the 6-pass HIGHEST policy, and the
+    hsvd truncation error dominates it by orders of magnitude for any
+    rank-truncated use (VERDICT r4 #4's sanctioned bf16-accumulate move).
+    Every non-Gram matmul in the pipeline stays HIGHEST; set
+    HEAT_TPU_HSVD_PRECISION=highest to force full f32 throughout."""
     from .._env import precision_from_env
 
     return precision_from_env("HEAT_TPU_HSVD_PRECISION", "high")

@@ -92,14 +92,42 @@ def _device_bytes(compiled) -> int:
     )
 
 
-def test_gram_syrk_north_star_shape(one_chip, for_the_chip):
+@pytest.mark.parametrize(
+    "rows, cols",
+    [(2**22, 128), (12_582_912, 128), (2**20, 256), (2**20, 384), (2**20, 512)],
+    ids=["4Mx128", "cell_12.6Mx128", "1Mx256", "1Mx384", "1Mx512"],
+)
+def test_gram_syrk_north_star_shape(one_chip, for_the_chip, rows, cols):
+    """Every width ``syrk_supported`` admits, at the tile the width gives
+    (``_SYRK_TILE_BYTES``): the kernel and its buffers fit the chip's fast
+    memory under the default limit.  Until PR 30 a tile was 2048 rows at
+    every width, and 384 and 512 did not compile (RESOURCE_EXHAUSTED in vmem)."""
     from heat_tpu.core import kernels
 
+    assert kernels.syrk_supported(rows, cols, jnp.float32)
     compiled = jax.jit(kernels.gram_syrk).lower(
-        _sds((2**22, 128), jnp.float32, one_chip)
+        _sds((rows, cols), jnp.float32, one_chip)
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_gram_syrk_takes_a_row_remainder_without_a_copy(one_chip, for_the_chip):
+    """Rows that are no multiple of the tile: the kernel's grid stops at
+    the last full tile and reads ``x`` itself.  Until PR 30 ``x[:m0]`` stood
+    in front of the custom call, a ``slice`` that copied all of it
+    (2,147,483,648 B of temporaries at this shape)."""
+    from heat_tpu.core import kernels
+
+    rows = 2**22 + 137
+    compiled = jax.jit(kernels.gram_syrk).lower(
+        _sds((rows, 128), jnp.float32, one_chip)
+    ).compile()
+    instructions = _entry_instructions(compiled)
+    (kernel,) = [i for i in instructions if i[1] == "custom-call"]
+    opcode_of = {name: opcode for name, opcode, _, _ in instructions}
+    assert kernel[0].startswith("gram_syrk") and [opcode_of[o] for o in kernel[3]] == ["parameter"], kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
 def _entry_instructions(compiled) -> list:

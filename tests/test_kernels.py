@@ -3,24 +3,81 @@ interpreter on the virtual CPU mesh, same code path as Mosaic on TPU.
 The module holds one kernel, ``gram_syrk``."""
 
 import numpy as np
+import pytest
 
+import jax
 import jax.numpy as jnp
+
+WIDTHS = [128, 256, 512]
+#: rows as a function of one tile's rows at the width
+ROWS = {
+    "two_tiles_and_137": lambda tile: 2 * tile + 137,  # kernel + XLA tail
+    "one_tile": lambda tile: tile,                     # kernel alone, one grid step
+    "one_row_short": lambda tile: tile - 1,            # under the gate: the XLA product
+}
+
+
+def _split(blk):
+    hi = blk.astype(jnp.bfloat16)
+    lo = (blk - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, lo
+
+
+def _three_products(x, rows):
+    """The kernel's arithmetic in plain ``jax.numpy``, with the third
+    product written out: per tile hi^T hi + hi^T lo + lo^T hi in float32,
+    Kahan-summed over the tiles, the row remainder at ``HIGH``."""
+    m, n = x.shape
+    dims = (((0,), (0,)), ((), ()))
+    dot = lambda a, b: jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+    m0 = m // rows * rows
+    if m0 == 0:
+        return jnp.matmul(x.T, x, precision=jax.lax.Precision.HIGH)
+    acc = comp = jnp.zeros((n, n), jnp.float32)
+    for i in range(m0 // rows):
+        hi, lo = _split(x[i * rows:(i + 1) * rows])
+        y = dot(hi, hi) + dot(hi, lo) + dot(lo, hi) - comp
+        t = acc + y
+        comp = (t - acc) - y
+        acc = t
+    if m0 < m:
+        acc = acc + jnp.matmul(x[m0:].T, x[m0:], precision=jax.lax.Precision.HIGH)
+    return acc
 
 
 class TestSyrk:
-    """gram_syrk: the one-read Gram kernel behind hsvd (r5)."""
+    """gram_syrk: the one-read Gram kernel behind hsvd (r5; two products a
+    step on a tile of ``_SYRK_TILE_BYTES`` since PR 30)."""
 
-    def test_values_with_remainder_tail(self, ht):
+    @pytest.mark.parametrize("kind", list(ROWS))
+    @pytest.mark.parametrize("n", WIDTHS)
+    def test_values(self, ht, n, kind):
         from heat_tpu.core import kernels
 
-        rng = np.random.default_rng(3)
-        m = 2 * kernels._SYRK_TILE + 137  # exercises kernel + XLA tail
-        x = rng.standard_normal((m, 128)).astype(np.float32)
-        assert kernels.syrk_supported(m, 128, jnp.float32)
+        tile = kernels._syrk_rows(n)
+        assert tile * n * 4 <= kernels._SYRK_TILE_BYTES and tile % 128 == 0
+        m = ROWS[kind](tile)
+        assert kernels.syrk_supported(m, n, jnp.float32) == (kind != "one_row_short")
+        x = np.random.default_rng(3).standard_normal((m, n)).astype(np.float32)
         g = np.asarray(kernels.gram_syrk(jnp.asarray(x)))
+
+        # the same split with the third product taken, not transposed: bit for bit
+        np.testing.assert_array_equal(g, np.asarray(_three_products(jnp.asarray(x), tile)))
+
+        # Against float64.  What the three terms give is computed here, from
+        # the same split in float64: x = hi + lo + e, |e| <= 2^-16 |x|, and
+        # lo^T lo is dropped, so the diagonal reads ~3e-6 low.  On top of it a
+        # float32 sum of one tile's rows walks at most sqrt(rows) * 2^-24 from
+        # the exact sum (the Kahan buffer keeps the tiles from adding to it).
         want = x.astype(np.float64).T @ x.astype(np.float64)
-        rel = np.linalg.norm(g - want) / np.linalg.norm(want)
-        assert rel < 5e-5, rel  # compensated bf16x3 + Kahan accumulation
+        hi, lo = (np.asarray(p, np.float64) for p in _split(jnp.asarray(x)))
+        three = hi.T @ hi + hi.T @ lo + lo.T @ hi
+        rel = lambda a, b: np.linalg.norm(a - b) / np.linalg.norm(want)
+        summing = np.sqrt(tile) * 2.0**-24
+        assert 1e-6 < rel(three, want) < 5e-6  # the tolerance is the terms', not a guess
+        if kind != "one_row_short":  # the XLA product at HIGH is plain float32 on the CPU, nearer than the terms
+            assert rel(g, three) < summing, (rel(g, three), summing)
+        assert rel(g, want) < rel(three, want) + summing, rel(g, want)
         np.testing.assert_allclose(g, g.T, rtol=1e-5, atol=1e-4)
 
     def test_unsupported_shapes(self, ht):
@@ -29,6 +86,15 @@ class TestSyrk:
         assert not kernels.syrk_supported(100, 128, jnp.float32)  # too short
         assert not kernels.syrk_supported(10000, 100, jnp.float32)  # lanes
         assert not kernels.syrk_supported(10000, 128, jnp.float64)  # dtype
+        assert not kernels.syrk_supported(10000, 640, jnp.float32)  # wider than fits
+
+    @pytest.mark.parametrize("n, rows", [(128, 4096), (256, 2048), (384, 1280), (512, 1024)])
+    def test_tile_rows_follow_the_width(self, ht, n, rows):
+        from heat_tpu.core import kernels
+
+        assert kernels._syrk_rows(n) == rows
+        assert kernels.syrk_supported(rows, n, jnp.float32)
+        assert not kernels.syrk_supported(rows - 1, n, jnp.float32)
 
     def test_hsvd_uses_it_and_matches(self, ht):
         import heat_tpu as htm
@@ -50,3 +116,23 @@ class TestSyrk:
             jnp.asarray(xh), 15, 1, 2, 10, True, "float32", syrk_ok=True
         )
         np.testing.assert_allclose(np.asarray(s2), want_s, rtol=1e-3)
+
+    def test_hsvd_rank_takes_the_kernel_at_width_128(self, ht):
+        """One tile and a tail at the width the kernel serves: the traced
+        program holds the ``pallas_call`` and the singular values are the
+        float64 ones to the Gram's 1e-6."""
+        from heat_tpu.core import kernels
+        from heat_tpu.core.linalg.svdtools import _hsvd_rank_jit
+
+        m = kernels._syrk_rows(128) + 11
+        xh = (np.random.default_rng(5).standard_normal((m, 128)) * np.geomspace(1.0, 1e-2, 128)).astype(np.float32)
+        args = (jnp.asarray(xh), 15, 1, 2, 10, True, "float32")
+        assert "pallas_call" in str(jax.make_jaxpr(
+            lambda a: _hsvd_rank_jit(a, *args[1:], syrk_ok=True))(args[0]))
+        assert "pallas_call" not in str(jax.make_jaxpr(
+            lambda a: _hsvd_rank_jit(a, *args[1:], syrk_ok=False))(args[0]))
+        u, s, v, err = _hsvd_rank_jit(*args, syrk_ok=True)
+        want_s = np.linalg.svd(xh.astype(np.float64), compute_uv=False)[:10]
+        np.testing.assert_allclose(np.asarray(s), want_s, rtol=1e-5)
+        un = np.asarray(u, np.float64)
+        np.testing.assert_allclose(un.T @ un, np.eye(10), atol=1e-4)
