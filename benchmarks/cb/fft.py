@@ -1,8 +1,12 @@
 """FFT continuous benchmarks: pencil-decomposed split-axis transforms.
 
-The reference has no FFT cb suite; this one tracks the round-2 pencil
-collective (all_to_all transpose instead of GSPMD's all-gather) on the
-shapes the 3-D FFT baseline config uses."""
+The reference has no FFT cb suite; this one tracks the pencil on the shapes
+the 3-D FFT baseline config uses.  Since PR 31 every transform of a split
+array is ONE ``shard_map`` program: along the split axis ``all_to_all``, the
+transform, ``all_to_all`` back; along the other axes XLA's ``fft`` on each
+device's own slab (GSPMD gathers a sharded array for it on some backends).
+``fftn_pencil`` at 1024^3 over four chips is the chip benchmark's cell
+``fftn-pencil-1024.4chip`` (``chipbench/``, PERF.md)."""
 
 # flake8: noqa
 import heat_tpu as ht

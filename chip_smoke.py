@@ -651,8 +651,8 @@ def phase_four_chips(seed: int, platform: str, comm, n: int = 2**24, f: int = 16
 
     # 3-D FFT through the all-to-all pencil
     g = ht.random.randn(fft_n, fft_n, fft_n, split=0, comm=comm)
-    pencil = importlib.import_module("heat_tpu.fft.fft")._pencil_partner(g, 0, None)
-    check(pencil is not None, "the pencil path does not apply")
+    route = importlib.import_module("heat_tpu.fft.fft")._route(g, ((0, None), (1, None), (2, None)))
+    check(route == "pencil", "the pencil path does not apply")
     y = ht.fft.fftn(g)
     f_err = max_rel_err(y.numpy(), np.fft.fftn(g.numpy().astype(np.float64)))
     check(f_err < 1e-4, f"pencil fftn vs numpy at {fft_n}^3", err=f_err)

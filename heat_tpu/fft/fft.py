@@ -3,11 +3,13 @@
 The reference implements pencil-decomposition FFT by hand: a transform
 along the split axis transposes that axis to 0, resplits to 1 (an MPI
 Alltoallw with subarray datatypes), runs the local torch FFT, and resplits
-back (``__fft_op`` fft.py:40-138, ``__fftn_op`` :139-298).  Under GSPMD a
-single ``jnp.fft.*`` call over the sharded global array compiles to exactly
-that pencil schedule (transpose-based distributed FFT with all-to-alls on
-the mesh) — SURVEY.md §3.6.  What remains here is axis/split bookkeeping
-and the real-transform Nyquist length arithmetic.
+back (``__fft_op`` fft.py:40-138, ``__fftn_op`` :139-298).  Here a split
+array is transformed by one ``shard_map`` program over its split axis: the
+same pencil (``all_to_all``, the transform, ``all_to_all`` back) along the
+split axis and XLA's ``fft`` on each device's own slab along the others.  A
+``jnp.fft.*`` call on the sharded global array is NOT that: GSPMD does not
+keep a sharded batch axis through the ``fft`` operation and, on the CPU
+mesh, gathers the whole array onto every device (PERF.md, PR 31).
 """
 
 from __future__ import annotations
@@ -18,11 +20,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map as _shard_map
-from jax.sharding import PartitionSpec as P
 
 from ..core import types
 from ..core.dndarray import DNDarray
 from ..core.stride_tricks import sanitize_axis
+from ..telemetry.spans import span as _span
 
 __all__ = [
     "fft",
@@ -313,12 +315,8 @@ def _pencil_planar_kind_fn(
     def run(*planes):
         re = planes[0]
         im = planes[1] if have_im else None
-        tre = jax.lax.all_to_all(re, name, split_axis=partner, concat_axis=axis, tiled=True)
-        tim = (
-            jax.lax.all_to_all(im, name, split_axis=partner, concat_axis=axis, tiled=True)
-            if have_im
-            else None
-        )
+        tre = comm.all_to_all(re, split_axis=partner, concat_axis=axis)
+        tim = comm.all_to_all(im, split_axis=partner, concat_axis=axis) if have_im else None
         idx = tuple(slice(0, n_true) if d == axis else slice(None) for d in range(ndim))
         tre = tre[idx]
         tim = tim[idx] if have_im else None
@@ -334,11 +332,11 @@ def _pencil_planar_kind_fn(
             ore, oim = _pl.hfft1(tre, tim, axis, n_param, norm), None
         widths = [(0, m_pad - m_out) if d == axis else (0, 0) for d in range(ndim)]
         ore = jnp.pad(ore, widths)
-        rre = jax.lax.all_to_all(ore, name, split_axis=axis, concat_axis=partner, tiled=True)
+        rre = comm.all_to_all(ore, split_axis=axis, concat_axis=partner)
         if oim is None:
             return (rre,)
         oim = jnp.pad(oim, widths)
-        rim = jax.lax.all_to_all(oim, name, split_axis=axis, concat_axis=partner, tiled=True)
+        rim = comm.all_to_all(oim, split_axis=axis, concat_axis=partner)
         return (rre, rim)
 
     n_in = 2 if have_im else 1
@@ -471,129 +469,174 @@ def _planar_split_chain(y: DNDarray, kind: str, axes_ns, norm) -> DNDarray:
 
 
 # ----------------------------------------------------------------------
-# 1-D transforms (fft.py:299-420)
+# split arrays: every transform is ONE ``shard_map`` program over the
+# split axis.  GSPMD does not keep a sharded batch axis through XLA's
+# ``fft`` operation: ``jnp.fft.*`` on a sharded global array compiles to an
+# all-gather of the whole array and a transform of all of it on every
+# device (PERF.md, PR 31).  Inside the ``shard_map`` each device sees its
+# own slab, whose other axes are whole, so a transform along axes that are
+# not split is the slab's own (a ``local`` stage), and a transform along
+# the split axis is the pencil: ``all_to_all`` so that the axis becomes
+# whole on the device, the transform, ``all_to_all`` back -- the
+# reference's pencil resplit (fft.py:100-137).  No stage gathers.
 # ----------------------------------------------------------------------
-# ----------------------------------------------------------------------
-# pencil decomposition: FFT along the split axis WITHOUT gathering.
-# GSPMD lowers a split-axis FFT to an all-gather (every device pays the
-# full array); the pencil program instead all_to_all-transposes so the
-# transform axis becomes device-local, runs the local FFT, and transposes
-# back — p x less traffic and O(N/p) memory, the reference's pencil
-# resplit (fft.py:100-137) as one shard_map program.
-# ----------------------------------------------------------------------
-import functools as _functools
+_COMPLEX_OF = {"fft": "fft", "rfft": "fft", "hfft": "fft", "ifft": "ifft", "ihfft": "ifft", "irfft": "ifft"}
 
 
-def _pencil_partner(x: DNDarray, axis: int, n) -> Optional[int]:
-    """Axis to trade in the all_to_all transpose, or None if ineligible."""
-    comm = x.comm
-    if comm.size <= 1 or x.split != axis or x.ndim < 2 or n is not None:
-        return None
-    for d in range(x.ndim):
-        if d != axis and x.shape[d] % comm.size == 0:
-            return d
-    return None
+def _stages(kind: str, axes_ns, split: int, norm) -> tuple:
+    """The transform as stages in NumPy's order for each kind: the real
+    transform of ``rfft`` / ``ihfft`` first and of ``irfft`` / ``hfft`` last,
+    on the last axis; between them the complex passes, the split axis's
+    (``("pencil", op, n, norm)``) ahead of the local axes', which go as one
+    n-D call (``("local", op, size, axes, norm)``: the arguments of
+    ``jnp.fft.<op>`` after the array)."""
+    cplx = _COMPLEX_OF[kind]
+    head = axes_ns[-1:] if kind in ("rfft", "ihfft") else ()
+    tail = axes_ns[-1:] if kind in ("irfft", "hfft") else ()
+    mid = axes_ns[: len(axes_ns) - len(head) - len(tail)]
+
+    def one(op, a, n):
+        return ("pencil", op, n, norm) if a == split else ("local", op, n, a, norm)
+
+    stages = [one(kind, a, n) for a, n in head]
+    stages += [one(cplx, a, n) for a, n in mid if a == split]
+    local = [(a, n) for a, n in mid if a != split]
+    if local:
+        size = None if all(n is None for _, n in local) else tuple(n for _, n in local)
+        stages.append(("local", cplx + "n", size, tuple(a for a, _ in local), norm))
+    stages += [one(kind, a, n) for a, n in tail]
+    return tuple(stages)
 
 
-@_functools.lru_cache(maxsize=128)
-def _pencil_fn(comm, kind: str, axis: int, partner: int, n_true: int, ndim: int, norm):
-    """Jitted, cached pencil-FFT executable."""
-    name = comm.axis_name
-    fft_op = getattr(jnp.fft, kind)
-    spec = P(*[name if d == axis else None for d in range(ndim)])
+def _pencil_stage(comm, blk, axis: int, n_true: int, op: str, n, norm):
+    """One transform along the split axis of a slab: the partner axis (one
+    the mesh divides if there is one, else the one that pads least; padded
+    here, on the device) is traded for the split axis, whose canonical
+    padding rows are left out of the transform, and traded back."""
+    partner = _pencil_pick_partner(blk.shape, axis, comm)
+    n_partner = blk.shape[partner]
+
+    def fit(a, d, extent):  # zero rows up to ``extent`` along ``d``, or the first ``extent`` rows
+        if a.shape[d] < extent:
+            return jnp.pad(a, [(0, extent - a.shape[d]) if i == d else (0, 0) for i in range(a.ndim)])
+        return a[tuple(slice(0, extent) if i == d else slice(None) for i in range(a.ndim))]
+
+    with jax.named_scope("fft.alltoall.in"):
+        t = comm.all_to_all(fit(blk, partner, comm.padded_extent(n_partner)), split_axis=partner, concat_axis=axis)
+        t = fit(t, axis, n_true)
+    with jax.named_scope("fft.split_axis"):
+        res = getattr(jnp.fft, op)(t, n, axis, norm)
+    with jax.named_scope("fft.alltoall.out"):
+        n_out = res.shape[axis]
+        res = comm.all_to_all(fit(res, axis, comm.padded_extent(n_out)), split_axis=axis, concat_axis=partner)
+        return fit(res, partner, n_partner), n_out
+
+
+@_functools.lru_cache(maxsize=256)
+def _slab_program(comm, split: int, ndim: int, n_true: int, stages: tuple):
+    """The jitted ``shard_map`` program of ``stages`` over the split axis:
+    padded array in, padded result out, both with the canonical sharding."""
+    spec = comm.sharding(split, ndim).spec
 
     def body(blk):
-        # blk: (.., padded_n/p at axis, .., full at partner, ..)
-        t = jax.lax.all_to_all(blk, name, split_axis=partner, concat_axis=axis, tiled=True)
-        # transform axis is now full locally; padding rows are excluded
-        # from the transform and re-appended (don't-care bytes)
-        idx = tuple(slice(0, n_true) if d == axis else slice(None) for d in range(ndim))
-        res = fft_op(t[idx], axis=axis, norm=norm)
-        widths = [(0, t.shape[axis] - n_true) if d == axis else (0, 0) for d in range(ndim)]
-        res = jnp.pad(res, widths)
-        return jax.lax.all_to_all(res, name, split_axis=axis, concat_axis=partner, tiled=True)
+        if not jnp.issubdtype(blk.dtype, jnp.inexact):
+            blk = blk.astype(jnp.float32)
+        n_split = n_true
+        for stage in stages:
+            if stage[0] == "pencil":
+                blk, n_split = _pencil_stage(comm, blk, split, n_split, *stage[1:])
+            else:
+                with jax.named_scope("fft.local"):
+                    blk = getattr(jnp.fft, stage[1])(blk, *stage[2:])
+        return blk
 
-    return jax.jit(
-        _shard_map(body, mesh=comm.mesh, in_specs=spec, out_specs=spec)
-    )
+    return jax.jit(_shard_map(body, mesh=comm.mesh, in_specs=spec, out_specs=spec))
 
 
-def _pencil_transform(x: DNDarray, kind: str, axis: int, partner: int, norm) -> DNDarray:
-    from ..core.dndarray import DNDarray as _D
+def _transform_padded(blk, comm, split: int, n_true: int, stages: tuple):
+    """The seam of every split transform: the padded array goes in, the
+    padded result comes out (``chipbench/drivers/fftn_pencil.py`` plants its
+    faults around this one function)."""
+    return _slab_program(comm, split, blk.ndim, n_true, stages)(blk)
 
-    blk = x.larray_padded
-    if not types.heat_type_is_inexact(x.dtype):
-        blk = blk.astype(jnp.float32)
-    out = _pencil_fn(x.comm, kind, axis, partner, x.shape[axis], x.ndim, norm)(blk)
-    return _D(out, x.shape, types.canonical_heat_type(out.dtype), axis, x.device, x.comm)
+
+def _route(x: DNDarray, axes_ns) -> str:
+    """``pencil``: a split array transformed along its split axis;
+    ``local``: a split array transformed along other axes only; ``dense``:
+    ``jnp.fft`` on the whole array where nothing is split (or one device
+    holds it all), and for a 1-D split array, which has no axis to trade
+    (it gathers: PERF.md section 7)."""
+    if x.split is None or x.comm.size == 1:
+        return "dense"
+    if all(a != x.split for a, _ in axes_ns):
+        return "local"
+    return "pencil" if x.ndim >= 2 else "dense"
+
+
+def _transform(x: DNDarray, kind: str, axes_ns, norm, dense_fn, route: Optional[str] = None) -> DNDarray:
+    """Every non-planar entry: ``kind`` over ``axes_ns`` (``(axis, n)``
+    pairs) by the route the array's split gives, ``dense_fn`` being the
+    ``jnp.fft`` call of the dense route.  The result is split as ``x``."""
+    axes_ns = tuple((int(a), None if n is None else int(n)) for a, n in axes_ns)
+    if (route or _route(x, axes_ns)) == "dense":
+        with _span("fft.dispatch"):
+            result = dense_fn(_complex_dense(x))
+        with _span("fft.wrap"):
+            return _wrap(x, result)
+    split, stages = x.split, _stages(kind, axes_ns, x.split, norm)
+    with _span("fft.dispatch"):
+        out = _transform_padded(x.larray_padded, x.comm, split, x.shape[split], stages)
+    with _span("fft.wrap"):
+        # the program's result is padded and placed as a DNDarray stores it: nothing is copied
+        n_split = x.shape[split]
+        for stage in stages:
+            if stage[0] == "pencil":
+                n_split = _pencil_out_len(stage[1], n_split, stage[2])
+        gshape = tuple(n_split if d == split else e for d, e in enumerate(out.shape))
+        return DNDarray(out, gshape, types.canonical_heat_type(out.dtype), split, x.device, x.comm)
+
+
+# ----------------------------------------------------------------------
+# 1-D transforms (fft.py:299-420)
+# ----------------------------------------------------------------------
+def _fft1(x: DNDarray, kind: str, n, axis: int, norm) -> DNDarray:
+    _check(x)
+    if kind == "rfft" and types.heat_type_is_complexfloating(x.dtype):
+        raise TypeError(f"x must be a real-typed DNDarray, is {x.dtype.__name__}")
+    axis = sanitize_axis(x.shape, axis)
+    if _use_planar():
+        return _planar_entry(x, kind, ((axis, n),), norm)
+    return _transform(x, kind, ((axis, n),), norm, lambda a: getattr(jnp.fft, kind)(a, n=n, axis=axis, norm=norm))
 
 
 def fft(x: DNDarray, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None) -> DNDarray:
     """1-D complex FFT along ``axis`` (fft.py:310)."""
-    _check(x)
-    axis = sanitize_axis(x.shape, axis)
-    if _use_planar():
-        return _planar_entry(x, "fft", ((axis, n),), norm)
-    partner = _pencil_partner(x, axis, n)
-    if partner is not None:
-        return _pencil_transform(x, "fft", axis, partner, norm)
-    result = jnp.fft.fft(_complex_dense(x), n=n, axis=axis, norm=norm)
-    return _wrap(x, result)
+    return _fft1(x, "fft", n, axis, norm)
 
 
 def ifft(x: DNDarray, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None) -> DNDarray:
     """1-D inverse FFT (fft.py:575)."""
-    _check(x)
-    axis = sanitize_axis(x.shape, axis)
-    if _use_planar():
-        return _planar_entry(x, "ifft", ((axis, n),), norm)
-    partner = _pencil_partner(x, axis, n)
-    if partner is not None:
-        return _pencil_transform(x, "ifft", axis, partner, norm)
-    result = jnp.fft.ifft(_complex_dense(x), n=n, axis=axis, norm=norm)
-    return _wrap(x, result)
+    return _fft1(x, "ifft", n, axis, norm)
 
 
 def rfft(x: DNDarray, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None) -> DNDarray:
     """Real-input FFT; output truncated at Nyquist (fft.py:878)."""
-    _check(x)
-    if types.heat_type_is_complexfloating(x.dtype):
-        raise TypeError(f"x must be a real-typed DNDarray, is {x.dtype.__name__}")
-    axis = sanitize_axis(x.shape, axis)
-    if _use_planar():
-        return _planar_entry(x, "rfft", ((axis, n),), norm)
-    result = jnp.fft.rfft(_complex_dense(x), n=n, axis=axis, norm=norm)
-    return _wrap(x, result)
+    return _fft1(x, "rfft", n, axis, norm)
 
 
 def irfft(x: DNDarray, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None) -> DNDarray:
     """Inverse of rfft, real output (fft.py:700)."""
-    _check(x)
-    axis = sanitize_axis(x.shape, axis)
-    if _use_planar():
-        return _planar_entry(x, "irfft", ((axis, n),), norm)
-    result = jnp.fft.irfft(_complex_dense(x), n=n, axis=axis, norm=norm)
-    return _wrap(x, result)
+    return _fft1(x, "irfft", n, axis, norm)
 
 
 def hfft(x: DNDarray, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None) -> DNDarray:
     """FFT of a Hermitian-symmetric signal (fft.py:478)."""
-    _check(x)
-    axis = sanitize_axis(x.shape, axis)
-    if _use_planar():
-        return _planar_entry(x, "hfft", ((axis, n),), norm)
-    result = jnp.fft.hfft(_complex_dense(x), n=n, axis=axis, norm=norm)
-    return _wrap(x, result)
+    return _fft1(x, "hfft", n, axis, norm)
 
 
 def ihfft(x: DNDarray, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None) -> DNDarray:
     """Inverse Hermitian FFT (fft.py:651)."""
-    _check(x)
-    axis = sanitize_axis(x.shape, axis)
-    if _use_planar():
-        return _planar_entry(x, "ihfft", ((axis, n),), norm)
-    result = jnp.fft.ihfft(_complex_dense(x), n=n, axis=axis, norm=norm)
-    return _wrap(x, result)
+    return _fft1(x, "ihfft", n, axis, norm)
 
 
 # ----------------------------------------------------------------------
@@ -643,143 +686,94 @@ def _axes_ns_of(x, s, axes) -> tuple:
     return tuple(zip(axes2, s2))
 
 
+def _fftnd(x: DNDarray, kind: str, s, axes, norm, root: Optional[str] = None) -> DNDarray:
+    """The 2-D and N-D entries (``axes`` sanitized by the caller)."""
+    _check(x)
+    axes_ns = _axes_ns_of(x, s, axes)
+    if _use_planar():
+        return _planar_entry(x, kind, axes_ns, norm)
+    if kind in ("hfft", "ihfft"):
+        def dense_fn(a):
+            return _hermitian_fftn(a, s, axes, norm, kind)
+    else:
+        def dense_fn(a):
+            return getattr(jnp.fft, kind + "n")(a, s=s, axes=axes, norm=norm)
+    if root is None:
+        return _transform(x, kind, axes_ns, norm, dense_fn)
+    # Host spans at the layer boundaries, as ``svdtools._hsvd`` carries them: the
+    # root is the API layer, ``fft.dispatch`` the jit cache lookup and enqueue of
+    # the one program, ``fft.wrap`` the result's ``DNDarray``.
+    route = _route(x, axes_ns)
+    with _span(root, shape="x".join(map(str, x.shape)), split=x.split, dtype=x.dtype.__name__, kind=kind, route=route):
+        return _transform(x, kind, axes_ns, norm, dense_fn, route)
+
+
+def _axes_n(x, axes):
+    return None if axes is None else tuple(sanitize_axis(x.shape, a) for a in axes)
+
+
 def fft2(x: DNDarray, s=None, axes=(-2, -1), norm=None) -> DNDarray:
     """2-D FFT (fft.py:352)."""
-    _check(x)
-    if _use_planar():
-        return _planar_entry(x, "fft", _axes_ns_of(x, s, _axes2(x, axes)), norm)
-    result = jnp.fft.fft2(_complex_dense(x), s=s, axes=_axes2(x, axes), norm=norm)
-    return _wrap(x, result)
+    return _fftnd(x, "fft", s, _axes2(x, axes), norm)
 
 
 def ifft2(x: DNDarray, s=None, axes=(-2, -1), norm=None) -> DNDarray:
     """2-D inverse FFT (fft.py:606)."""
-    _check(x)
-    if _use_planar():
-        return _planar_entry(x, "ifft", _axes_ns_of(x, s, _axes2(x, axes)), norm)
-    result = jnp.fft.ifft2(_complex_dense(x), s=s, axes=_axes2(x, axes), norm=norm)
-    return _wrap(x, result)
-
-
-def _pencil_nd(x: DNDarray, kind: str, s, axes, norm):
-    """Pencil the split axis first, then transform the remaining (local)
-    axes — no axis of the n-D transform ever gathers.  Norms compose
-    because fftn's scaling factorizes per axis.  Returns None when the
-    pencil path doesn't apply."""
-    if s is not None:
-        return None
-    axes_eff = axes if axes is not None else tuple(range(x.ndim))
-    if x.split not in axes_eff:
-        return None
-    partner = _pencil_partner(x, x.split, None)
-    if partner is None:
-        return None
-    y = _pencil_transform(x, kind, x.split, partner, norm)
-    rest = tuple(a for a in axes_eff if a != x.split)
-    if not rest:
-        return y
-    nd_op = jnp.fft.fftn if kind == "fft" else jnp.fft.ifftn
-    return _wrap(y, nd_op(_complex_dense(y), axes=rest, norm=norm))
+    return _fftnd(x, "ifft", s, _axes2(x, axes), norm)
 
 
 def fftn(x: DNDarray, s=None, axes=None, norm=None) -> DNDarray:
-    """N-D FFT — the pencil-decomposition workhorse (fft.py:383)."""
-    _check(x)
-    if axes is not None:
-        axes = tuple(sanitize_axis(x.shape, a) for a in axes)
-    if _use_planar():
-        return _planar_entry(x, "fft", _axes_ns_of(x, s, axes), norm)
-    pencil = _pencil_nd(x, "fft", s, axes, norm)
-    if pencil is not None:
-        return pencil
-    return _wrap(x, jnp.fft.fftn(_complex_dense(x), s=s, axes=axes, norm=norm))
+    """N-D FFT (fft.py:383); a split array is never gathered.
+
+    One ``shard_map`` program transforms it: along its split axis by the
+    pencil (``all_to_all``, the transform, ``all_to_all`` back), along the
+    other axes on each device's own slab; the result is split as ``x``."""
+    return _fftnd(x, "fft", s, _axes_n(x, axes), norm, root="ht.fft.fftn")
 
 
 def ifftn(x: DNDarray, s=None, axes=None, norm=None) -> DNDarray:
-    """N-D inverse FFT (fft.py:628)."""
-    _check(x)
-    if axes is not None:
-        axes = tuple(sanitize_axis(x.shape, a) for a in axes)
-    if _use_planar():
-        return _planar_entry(x, "ifft", _axes_ns_of(x, s, axes), norm)
-    pencil = _pencil_nd(x, "ifft", s, axes, norm)
-    if pencil is not None:
-        return pencil
-    return _wrap(x, jnp.fft.ifftn(_complex_dense(x), s=s, axes=axes, norm=norm))
+    """N-D inverse FFT (fft.py:628); the path is ``fftn``'s."""
+    return _fftnd(x, "ifft", s, _axes_n(x, axes), norm, root="ht.fft.ifftn")
 
 
 def rfft2(x: DNDarray, s=None, axes=(-2, -1), norm=None) -> DNDarray:
     """2-D real FFT (fft.py:922)."""
-    _check(x)
-    if _use_planar():
-        return _planar_entry(x, "rfft", _axes_ns_of(x, s, _axes2(x, axes)), norm)
-    result = jnp.fft.rfft2(_complex_dense(x), s=s, axes=_axes2(x, axes), norm=norm)
-    return _wrap(x, result)
+    return _fftnd(x, "rfft", s, _axes2(x, axes), norm)
 
 
 def irfft2(x: DNDarray, s=None, axes=(-2, -1), norm=None) -> DNDarray:
     """2-D inverse real FFT (fft.py:744)."""
-    _check(x)
-    if _use_planar():
-        return _planar_entry(x, "irfft", _axes_ns_of(x, s, _axes2(x, axes)), norm)
-    result = jnp.fft.irfft2(_complex_dense(x), s=s, axes=_axes2(x, axes), norm=norm)
-    return _wrap(x, result)
+    return _fftnd(x, "irfft", s, _axes2(x, axes), norm)
 
 
 def rfftn(x: DNDarray, s=None, axes=None, norm=None) -> DNDarray:
     """N-D real FFT (fft.py:953)."""
-    _check(x)
-    if axes is not None:
-        axes = tuple(sanitize_axis(x.shape, a) for a in axes)
-    if _use_planar():
-        return _planar_entry(x, "rfft", _axes_ns_of(x, s, axes), norm)
-    return _wrap(x, jnp.fft.rfftn(_complex_dense(x), s=s, axes=axes, norm=norm))
+    return _fftnd(x, "rfft", s, _axes_n(x, axes), norm)
 
 
 def irfftn(x: DNDarray, s=None, axes=None, norm=None) -> DNDarray:
     """N-D inverse real FFT (fft.py:775)."""
-    _check(x)
-    if axes is not None:
-        axes = tuple(sanitize_axis(x.shape, a) for a in axes)
-    if _use_planar():
-        return _planar_entry(x, "irfft", _axes_ns_of(x, s, axes), norm)
-    return _wrap(x, jnp.fft.irfftn(_complex_dense(x), s=s, axes=axes, norm=norm))
+    return _fftnd(x, "irfft", s, _axes_n(x, axes), norm)
 
 
 def hfft2(x: DNDarray, s=None, axes=(-2, -1), norm=None) -> DNDarray:
     """2-D Hermitian FFT (fft.py:509)."""
-    _check(x)
-    if _use_planar():
-        return _planar_entry(x, "hfft", _axes_ns_of(x, s, _axes2(x, axes)), norm)
-    return _wrap(x, _hermitian_fftn(_complex_dense(x), s, _axes2(x, axes), norm, "hfft"))
+    return _fftnd(x, "hfft", s, _axes2(x, axes), norm)
 
 
 def hfftn(x: DNDarray, s=None, axes=None, norm=None) -> DNDarray:
     """N-D Hermitian FFT (fft.py:540)."""
-    _check(x)
-    if axes is not None:
-        axes = tuple(sanitize_axis(x.shape, a) for a in axes)
-    if _use_planar():
-        return _planar_entry(x, "hfft", _axes_ns_of(x, s, axes), norm)
-    return _wrap(x, _hermitian_fftn(_complex_dense(x), s, axes, norm, "hfft"))
+    return _fftnd(x, "hfft", s, _axes_n(x, axes), norm)
 
 
 def ihfft2(x: DNDarray, s=None, axes=(-2, -1), norm=None) -> DNDarray:
     """2-D inverse Hermitian FFT (fft.py:672)."""
-    _check(x)
-    if _use_planar():
-        return _planar_entry(x, "ihfft", _axes_ns_of(x, s, _axes2(x, axes)), norm)
-    return _wrap(x, _hermitian_fftn(_complex_dense(x), s, _axes2(x, axes), norm, "ihfft"))
+    return _fftnd(x, "ihfft", s, _axes2(x, axes), norm)
 
 
 def ihfftn(x: DNDarray, s=None, axes=None, norm=None) -> DNDarray:
     """N-D inverse Hermitian FFT (fft.py:686)."""
-    _check(x)
-    if axes is not None:
-        axes = tuple(sanitize_axis(x.shape, a) for a in axes)
-    if _use_planar():
-        return _planar_entry(x, "ihfft", _axes_ns_of(x, s, axes), norm)
-    return _wrap(x, _hermitian_fftn(_complex_dense(x), s, axes, norm, "ihfft"))
+    return _fftnd(x, "ihfft", s, _axes_n(x, axes), norm)
 
 
 # ----------------------------------------------------------------------

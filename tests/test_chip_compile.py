@@ -315,6 +315,31 @@ def test_lloyd_step_four_chips(comm4, for_the_chip, program):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize("kind,dtype,all_to_alls,temporaries", [
+    ("fft", jnp.float32, 3, 4_295_314_944), ("ifft", jnp.complex64, 4, 5_369_153_536)])
+def test_fftn_pencil_at_the_benchmark_cell(comm4, for_the_chip, kind, dtype, all_to_alls, temporaries):
+    """The one program of ``ht.fft.fftn`` / ``ifftn`` on the 1024^3 cube split
+    over four chips (PR 31's cell).  No all-gather: a chip never holds more
+    than its slabs.  A complex64 array is two float32 planes on a TPU, so its
+    all-to-all is two: three for a real cube's forward transform, four for
+    the inverse.  Pinned a chip: the slab in, the spectrum's slab out, and
+    the temporaries (two slabs of the spectrum, three for the inverse); with
+    the caller's previous result (2,147,483,648 B) still under 16 GB."""
+    import importlib
+
+    fft = importlib.import_module("heat_tpu.fft.fft")
+    n = 1024
+    stages = fft._stages(kind, ((0, None), (1, None), (2, None)), 0, None)
+    compiled = fft._slab_program(comm4, 0, 3, n, stages).lower(_sds((n, n, n), dtype, comm4.sharding(0))).compile()
+    txt = compiled.as_text()
+    assert "all-gather" not in txt
+    assert len(re.findall(r" all-to-all\(", txt)) == all_to_alls
+    m = compiled.memory_analysis()
+    slab = n ** 3 // 4 * jnp.dtype(dtype).itemsize
+    assert (m.argument_size_in_bytes, m.output_size_in_bytes, m.temp_size_in_bytes) == (slab, 2_147_483_648, temporaries)
+    assert _device_bytes(compiled) + 2_147_483_648 < HBM_BYTES
+
+
 def test_bucketed_data_parallel_step_four_chips(comm4, for_the_chip):
     import optax
 

@@ -347,13 +347,13 @@ class TestPencilFFT:
         fft_mod = importlib.import_module("heat_tpu.fft.fft")
         p = ht.get_comm().size
         a = ht.array(np.zeros((3 * p, 2 * p, 8)), split=0)
-        fn = fft_mod._pencil_fn(a.comm, "fft", 0, 1, 3 * p, 3, None)
+        fn = fft_mod._slab_program(a.comm, 0, 3, 3 * p, fft_mod._stages("fft", ((0, None),), 0, None))
         txt = fn.lower(a.larray_padded.astype(np.complex128)).compile().as_text()
         assert "all-to-all" in txt
         assert "all-gather" not in txt
 
-    def test_pencil_ineligible_falls_back(self, ht):
-        # no partner axis divisible by the mesh -> dense path, still correct
+    def test_pencil_pads_a_partner_the_mesh_does_not_divide(self, ht):
+        # no partner axis divisible by the mesh -> the partner is padded on the device, still correct
         rng = np.random.default_rng(3)
         x = rng.standard_normal((40, 7))
         a = ht.array(x, split=0)

@@ -1,5 +1,5 @@
-"""Spans at the layer boundaries of the two solve paths the chip benchmark
-times (``ht.linalg.hsvd*``, ``KMeans.fit``), the operator's function that
+"""Spans at the layer boundaries of the solve paths the chip benchmark
+times (``ht.linalg.hsvd*``, ``KMeans.fit``, ``ht.fft.fftn``), the operator's function that
 reads them beside the device plane (``idle_by_span``), and the benchmark's
 three per-layer readers.  All on the CPU: counts, names and containment,
 never a time.
@@ -61,8 +61,20 @@ def _kmeans(a):
     return [km.cluster_centers_.numpy(), km.labels_.numpy(), np.asarray(km.inertia_)]
 
 
+def _fftn(a):
+    return [ht.fft.fftn(a).numpy()]
+
+
+def _ifftn(a):
+    return [ht.fft.ifftn(a).numpy()]
+
+
+FFT_ATTRS = {"shape": f"{ROWS}x{COLS}", "split": 0, "dtype": "float32", "route": "dense"}  # one device: nothing to trade
+
 #: solve -> (root, its attributes, children in the order they open)
 SOLVES = {
+    "fftn": (_fftn, "ht.fft.fftn", {**FFT_ATTRS, "kind": "fft"}, [("fft.dispatch", {}), ("fft.wrap", {})]),
+    "ifftn": (_ifftn, "ht.fft.ifftn", {**FFT_ATTRS, "kind": "ifft"}, [("fft.dispatch", {}), ("fft.wrap", {})]),
     "hsvd_rank": (_hsvd_rank, "ht.linalg.hsvd", {"rows": ROWS, "cols": COLS, "split": 0, "rank": 4},
                   [("hsvd.dispatch", {"path": "rank"}), ("hsvd.wrap", {})]),
     "hsvd_rtol": (_hsvd_rtol, "ht.linalg.hsvd", {"rows": ROWS, "cols": COLS, "split": 0, "rtol": 1e-1},
@@ -95,6 +107,29 @@ def test_solve_leaves_the_tables_spans(one_device, solve):
     assert {r.trace_id for r in spans} == {None}  # no identifier beside thread, depth and time
 
 
+@pytest.mark.parametrize("split,route", [(0, "pencil"), (1, "local"), (None, "dense")])
+def test_fftn_names_its_route_on_the_mesh(split, route):
+    """Over the whole mesh: the split axis among the transformed ones is the
+    pencil, beside them the slab's own transform; the trace of the program
+    (the first call) leaves the two ``comm.all_to_all`` inside ``fft.dispatch``."""
+    prev = telemetry.set_tracing(True)
+    try:
+        a = ht.array(np.random.default_rng(3).standard_normal((24, 16, 6)).astype(np.float32), split=split)
+        telemetry.clear_spans()
+        ht.fft.fftn(a, axes=(0, 2))
+        first = [r.name for r in sorted(telemetry.get_spans(), key=lambda r: r.start_ns)]
+        telemetry.clear_spans()
+        ht.fft.fftn(a, axes=(0, 2))
+        spans = sorted(telemetry.get_spans(), key=lambda r: r.start_ns)
+    finally:
+        telemetry.set_tracing(prev)
+        telemetry.clear_spans()
+    assert [r.name for r in spans] == ["ht.fft.fftn", "fft.dispatch", "fft.wrap"]
+    assert spans[0].attrs == {"shape": "24x16x6", "split": split, "dtype": "float32", "kind": "fft", "route": route}
+    assert first.count("comm.all_to_all") == (2 if route == "pencil" else 0)
+    assert [n for n in first if not n.startswith(("comm.", "dispatch."))] == ["ht.fft.fftn", "fft.dispatch", "fft.wrap"]
+
+
 def test_resumable_fit_initializes_inside_its_loop(one_device, tmp_path):
     a = _data()
     ht.cluster.KMeans(n_clusters=3, init="random", max_iter=4, random_state=2,
@@ -119,7 +154,8 @@ def test_tracing_off_leaves_nothing_and_changes_no_result(one_device, solve):
 
 
 @pytest.mark.parametrize("solve,root,child", [("hsvd_rank", "ht.linalg.hsvd", "hsvd.dispatch"),
-                                              ("kmeans", "ht.cluster.KMeans.fit", "kmeans.loop")])
+                                              ("kmeans", "ht.cluster.KMeans.fit", "kmeans.loop"),
+                                              ("fftn", "ht.fft.fftn", "fft.dispatch")])
 def test_spans_land_in_the_profilers_host_plane(one_device, tmp_path, solve, root, child):
     """Under the options ``chipbench/run.py`` traces with, the annotation of
     each span is kept, on the calling thread's line, the child in the root."""
@@ -194,14 +230,15 @@ def test_attribute_idle(case):
 
 
 # ------------------------------------------------------------------- the readers
-def _ring(solves, dispatch_ns=400_000, root_ns=1_000_000, warmup=2):
+def _ring(solves, dispatch_ns=400_000, root_ns=1_000_000, warmup=2, names=("ht.linalg.hsvd", "hsvd.dispatch", "hsvd.wrap")):
     """A ring as a run leaves it: ``warmup`` solves, then the window's."""
+    root, dispatch, wrap = names
     telemetry.clear_spans()
     for i in range(warmup + solves):
         t = i * 10_000_000
-        telemetry.record_span("hsvd.dispatch", t + 100_000, 5 * dispatch_ns if i < warmup else dispatch_ns)
-        telemetry.record_span("hsvd.wrap", t + 700_000, 200_000)
-        telemetry.record_span("ht.linalg.hsvd", t, 5 * root_ns if i < warmup else root_ns, rows=1)
+        telemetry.record_span(dispatch, t + 100_000, 5 * dispatch_ns if i < warmup else dispatch_ns)
+        telemetry.record_span(wrap, t + 700_000, 200_000)
+        telemetry.record_span(root, t, 5 * root_ns if i < warmup else root_ns, rows=1)
 
 
 TOP_OPS = [["%fusion.2 fusion f32[12582912,15]", 0.9], ["%gram_syrk.1 custom-call:tpu_custom_call f32[128,128]", 0.5]]
@@ -225,6 +262,42 @@ def test_layer_metric_readers(one_device, reader, case):
     fill, solves, top_ops, want = READER_CASES[(reader, case)]
     fill()
     run = {"trace": {"top_ops": top_ops, "busy_s": 1.0}, "solves": solves, "window_s": 2.0, "notes": {}}
+    got = load_py("layer_metrics", reader).read(run)
+    if want is None:
+        assert got is None and reader in run["notes"]
+    else:
+        assert got == pytest.approx(want) and run["notes"] == {}
+
+
+def _fft_ring(solves):
+    _ring(solves, root_ns=900_000, names=("ht.fft.fftn", "fft.dispatch", "fft.wrap"))
+
+
+#: the chip's names (PR 31's traces): one all-to-all for the float32 slab, two for the complex64 one; the
+#: reshape that takes the collective's name is a layout copy and is not counted; seconds summed over four chips
+FFT_OPS = [["%a.3 custom-call:X64Combine c64[256,1024,1024]", 0.9], ["%all_to_all.36 all-to-all f32[256,1024,1024]", 0.4],
+           ["%all_to_all.40 all-to-all f32[1024,256,1024]", 0.4], ["%all_to_all.44 all-to-all f32[1024,256,1024]", 0.4],
+           ["%all_to_all.55 reshape f32[1024,256,1024]", 0.1]]
+FFT_WORK = {"bytes": 12 * 2 ** 28, "operations": 1, "chips": 4, "ici_bytes": 9 * 2 ** 28}
+
+#: (reader, case) -> (what fills the ring, the trace's operations, the work model, the reading wanted)
+FFT_READER_CASES = {
+    ("fft_host_ms", "read"): (lambda: _fft_ring(5), FFT_OPS, FFT_WORK, 0.9),
+    ("fft_host_ms", "no_such_span"): (lambda: _ring(5), FFT_OPS, FFT_WORK, None),  # a program without the fft spans
+    ("fft_host_ms", "tracing_off"): (telemetry.clear_spans, FFT_OPS, FFT_WORK, None),
+    ("fft_alltoall_ms", "read"): (telemetry.clear_spans, FFT_OPS, FFT_WORK, 1000 * 1.2 / 4 / 5),
+    ("fft_alltoall_ms", "no_collective"): (telemetry.clear_spans, FFT_OPS[:1], FFT_WORK, None),
+    ("fft_alltoall_ms", "no_device_plane"): (telemetry.clear_spans, [], FFT_WORK, None),
+    ("fft_alltoall_gbps", "read"): (telemetry.clear_spans, FFT_OPS, FFT_WORK, 9 * 2 ** 28 * 5 / 0.3 / 1e9),
+    ("fft_alltoall_gbps", "no_collective"): (telemetry.clear_spans, FFT_OPS[:1], FFT_WORK, None),
+}
+
+
+@pytest.mark.parametrize("reader,case", sorted(FFT_READER_CASES))
+def test_fft_layer_metric_readers(one_device, reader, case):
+    fill, top_ops, work, want = FFT_READER_CASES[(reader, case)]
+    fill()
+    run = {"trace": {"top_ops": top_ops, "busy_s": 1.0}, "solves": 5, "window_s": 2.0, "work": work, "notes": {}}
     got = load_py("layer_metrics", reader).read(run)
     if want is None:
         assert got is None and reader in run["notes"]
