@@ -181,7 +181,7 @@ KNOBS = {
     "HEAT_TPU_SPGEMM_DENSE_DENSITY": ("float", "0.5", "estimated-output-density threshold at which sparse@sparse matmul falls back from the output-sparse triplet ring to the GEMM-style dense route (estimate: 1 - exp(-nnz_A*nnz_B/(m*k*n)); 1.0 = always ring, 0.0 = always dense)"),
     # -- fft (docs/fft_roofline.md) -------------------------------------
     "HEAT_TPU_PLANAR": ("bool", "0", "planar (re, im) FFT engine: 1 = transforms run on two real planes (the leading-contraction engine and its Pallas stage kernels); 0 = native complex through jnp.fft"),
-    "HEAT_TPU_FFT_PRECISION": ("choice", "highest", "FFT matmul precision: default | high | highest"),
+    "HEAT_TPU_FFT_PRECISION": ("choice", "high", "matmul precision of the planar FFT engine (HEAT_TPU_PLANAR=1): default | high | highest; unset, its interleaved and leading-axis engines run at high and only the per-axis fallback (_planar.fft1) at highest"),
     "HEAT_TPU_FFT_CUTOFF": ("int", "64", "extent cutoff below which planar FFT uses the direct DFT matmul"),
     "HEAT_TPU_FFT_DIRECT_CAP": ("int", "1024", "largest extent the direct DFT path may handle"),
     "HEAT_TPU_FFT_PALLAS": ("bool", "0", "opt-in Pallas planar-FFT stage kernel"),

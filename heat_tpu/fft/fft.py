@@ -6,7 +6,9 @@ Alltoallw with subarray datatypes), runs the local torch FFT, and resplits
 back (``__fft_op`` fft.py:40-138, ``__fftn_op`` :139-298).  Here a split
 array is transformed by one ``shard_map`` program over its split axis: the
 same pencil (``all_to_all``, the transform, ``all_to_all`` back) along the
-split axis and XLA's ``fft`` on each device's own slab along the others.  A
+split axis, a large slab block by block so that one block's exchange is in
+flight while another is transformed, and XLA's ``fft`` on each device's own
+slab along the others.  A
 ``jnp.fft.*`` call on the sharded global array is NOT that: GSPMD does not
 keep a sharded batch axis through the ``fft`` operation and, on the CPU
 mesh, gathers the whole array onto every device (PERF.md, PR 31).
@@ -14,6 +16,7 @@ mesh, gathers the whole array onto every device (PERF.md, PR 31).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Iterable, Optional, Tuple, Union
 
 import jax
@@ -348,14 +351,14 @@ def _pencil_planar_kind_fn(
     )
 
 
-def _pencil_pick_partner(gshape, split: int, comm) -> Optional[int]:
+def _pencil_pick_partner(gshape, split: int, comm, last: bool = False) -> Optional[int]:
     """Partner axis for the pencil all_to_all: a divisible axis if one
-    exists, else the axis with the least relative padding (the padded
-    partner replaces the r3 GSPMD-reshard fallback).  None only for 1-D."""
+    exists (the first, or with ``last`` the last), else the axis with the
+    least relative padding (the padded partner replaces the r3
+    GSPMD-reshard fallback).  None only for 1-D."""
     best, best_frac = None, None
-    for d in range(len(gshape)):
-        if d == split:
-            continue
+    others = [d for d in range(len(gshape)) if d != split]
+    for d in reversed(others) if last else others:
         pad = comm.pad_amount(gshape[d])
         if pad == 0:
             return d
@@ -478,7 +481,8 @@ def _planar_split_chain(y: DNDarray, kind: str, axes_ns, norm) -> DNDarray:
 # not split is the slab's own (a ``local`` stage), and a transform along
 # the split axis is the pencil: ``all_to_all`` so that the axis becomes
 # whole on the device, the transform, ``all_to_all`` back -- the
-# reference's pencil resplit (fft.py:100-137).  No stage gathers.
+# reference's pencil resplit (fft.py:100-137), pipelined over blocks of a
+# bystander axis where the slab is large (``_pencil_layout``).  No stage gathers.
 # ----------------------------------------------------------------------
 _COMPLEX_OF = {"fft": "fft", "rfft": "fft", "hfft": "fft", "ifft": "ifft", "ihfft": "ifft", "irfft": "ifft"}
 
@@ -488,8 +492,9 @@ def _stages(kind: str, axes_ns, split: int, norm) -> tuple:
     transform of ``rfft`` / ``ihfft`` first and of ``irfft`` / ``hfft`` last,
     on the last axis; between them the complex passes, the split axis's
     (``("pencil", op, n, norm)``) ahead of the local axes', which go as one
-    n-D call (``("local", op, size, axes, norm)``: the arguments of
-    ``jnp.fft.<op>`` after the array)."""
+    n-D call (``("locals", op, size, axes, norm)``; a single local axis is
+    ``("local", op, n, axis, norm)``: both the arguments of ``jnp.fft.<op>``
+    after the array).  ``_planned`` writes each pencil's layout into it."""
     cplx = _COMPLEX_OF[kind]
     head = axes_ns[-1:] if kind in ("rfft", "ihfft") else ()
     tail = axes_ns[-1:] if kind in ("irfft", "hfft") else ()
@@ -502,18 +507,116 @@ def _stages(kind: str, axes_ns, split: int, norm) -> tuple:
     stages += [one(cplx, a, n) for a, n in mid if a == split]
     local = [(a, n) for a, n in mid if a != split]
     if local:
-        size = None if all(n is None for _, n in local) else tuple(n for _, n in local)
-        stages.append(("local", cplx + "n", size, tuple(a for a, _ in local), norm))
+        stages.append(_locals(cplx + "n", local, norm))
     stages += [one(kind, a, n) for a, n in tail]
     return tuple(stages)
 
 
-def _pencil_stage(comm, blk, axis: int, n_true: int, op: str, n, norm):
-    """One transform along the split axis of a slab: the partner axis (one
-    the mesh divides if there is one, else the one that pads least; padded
-    here, on the device) is traded for the split axis, whose canonical
-    padding rows are left out of the transform, and traded back."""
-    partner = _pencil_pick_partner(blk.shape, axis, comm)
+def _locals(op: str, axes_ns, norm) -> tuple:
+    """The n-D stage of ``op`` over ``(axis, n)`` pairs; no sizes where no axis has one."""
+    size = None if all(n is None for _, n in axes_ns) else tuple(n for _, n in axes_ns)
+    return ("locals", op, size, tuple(a for a, _ in axes_ns), norm)
+
+
+#: The pencil is pipelined: a slab is cut along a bystander axis (neither the
+#: split axis nor the partner) into blocks that are exchanged in, transformed
+#: and exchanged back on their own, so that the compiler keeps one block's
+#: exchange in flight while another is transformed.  By step 0 of PR 32 (four
+#: v5e chips, PERF.md section 6): a block's plane (its real or its imaginary
+#: part) of 16 MiB or more, at most 16 blocks (1024^3 float32 over four
+#: chips: 16 blocks of 64 MiB, 166.8 ms for the one block's 187.9; 512^3:
+#: 8 of 16 MiB, 21.9 for 27.1), and the blocks cut along a MAJOR axis, the
+#: partner being the last axis the mesh divides (166.8 against 173.2 ms cut
+#: along the minor one).
+_PENCIL_BLOCK_BYTES = 16 << 20
+_PENCIL_BLOCKS_MAX = 16
+
+
+def _pencil_layout(slab, split: int, comm) -> Tuple[int, Optional[int], int]:
+    """(partner, the axis the blocks are cut along, blocks) of the pencil of
+    ``slab`` (a device's, by shape and dtype), from what the code can see.
+    Cut up: the partner is the last axis the mesh divides, the blocks lie
+    along the largest axis left (the first of equals), and there are as many
+    as the largest power of two, ``_PENCIL_BLOCKS_MAX`` at most, that divides
+    that axis and leaves every block's plane ``_PENCIL_BLOCK_BYTES`` or more.
+    One block, PR 31's program operation for operation (the first such axis
+    the partner, nothing cut): where the slab has no bystander axis (2-D),
+    where it is small, and where the mesh's compiler has no option that puts
+    an exchange beside compute, since cutting alone only costs (step 0:
+    198.4 ms cut in four for the one block's 187.7)."""
+    shape = slab.shape
+    partner = _pencil_pick_partner(shape, split, comm, last=True)
+    others = [d for d in range(len(shape)) if d not in (split, partner)]
+    if others and comm.overlap_compiler_options():
+        cut = max(others, key=lambda d: shape[d])
+        plane = int(np.prod(shape)) * slab.dtype.itemsize // (2 if jnp.issubdtype(slab.dtype, jnp.complexfloating) else 1)
+        blocks = _PENCIL_BLOCKS_MAX
+        while blocks > 1 and (shape[cut] % blocks or plane // blocks < _PENCIL_BLOCK_BYTES):
+            blocks //= 2
+        if blocks > 1:
+            return partner, cut, blocks
+    return _pencil_pick_partner(shape, split, comm), None, 1
+
+
+def _local(blk, stage: tuple):
+    """A ``local`` / ``locals`` stage on a slab or on a block of one."""
+    with jax.named_scope("fft.local"):
+        return getattr(jnp.fft, stage[1])(blk, *stage[2:])
+
+
+@_functools.lru_cache(maxsize=256)
+def _planned(stages: tuple, comm, split: int, padded_shape: tuple, n_true: int, dtype) -> tuple:
+    """``stages`` with every pencil's layout decided, once, here:
+    ``("pencil", op, n, norm, partner, cut, blocks, then)`` by
+    ``_pencil_layout`` of the slab as it reaches that stage.  Behind a pencil
+    that is cut, the ``locals`` stage is taken apart: its axes but ``cut`` go
+    to each block as it lands (``then``, a ``locals`` stage or None), and
+    ``cut`` alone stays a stage of the whole slab after the pencil."""
+    slab = jax.ShapeDtypeStruct(tuple(e // comm.size if d == split else e for d, e in enumerate(padded_shape)),
+                                dtype if np.issubdtype(dtype, np.inexact) else np.float32)
+
+    def resized(slab, extent):  # the slab with ``extent`` rows of the split axis
+        return jax.ShapeDtypeStruct(tuple(extent if d == split else e for d, e in enumerate(slab.shape)), slab.dtype)
+
+    planned, todo = [], list(stages)
+    while todo:
+        stage = todo.pop(0)
+        if stage[0] == "pencil":
+            partner, cut, blocks = _pencil_layout(slab, split, comm)
+            then = None
+            if blocks > 1 and todo and todo[0][0] == "locals":
+                _, op, size, axes, norm = todo.pop(0)
+                pairs = list(zip(axes, size or (None,) * len(axes)))
+                per_block, whole = ([(a, n) for a, n in pairs if (a == cut) == is_cut] for is_cut in (False, True))
+                then = _locals(op, per_block, norm) if per_block else None
+                if whole:
+                    todo.insert(0, _locals(op, whole, norm))
+            stage += (partner, cut, blocks, then)
+            whole_axis = jax.eval_shape(lambda b, s=stage: getattr(jnp.fft, s[1])(b, s[2], split, s[3]), resized(slab, n_true))
+            n_true = whole_axis.shape[split]
+            slab = resized(whole_axis, comm.padded_extent(n_true) // comm.size)
+            if then:
+                slab = jax.eval_shape(_functools.partial(_local, stage=then), slab)
+        else:
+            slab = jax.eval_shape(_functools.partial(_local, stage=stage), slab)
+        planned.append(stage)
+    return tuple(planned)
+
+
+def _pencil_stage(comm, blk, axis: int, n_true: int, op: str, n, norm, partner: int, cut: Optional[int], blocks: int, then):
+    """One transform along the split axis of a slab, laid out as ``_planned``
+    wrote it: the partner axis (one the mesh divides if there is one, else
+    the one that pads least; padded here, on the device) is traded for the
+    split axis, whose canonical padding rows are left out of the transform,
+    and traded back; ``then``, if there is one, is the slab's own stage that
+    is applied to each block as it comes back.
+
+    In ``blocks`` blocks of the axis ``cut``, as a pipeline: every block's
+    way in is issued up front, and block ``i``'s transform waits for block
+    ``i - 1``'s (an ``optimization_barrier`` over the two).  Without that
+    order the compiler merges the blocks' transforms into one fusion that
+    waits for every block's exchange, and nothing is left to run beside one
+    (PERF.md section 6, PR 32: at four blocks 184.9 ms a solve merged, 178.7 chained)."""
     n_partner = blk.shape[partner]
 
     def fit(a, d, extent):  # zero rows up to ``extent`` along ``d``, or the first ``extent`` rows
@@ -521,21 +624,41 @@ def _pencil_stage(comm, blk, axis: int, n_true: int, op: str, n, norm):
             return jnp.pad(a, [(0, extent - a.shape[d]) if i == d else (0, 0) for i in range(a.ndim)])
         return a[tuple(slice(0, extent) if i == d else slice(None) for i in range(a.ndim))]
 
-    with jax.named_scope("fft.alltoall.in"):
-        t = comm.all_to_all(fit(blk, partner, comm.padded_extent(n_partner)), split_axis=partner, concat_axis=axis)
-        t = fit(t, axis, n_true)
-    with jax.named_scope("fft.split_axis"):
-        res = getattr(jnp.fft, op)(t, n, axis, norm)
-    with jax.named_scope("fft.alltoall.out"):
-        n_out = res.shape[axis]
-        res = comm.all_to_all(fit(res, axis, comm.padded_extent(n_out)), split_axis=axis, concat_axis=partner)
-        return fit(res, partner, n_partner), n_out
+    def come_in(piece):
+        with jax.named_scope("fft.alltoall.in"):
+            t = comm.all_to_all(fit(piece, partner, comm.padded_extent(n_partner)), split_axis=partner, concat_axis=axis)
+            return fit(t, axis, n_true)
+
+    def transform(t):
+        with jax.named_scope("fft.split_axis"):
+            return getattr(jnp.fft, op)(t, n, axis, norm)
+
+    def go_back(res):
+        with jax.named_scope("fft.alltoall.out"):
+            res = comm.all_to_all(fit(res, axis, comm.padded_extent(res.shape[axis])), split_axis=axis, concat_axis=partner)
+            res = fit(res, partner, n_partner)
+        return _local(res, then) if then else res
+
+    if blocks == 1:
+        res = transform(come_in(blk))
+        return go_back(res), res.shape[axis]
+    arrived = [come_in(piece) for piece in jnp.split(blk, blocks, axis=cut)]
+    res, back = transform(arrived[0]), []
+    for t in arrived[1:]:
+        res, t = jax.lax.optimization_barrier((res, t))
+        back.append(go_back(res))
+        res = transform(t)
+    back.append(go_back(res))
+    return jnp.concatenate(back, axis=cut), res.shape[axis]
 
 
 @_functools.lru_cache(maxsize=256)
 def _slab_program(comm, split: int, ndim: int, n_true: int, stages: tuple):
-    """The jitted ``shard_map`` program of ``stages`` over the split axis:
-    padded array in, padded result out, both with the canonical sharding."""
+    """The jitted ``shard_map`` program of ``stages`` (as ``_planned`` leaves
+    them) over the split axis: padded array in, padded result out, both with
+    the canonical sharding.  A program with a pencil that is cut is compiled
+    with the options that let a block's exchange run beside the other
+    blocks' transforms (``Communication.overlap_compiler_options``)."""
     spec = comm.sharding(split, ndim).spec
 
     def body(blk):
@@ -546,11 +669,16 @@ def _slab_program(comm, split: int, ndim: int, n_true: int, stages: tuple):
             if stage[0] == "pencil":
                 blk, n_split = _pencil_stage(comm, blk, split, n_split, *stage[1:])
             else:
-                with jax.named_scope("fft.local"):
-                    blk = getattr(jnp.fft, stage[1])(blk, *stage[2:])
+                blk = _local(blk, stage)
         return blk
 
-    return jax.jit(_shard_map(body, mesh=comm.mesh, in_specs=spec, out_specs=spec))
+    return jax.jit(_shard_map(body, mesh=comm.mesh, in_specs=spec, out_specs=spec),
+                   compiler_options=comm.overlap_compiler_options() if _blocks(stages) > 1 else None)
+
+
+def _blocks(stages: tuple) -> int:
+    """What the pencil of planned ``stages`` is cut into; 1 without a pencil."""
+    return max((stage[6] for stage in stages if stage[0] == "pencil"), default=1)
 
 
 def _transform_padded(blk, comm, split: int, n_true: int, stages: tuple):
@@ -573,27 +701,42 @@ def _route(x: DNDarray, axes_ns) -> str:
     return "pencil" if x.ndim >= 2 else "dense"
 
 
-def _transform(x: DNDarray, kind: str, axes_ns, norm, dense_fn, route: Optional[str] = None) -> DNDarray:
+def _transform(x: DNDarray, kind: str, axes_ns, norm, dense_fn, root: Optional[str] = None) -> DNDarray:
     """Every non-planar entry: ``kind`` over ``axes_ns`` (``(axis, n)``
     pairs) by the route the array's split gives, ``dense_fn`` being the
-    ``jnp.fft`` call of the dense route.  The result is split as ``x``."""
-    axes_ns = tuple((int(a), None if n is None else int(n)) for a, n in axes_ns)
-    if (route or _route(x, axes_ns)) == "dense":
-        with _span("fft.dispatch"):
-            result = dense_fn(_complex_dense(x))
+    ``jnp.fft`` call of the dense route.  The result is split as ``x``.
+
+    Host spans at the layer boundaries, as ``svdtools._hsvd`` carries them:
+    ``root`` (the n-D entries name one) is the API layer and holds all but
+    the route; ``fft.dispatch`` the array's materialisation, the jit cache
+    lookup and the enqueue of the one program; ``fft.wrap`` the result's
+    ``DNDarray``.  ``blocks`` on the first two is what the program's pencil
+    is cut into, written once the plan is known."""
+    route = _route(x, axes_ns)
+    with _span(root, shape="x".join(map(str, x.shape)), split=x.split, dtype=x.dtype.__name__, kind=kind, route=route,
+               blocks=1) if root else contextlib.nullcontext() as top:
+        axes_ns = tuple((int(a), None if n is None else int(n)) for a, n in axes_ns)
+        if route == "dense":
+            with _span("fft.dispatch", blocks=1):
+                result = dense_fn(_complex_dense(x))
+            with _span("fft.wrap"):
+                return _wrap(x, result)
+        split, n_true = x.split, x.shape[x.split]
+        stages = _stages(kind, axes_ns, split, norm)
+        with _span("fft.dispatch", blocks=1) as enqueue:
+            padded = x.larray_padded
+            stages = _planned(stages, x.comm, split, padded.shape, n_true, np.dtype(padded.dtype))
+            enqueue.attrs["blocks"] = _blocks(stages)
+            if top is not None:
+                top.attrs["blocks"] = enqueue.attrs["blocks"]
+            out = _transform_padded(padded, x.comm, split, n_true, stages)
         with _span("fft.wrap"):
-            return _wrap(x, result)
-    split, stages = x.split, _stages(kind, axes_ns, x.split, norm)
-    with _span("fft.dispatch"):
-        out = _transform_padded(x.larray_padded, x.comm, split, x.shape[split], stages)
-    with _span("fft.wrap"):
-        # the program's result is padded and placed as a DNDarray stores it: nothing is copied
-        n_split = x.shape[split]
-        for stage in stages:
-            if stage[0] == "pencil":
-                n_split = _pencil_out_len(stage[1], n_split, stage[2])
-        gshape = tuple(n_split if d == split else e for d, e in enumerate(out.shape))
-        return DNDarray(out, gshape, types.canonical_heat_type(out.dtype), split, x.device, x.comm)
+            # the program's result is padded and placed as a DNDarray stores it: nothing is copied
+            for stage in stages:
+                if stage[0] == "pencil":
+                    n_true = _pencil_out_len(stage[1], n_true, stage[2])
+            gshape = tuple(n_true if d == split else e for d, e in enumerate(out.shape))
+            return DNDarray(out, gshape, types.canonical_heat_type(out.dtype), split, x.device, x.comm)
 
 
 # ----------------------------------------------------------------------
@@ -698,14 +841,7 @@ def _fftnd(x: DNDarray, kind: str, s, axes, norm, root: Optional[str] = None) ->
     else:
         def dense_fn(a):
             return getattr(jnp.fft, kind + "n")(a, s=s, axes=axes, norm=norm)
-    if root is None:
-        return _transform(x, kind, axes_ns, norm, dense_fn)
-    # Host spans at the layer boundaries, as ``svdtools._hsvd`` carries them: the
-    # root is the API layer, ``fft.dispatch`` the jit cache lookup and enqueue of
-    # the one program, ``fft.wrap`` the result's ``DNDarray``.
-    route = _route(x, axes_ns)
-    with _span(root, shape="x".join(map(str, x.shape)), split=x.split, dtype=x.dtype.__name__, kind=kind, route=route):
-        return _transform(x, kind, axes_ns, norm, dense_fn, route)
+    return _transform(x, kind, axes_ns, norm, dense_fn, root)
 
 
 def _axes_n(x, axes):

@@ -547,6 +547,19 @@ class Communication:
                 x, name, split_axis=split_axis, concat_axis=concat_axis, tiled=True,
             )
 
+    def overlap_compiler_options(self) -> dict:
+        """Compile options (``jax.jit(..., compiler_options=...)``) of a
+        program that issues ``all_to_all`` on independent blocks and wants
+        each exchange in flight while the other blocks compute: on a TPU mesh
+        the compiler then emits ``all-to-all-start`` / ``-done`` pairs and
+        schedules the blocks' fusions between them; without the option every
+        all-to-all is one synchronous operation however the program is cut
+        (PERF.md section 6, PR 32).  Empty on other backends, whose compilers
+        refuse the name."""
+        if self.devices[0].platform == "tpu":
+            return {"xla_tpu_enable_async_all_to_all": True}
+        return {}
+
     def psum_scatter(self, x, axis_name: Optional[str] = None, scatter_dimension: int = 0):
         """Reduce-scatter: the sum lands shard-wise instead of replicated
         (the reference's Reduce_scatter, communication.py; the sparse

@@ -106,7 +106,7 @@ from .tracing import (
     tracez_report,
     use_context,
 )
-from .profiling import annotate, idle_by_span, monitor, start_trace, stop_trace, trace
+from .profiling import annotate, exchange_exposure, idle_by_span, monitor, start_trace, stop_trace, trace
 from .aggregate import (
     gather_snapshots,
     merge_snapshots,
@@ -196,6 +196,7 @@ __all__ = [
     "device_peaks",
     "dump_bundle",
     "dump_json",
+    "exchange_exposure",
     "expose",
     "export_chrome_trace",
     "gather_snapshots",

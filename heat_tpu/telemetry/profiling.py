@@ -20,12 +20,13 @@ import contextlib
 import functools
 import glob
 import os
+import re
 import time
 from typing import Callable, Optional
 
 import jax
 
-__all__ = ["annotate", "idle_by_span", "monitor", "start_trace", "stop_trace", "trace"]
+__all__ = ["annotate", "exchange_exposure", "idle_by_span", "monitor", "start_trace", "stop_trace", "trace"]
 
 #: what an idle stretch is given to when no program span is open: the
 #: caller's own code (its loop, its ``block_until_ready``)
@@ -171,6 +172,16 @@ def attribute_idle(device_ops, thread_spans) -> dict:
     }
 
 
+def _newest_planes(trace_dir: str):
+    """The planes of the newest trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise FileNotFoundError(f"no xplane under {trace_dir}")
+    return ProfileData.from_file(paths[-1]).planes
+
+
 def idle_by_span(trace_dir: str) -> dict:
     """The device's idle time by what the host was doing, from the newest
     trace under ``trace_dir`` (as :func:`start_trace` / ``jax.profiler``
@@ -184,16 +195,11 @@ def idle_by_span(trace_dir: str) -> dict:
     process's ring, so call it in the process that was traced.  Returns
     ``{"idle": [(span name, idle seconds, gaps)] largest first, "traced_s",
     "busy_share", "longest": (seconds, span name) of one stretch}``."""
-    from jax.profiler import ProfileData
-
     from .spans import get_spans
 
-    paths = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not paths:
-        raise FileNotFoundError(f"no xplane under {trace_dir}")
     names = {rec.name for rec in get_spans()}
     device_ops, threads = [], []
-    for plane in ProfileData.from_file(paths[-1]).planes:
+    for plane in _newest_planes(trace_dir):
         if plane.name.startswith("/device:TPU:"):
             device_ops += [
                 [(e.name, e.start_ns, e.duration_ns) for e in line.events]
@@ -205,3 +211,107 @@ def idle_by_span(trace_dir: str) -> dict:
                 for line in plane.lines
             ]
     return attribute_idle(device_ops, max(threads, key=len, default=[]))
+
+
+#: the exchanges between devices, by the stem of their opcode
+_EXCHANGES = ("all-to-all", "collective-permute", "all-gather", "all-reduce", "reduce-scatter", "collective-broadcast")
+_HLO = re.compile(r"%([^ ]+?)(?:\.\d+)? = .*? ([a-z][a-z\-]*)\(")
+
+
+def _exchange_part(hlo: str):
+    """``(stem, part)`` of an exchange in a device trace, which names an
+    operation by its HLO line; ``part`` is ``""`` for a synchronous one,
+    ``"start"`` or ``"done"`` for the halves of an asynchronous one, whatever
+    its form: ``%x = ... all-to-all-start(`` or, as the TPU's compiler writes
+    it, ``%all-to-all-start.4 = ... async-start(``, where only the
+    instruction's name says what is started.  None for any other operation."""
+    m = _HLO.match(hlo)
+    if not m:
+        return None
+    name, opcode = m.groups()
+    what = name if opcode in ("async-start", "async-done") else opcode
+    for stem in _EXCHANGES:
+        if what == stem:
+            return stem, ""
+        if what in (stem + "-start", stem + "-done"):
+            return stem, what[len(stem) + 1:]
+    return None
+
+
+def _self_times(events):
+    """``(name, self ns)`` of each event of one line: its duration less the
+    events nested in it (a ``while`` holds its body's operations)."""
+    out, stack = [], []  # stack of [name, end, self]
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][1] <= start:
+            out.append(tuple(stack.pop()[::2]))
+        if stack:
+            stack[-1][2] -= dur
+        stack.append([name, start + dur, dur])
+    return out + [tuple(entry[::2]) for entry in stack]
+
+
+def attribute_exchanges(device_ops, async_ops=()) -> dict:
+    """The pure part of :func:`exchange_exposure`, on lists of ``(HLO line,
+    start_ns, duration_ns)``: ``device_ops`` holds one list a device (its
+    ``XLA Ops``), ``async_ops`` likewise its ``Async XLA Ops``.  Seconds are
+    a device's, the mean over the devices, summed over EVERY exchange
+    operation of the trace, however small."""
+    n = max(len(device_ops), 1)
+    sums = {"": 0.0, "start": 0.0, "done": 0.0}
+    counts = {"": 0, "start": 0, "done": 0}
+    by_stem = {}
+    for ops in device_ops:
+        for hlo, ns in _self_times(ops):
+            part = _exchange_part(hlo)
+            if part:
+                sums[part[1]] += ns
+                counts[part[1]] += 1
+                if part[1] != "start":
+                    by_stem[part[0]] = by_stem.get(part[0], 0.0) + ns / n / 1e9
+    # the profiler fills ``Async XLA Ops`` on some planes only (the first of four v5e chips, PR 32): the mean is theirs
+    in_flight = sum(d for ops in async_ops for hlo, _, d in ops if _exchange_part(hlo)) / max(sum(1 for ops in async_ops if ops), 1)
+    return {
+        "exposed_s": (sums[""] + sums["done"]) / n / 1e9,
+        "issue_s": sums["start"] / n / 1e9,
+        "in_flight_s": in_flight / 1e9,
+        "hidden_s": max(in_flight - sums["done"] / n, 0.0) / 1e9,
+        "exposed_by_exchange": by_stem,
+        "synchronous": counts[""] // n,
+        "asynchronous": counts["start"] // n,
+        "devices": len(device_ops),
+    }
+
+
+def exchange_exposure(trace_dir: str) -> dict:
+    """How much of the devices' exchanges the compute beside them hides,
+    from the newest trace under ``trace_dir``: over every operation of each
+    ``/device:TPU:*`` plane whose opcode is an exchange (``all-to-all``,
+    ``collective-permute``, ``all-gather``, ``all-reduce``,
+    ``reduce-scatter``, ``collective-broadcast``), synchronous or a start /
+    done pair.  Seconds a device over the whole trace (divide by the solves
+    traced):
+
+    * ``exposed_s``: self time in synchronous exchanges and in the ``-done``
+      halves, while the device runs nothing else (``exposed_by_exchange``
+      splits it by opcode);
+    * ``issue_s``: self time in the ``-start`` halves;
+    * ``in_flight_s``: the pairs' intervals on the ``Async XLA Ops`` line,
+      from a start to the end of its done, waiting included (the mean over
+      the planes that carry the line: on a four-chip v5e host only the
+      first does);
+    * ``hidden_s``: ``in_flight_s`` less the time in the dones: how long an
+      exchange was in flight while the device ran something else;
+    * ``synchronous`` / ``asynchronous``: exchanges a device of each form.
+
+    On the benchmark's FFT cell (``ht.fft.fftn`` of 1024^3 over four v5e
+    chips) this is the reading PERF.md gives beside ``solve_ms``: the
+    benchmark's own readers keep the ten largest operations and match the
+    opcode ``all-to-all`` exactly (ROADMAP S1 (h))."""
+    device_ops, async_ops = [], []
+    for plane in _newest_planes(trace_dir):
+        if plane.name.startswith("/device:TPU:"):
+            lines = {line.name: [(e.name, e.start_ns, e.duration_ns) for e in line.events] for line in plane.lines}
+            device_ops.append(lines.get("XLA Ops", []))
+            async_ops.append(lines.get("Async XLA Ops", []))
+    return attribute_exchanges(device_ops, async_ops)

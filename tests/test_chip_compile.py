@@ -315,28 +315,52 @@ def test_lloyd_step_four_chips(comm4, for_the_chip, program):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-@pytest.mark.parametrize("kind,dtype,all_to_alls,temporaries", [
-    ("fft", jnp.float32, 3, 4_295_314_944), ("ifft", jnp.complex64, 4, 5_369_153_536)])
-def test_fftn_pencil_at_the_benchmark_cell(comm4, for_the_chip, kind, dtype, all_to_alls, temporaries):
+@pytest.mark.parametrize("kind,dtype,planes,plain,temporaries", [
+    ("fft", jnp.float32, 3, 0, 3_513_555_456), ("ifft", jnp.complex64, 4, 36, 3_246_353_920)])
+def test_fftn_pencil_at_the_benchmark_cell(comm4, for_the_chip, kind, dtype, planes, plain, temporaries):
     """The one program of ``ht.fft.fftn`` / ``ifftn`` on the 1024^3 cube split
-    over four chips (PR 31's cell).  No all-gather: a chip never holds more
-    than its slabs.  A complex64 array is two float32 planes on a TPU, so its
-    all-to-all is two: three for a real cube's forward transform, four for
-    the inverse.  Pinned a chip: the slab in, the spectrum's slab out, and
-    the temporaries (two slabs of the spectrum, three for the inverse); with
-    the caller's previous result (2,147,483,648 B) still under 16 GB."""
+    over four chips (PR 31's cell), its pencil in sixteen blocks (PR 32).  No
+    all-gather: a chip never holds more than its slabs.  A complex64 array is
+    two float32 planes on a TPU, so its all-to-all is two: three planes cross
+    for a real cube's forward transform and four for the inverse, each block
+    by block.  **The overlap, pinned where no chip is needed**: the forward
+    transform's 48 exchanges are all ``all-to-all-start`` / ``-done`` pairs,
+    and the schedule puts fusions between most starts and their dones (one
+    exchange is in flight at a time); the inverse keeps 36 plain ones, every
+    block's way in among them (PERF.md section 7).  Pinned a chip: the slab
+    in, the spectrum's slab out, the temporaries (4,295,314,944 and
+    5,369,153,536 B in one block) and the code's size; with the caller's
+    previous result (2,147,483,648 B) still under 16 GB."""
     import importlib
 
     fft = importlib.import_module("heat_tpu.fft.fft")
     n = 1024
-    stages = fft._stages(kind, ((0, None), (1, None), (2, None)), 0, None)
+    stages = fft._planned(fft._stages(kind, ((0, None), (1, None), (2, None)), 0, None), comm4, 0, (n, n, n), n, np.dtype(dtype))
+    blocks = fft._blocks(stages)
+    assert blocks == 16 and stages[0][4:7] == (2, 1, 16)  # the last axis the partner, the blocks along the middle one
     compiled = fft._slab_program(comm4, 0, 3, n, stages).lower(_sds((n, n, n), dtype, comm4.sharding(0))).compile()
     txt = compiled.as_text()
     assert "all-gather" not in txt
-    assert len(re.findall(r" all-to-all\(", txt)) == all_to_alls
+    schedule = re.findall(r" = .*? ([a-z][a-z\-]*)\(", txt[txt.index("ENTRY"):])
+    started = schedule.count("all-to-all-start")
+    assert started + schedule.count("all-to-all") == planes * blocks and started == schedule.count("all-to-all-done")
+    assert schedule.count("all-to-all") == plain
+    between, hidden = None, []  # fusions between each start and its done
+    for op in schedule:
+        if op == "all-to-all-start":
+            between = 0
+        elif op == "all-to-all-done":
+            hidden.append(between)
+            between = None
+        elif op == "fusion" and between is not None:
+            between += 1
+    assert len(hidden) == started and sum(1 for k in hidden if k) >= 3 * started // 4, hidden
     m = compiled.memory_analysis()
     slab = n ** 3 // 4 * jnp.dtype(dtype).itemsize
     assert (m.argument_size_in_bytes, m.output_size_in_bytes, m.temp_size_in_bytes) == (slab, 2_147_483_648, temporaries)
+    # sixteen blocks' fusions are compiled one by one: the program's code is 111 MB on the chip for the one block's 11
+    # (what ``peak_bytes_in_use`` rose by in the cell, PR 32), and stays under an eighth of a GiB
+    assert 64 << 20 < m.generated_code_size_in_bytes < 128 << 20
     assert _device_bytes(compiled) + 2_147_483_648 < HBM_BYTES
 
 

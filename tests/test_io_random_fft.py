@@ -347,7 +347,8 @@ class TestPencilFFT:
         fft_mod = importlib.import_module("heat_tpu.fft.fft")
         p = ht.get_comm().size
         a = ht.array(np.zeros((3 * p, 2 * p, 8)), split=0)
-        fn = fft_mod._slab_program(a.comm, 0, 3, 3 * p, fft_mod._stages("fft", ((0, None),), 0, None))
+        stages = fft_mod._planned(fft_mod._stages("fft", ((0, None),), 0, None), a.comm, 0, (3 * p, 2 * p, 8), 3 * p, np.dtype(np.complex128))
+        fn = fft_mod._slab_program(a.comm, 0, 3, 3 * p, stages)
         txt = fn.lower(a.larray_padded.astype(np.complex128)).compile().as_text()
         assert "all-to-all" in txt
         assert "all-gather" not in txt

@@ -17,7 +17,7 @@ import pytest
 import heat_tpu as ht
 from heat_tpu import telemetry
 from heat_tpu.parallel.comm import Communication
-from heat_tpu.telemetry.profiling import OUTSIDE, attribute_idle, idle_by_span
+from heat_tpu.telemetry.profiling import OUTSIDE, _exchange_part, attribute_exchanges, attribute_idle, exchange_exposure, idle_by_span
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
@@ -69,12 +69,12 @@ def _ifftn(a):
     return [ht.fft.ifftn(a).numpy()]
 
 
-FFT_ATTRS = {"shape": f"{ROWS}x{COLS}", "split": 0, "dtype": "float32", "route": "dense"}  # one device: nothing to trade
+FFT_ATTRS = {"shape": f"{ROWS}x{COLS}", "split": 0, "dtype": "float32", "route": "dense", "blocks": 1}  # one device: nothing to trade
 
 #: solve -> (root, its attributes, children in the order they open)
 SOLVES = {
-    "fftn": (_fftn, "ht.fft.fftn", {**FFT_ATTRS, "kind": "fft"}, [("fft.dispatch", {}), ("fft.wrap", {})]),
-    "ifftn": (_ifftn, "ht.fft.ifftn", {**FFT_ATTRS, "kind": "ifft"}, [("fft.dispatch", {}), ("fft.wrap", {})]),
+    "fftn": (_fftn, "ht.fft.fftn", {**FFT_ATTRS, "kind": "fft"}, [("fft.dispatch", {"blocks": 1}), ("fft.wrap", {})]),
+    "ifftn": (_ifftn, "ht.fft.ifftn", {**FFT_ATTRS, "kind": "ifft"}, [("fft.dispatch", {"blocks": 1}), ("fft.wrap", {})]),
     "hsvd_rank": (_hsvd_rank, "ht.linalg.hsvd", {"rows": ROWS, "cols": COLS, "split": 0, "rank": 4},
                   [("hsvd.dispatch", {"path": "rank"}), ("hsvd.wrap", {})]),
     "hsvd_rtol": (_hsvd_rtol, "ht.linalg.hsvd", {"rows": ROWS, "cols": COLS, "split": 0, "rtol": 1e-1},
@@ -125,7 +125,8 @@ def test_fftn_names_its_route_on_the_mesh(split, route):
         telemetry.set_tracing(prev)
         telemetry.clear_spans()
     assert [r.name for r in spans] == ["ht.fft.fftn", "fft.dispatch", "fft.wrap"]
-    assert spans[0].attrs == {"shape": "24x16x6", "split": split, "dtype": "float32", "kind": "fft", "route": route}
+    assert spans[0].attrs == {"shape": "24x16x6", "split": split, "dtype": "float32", "kind": "fft", "route": route, "blocks": 1}
+    assert spans[1].attrs == {"blocks": 1}  # a small slab: the pencil in one block (tests/test_fft_pencil_kinds.py cuts one)
     assert first.count("comm.all_to_all") == (2 if route == "pencil" else 0)
     assert [n for n in first if not n.startswith(("comm.", "dispatch."))] == ["ht.fft.fftn", "fft.dispatch", "fft.wrap"]
 
@@ -182,11 +183,17 @@ def test_spans_land_in_the_profilers_host_plane(one_device, tmp_path, solve, roo
     assert r[1] <= c[1] and c[1] + c[2] <= r[1] + r[2]
     # no device plane on the CPU: the operator's function finds nothing to split
     assert idle_by_span(str(tmp_path)) == {"idle": [], "traced_s": 0.0, "busy_share": 0.0, "longest": None}
+    assert exchange_exposure(str(tmp_path)) == {**NO_EXCHANGE, "devices": 0}
 
 
-def test_idle_by_span_wants_a_trace(tmp_path):
+NO_EXCHANGE = {"exposed_s": 0.0, "issue_s": 0.0, "in_flight_s": 0.0, "hidden_s": 0.0, "exposed_by_exchange": {},
+               "synchronous": 0, "asynchronous": 0}
+
+
+@pytest.mark.parametrize("reader", [idle_by_span, exchange_exposure], ids=["idle_by_span", "exchange_exposure"])
+def test_a_trace_reader_wants_a_trace(tmp_path, reader):
     with pytest.raises(FileNotFoundError):
-        idle_by_span(str(tmp_path))
+        reader(str(tmp_path))
 
 
 # ---------------------------------------------------------------- attribute_idle
@@ -303,3 +310,68 @@ def test_fft_layer_metric_readers(one_device, reader, case):
         assert got is None and reader in run["notes"]
     else:
         assert got == pytest.approx(want) and run["notes"] == {}
+
+
+# ---------------------------------------------------------------- exchange_exposure
+#: an operation's name in a device trace is its HLO line; the TPU's compiler writes an asynchronous exchange as
+#: ``async-start`` / ``async-done`` whose instruction's name alone says what is started (PR 32's traces)
+EXCHANGE_NAMES = {
+    "synchronous": ("%all_to_all.40 = f32[1024,256,1024]{2,1,0:T(8,128)} all-to-all(%fusion.7), channel_id=2", ("all-to-all", "")),
+    "async_start_by_name": ("%all-to-all-start.4 = ((f32[256,64,1024]{2,1,0:T(8,128)}), f32[256,64,1024]{2,1,0:T(8,128)}, u32[], u32[]) "
+                            "async-start(%slice-done.3), calls=%wrapped", ("all-to-all", "start")),
+    "async_done_by_name": ("%all-to-all-done.4 = f32[256,64,1024]{2,1,0:T(8,128)} async-done(%all-to-all-start.4)", ("all-to-all", "done")),
+    "first_pair_has_no_number": ("%all-to-all-start = ((f32[8]), f32[8], u32[], u32[]) async-start(%p)", ("all-to-all", "start")),
+    "start_by_opcode": ("%x.1 = (f32[8], f32[8]) all-to-all-start(%p), replica_groups={{0,1}}", ("all-to-all", "start")),
+    "permute_done": ("%collective-permute-done.9 = f32[8] collective-permute-done(%collective-permute-start.9)", ("collective-permute", "done")),
+    "all_reduce": ("%all-reduce.2 = f32[8,17] all-reduce(%fusion.17), to_apply=%add", ("all-reduce", "")),
+    "an_asynchronous_slice_is_none": ("%slice-start.204 = ((f32[256,64,1024]), f32[64,64,1024], s32[]) async-start(%p)", None),
+    "a_copy_pair_is_none": ("%copy-done.1 = f32[8] copy-done(%copy-start.1)", None),
+    "a_fusion_named_after_one_is_none": ("%all_to_all.55 = f32[1024,256,1024] reshape(%all_to_all.44)", None),
+    "no_hlo_line": ("jit_body(16068260962732680508)", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXCHANGE_NAMES))
+def test_exchange_operations_by_their_trace_names(case):
+    hlo, want = EXCHANGE_NAMES[case]
+    assert _exchange_part(hlo) == want
+
+
+def _hlo(name, opcode):
+    return f"%{name} = f32[8] {opcode}(%p)"
+
+
+#: case -> (one list of XLA Ops a device, one of Async XLA Ops, what is wanted of the result)
+EXCHANGE_CASES = {
+    # PR 31's pencil: three synchronous all-to-alls of 12 between fusions, on each of two devices
+    "synchronous": ([[(_hlo("f.1", "fusion"), 0.0, 10.0), (_hlo("all_to_all.36", "all-to-all"), 10.0, 12.0), (_hlo("f.2", "fusion"), 22.0, 10.0),
+                      (_hlo("all_to_all.40", "all-to-all"), 32.0, 12.0), (_hlo("all_to_all.44", "all-to-all"), 44.0, 12.0)]] * 2, [[], []],
+                    {"exposed_s": 36e-9, "issue_s": 0.0, "in_flight_s": 0.0, "hidden_s": 0.0, "synchronous": 3, "asynchronous": 0,
+                     "exposed_by_exchange": {"all-to-all": 36e-9}, "devices": 2}),
+    # PR 32's: a pair in flight 10 with a fusion of 7 between start (1) and done (2): 8 hidden; and one all-reduce left synchronous
+    "pairs": ([[(_hlo("all-to-all-start.1", "async-start"), 0.0, 1.0), (_hlo("f.1", "fusion"), 1.0, 7.0), (_hlo("all-to-all-done.1", "async-done"), 8.0, 2.0),
+                (_hlo("all-reduce.2", "all-reduce"), 10.0, 0.5), (_hlo("slice-start.3", "async-start"), 11.0, 1.0)]],
+              [[(_hlo("all-to-all-start.1", "async-start"), 0.0, 10.0), (_hlo("slice-start.3", "async-start"), 11.0, 4.0)]],
+              {"exposed_s": 2.5e-9, "issue_s": 1e-9, "in_flight_s": 10e-9, "hidden_s": 8e-9, "synchronous": 1, "asynchronous": 1,
+               "exposed_by_exchange": {"all-to-all": 2e-9, "all-reduce": 0.5e-9}, "devices": 1}),
+    # four chips' trace keeps the asynchronous line on the first plane only: in flight is that plane's, not a quarter of it
+    "async_line_on_one_plane": ([[(_hlo("all-to-all-start.1", "async-start"), 0.0, 1.0), (_hlo("f.1", "fusion"), 1.0, 7.0),
+                                  (_hlo("all-to-all-done.1", "async-done"), 8.0, 2.0)]] * 4,
+                                [[(_hlo("all-to-all-start.1", "async-start"), 0.0, 10.0)], [], [], []],
+                                {"exposed_s": 2e-9, "issue_s": 1e-9, "in_flight_s": 10e-9, "hidden_s": 8e-9, "synchronous": 0, "asynchronous": 1,
+                                 "exposed_by_exchange": {"all-to-all": 2e-9}, "devices": 4}),
+    # an exchange inside a ``while``: the loop's own time is not the exchange's, the exchange's is not the loop's
+    "nested": ([[(_hlo("while.1", "while"), 0.0, 20.0), (_hlo("all-reduce.2", "all-reduce"), 2.0, 3.0), (_hlo("f.1", "fusion"), 5.0, 10.0)]], [[]],
+               {"exposed_s": 3e-9, "issue_s": 0.0, "in_flight_s": 0.0, "hidden_s": 0.0, "synchronous": 1, "asynchronous": 0,
+                "exposed_by_exchange": {"all-reduce": 3e-9}, "devices": 1}),
+    "no_exchange": ([[(_hlo("f.1", "fusion"), 0.0, 10.0)]], [[]], {**NO_EXCHANGE, "devices": 1}),
+    "no_device": ([], [], {**NO_EXCHANGE, "devices": 0}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXCHANGE_CASES))
+def test_attribute_exchanges(case):
+    device_ops, async_ops, want = EXCHANGE_CASES[case]
+    got = attribute_exchanges(device_ops, async_ops)
+    assert got.pop("exposed_by_exchange") == pytest.approx(want.pop("exposed_by_exchange"))
+    assert got == pytest.approx(want)
