@@ -228,16 +228,25 @@ def __binary_op(
         or (t2.ndim == 0 and t2.split is None and t1.shape == out_shape
             and (t1.split is None or t1.shape[t1.split] != 1))
     )
-    if same_layout or scalar_fast:
-        carrier = t1 if t1.shape == out_shape else t2
-        node = None
-        if _fusable(t1, t2):
-            node = dispatch.make_node(
-                operation, (_fusion_arg(t1), _fusion_arg(t2)), fn_kwargs
-            )
-            if node is not None and node.shape != carrier._padded_shape:
-                node = None  # op degenerated the padded layout: eager path
-        if node is not None and not types.heat_type_is_complexfloating(node.dtype):
+    # (c) one operand has the output's shape and the other's padded buffer
+    # broadcasts against it element for element (a row of statistics
+    # against a table split along its rows): joins the chain, or takes the
+    # general path below when it cannot be fused
+    padded_fast = same_layout or scalar_fast
+    broadcast = None if padded_fast else _broadcast_carrier(t1, t2, out_shape)
+    carrier = broadcast if broadcast is not None else (t1 if t1.shape == out_shape else t2)
+    node = None
+    if (padded_fast or broadcast is not None) and _fusable(t1, t2):
+        node = dispatch.make_node(
+            operation, (_fusion_arg(t1), _fusion_arg(t2)), fn_kwargs
+        )
+        if node is not None and (
+            node.shape != carrier._padded_shape
+            or types.heat_type_is_complexfloating(node.dtype)
+        ):
+            node = None  # op degenerated the padded layout, or complex: eager path
+    if padded_fast or node is not None:
+        if node is not None:
             res = DNDarray.from_pending(
                 node, out_shape, carrier.split, carrier.device, carrier.comm
             )
@@ -274,6 +283,31 @@ def __binary_op(
         return store_out(res, out)
     # an active ragged layout survives elementwise ops (lhs-first)
     return res._propagate_layout_from(t1, t2)
+
+
+def _broadcast_carrier(t1: DNDarray, t2: DNDarray, out_shape) -> Optional[DNDarray]:
+    """The operand that carries a broadcasting binary op's layout, when the
+    other's PADDED buffer broadcasts against the carrier's padded buffer as
+    it stands: the carrier has the output's shape, and the other is either
+    replicated with extent 1 (or no axis) where the carrier is split, or
+    split along the same right-aligned axis with the same extent (a column
+    of row norms against the table).  None: take the general path."""
+    for c, o in ((t1, t2), (t2, t1)):
+        if c.shape != tuple(out_shape) or not 1 <= o.ndim <= c.ndim:
+            continue
+        if c.split is None:
+            if o.split is None:
+                return c
+            continue
+        if c.shape[c.split] == 1:
+            continue
+        ax = c.split - (c.ndim - o.ndim)  # the other's axis under the carrier's split
+        if o.split is None:
+            if ax < 0 or o.shape[ax] == 1:
+                return c
+        elif o.split == ax and o.shape[ax] == c.shape[c.split]:
+            return c
+    return None
 
 
 def _fusable(*operands: DNDarray) -> bool:

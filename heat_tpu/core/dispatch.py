@@ -135,7 +135,7 @@ def fusion_enabled() -> bool:
 # byte-compatible view over them.
 # ----------------------------------------------------------------------
 _COUNTER_NAMES = ("hits", "misses", "dispatches", "fused_ops", "donations",
-                  "external_dispatches", "compile_fallbacks")
+                  "external_dispatches", "compile_fallbacks", "stores")
 _C = {n: _tm.counter(f"dispatch.{n}") for n in _COUNTER_NAMES}
 
 #: per-compile wall time (jit trace + XLA compile + first execution of a
@@ -178,7 +178,11 @@ def cache_stats() -> dict:
     the compiled-program launches issued through this layer,
     ``fused_ops`` the number of elementwise/reduce ops folded into those
     launches (fused_ops >> dispatches means fusion is working), and
-    ``donations`` the in-place launches that donated a dead buffer.
+    ``donations`` the in-place launches that donated a dead buffer,
+    ``stores`` the in-place stores asked of :func:`cast_store` (``a += b``,
+    ``out=``, a scaler's ``copy=False``): ``stores - donations`` of a
+    region that only stores is the number of them that wrote a second
+    buffer.
     ``external_dispatches`` are launches recorded by consumers with their
     own jitted programs (kmeans' Lloyd loop, lasso's CD loop,
     ``fusion.jit``).  ``compile_fallbacks`` counts compiled executions
@@ -592,6 +596,11 @@ def _linearize(root):
         return (False, ix)
 
     walk(root)
+    # ``walk`` names itself: unless the cell is emptied the cycle keeps
+    # ``leaves``, and every buffer in it, alive until the collector runs,
+    # and the next in-place store's refcount proof sees a buffer that a
+    # finished reduction of a chain still seems to share
+    del walk
     return nodes, leaves, leaf_slots
 
 
@@ -1095,6 +1104,7 @@ def cast_store(dst_buf, src, dtype, out_sharding=None):
     caller); the refcount proof compares against the calibrated call
     plumbing plus the leaf-list and arg-slot references when it is a
     leaf."""
+    _C["stores"].inc()
     if isinstance(src, PendingExpr):
         nodes, leaves, leaf_slots = _linearize(src)
         root = (True, len(nodes) - 1)

@@ -11,13 +11,17 @@ split bookkeeping.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
+from jax.sharding import PartitionSpec as _P
 
-from . import types
+from ..telemetry.spans import span as _span
+from . import dispatch, types
 from ._operations import __binary_op as _binary_op
 from ._operations import __reduce_op as _reduce_op
 from ._operations import _reduced_shape, _reduced_split
@@ -354,6 +358,203 @@ def _percentile_sorted_1d(x, q, interpolation: str):
     return DNDarray.from_dense(res, None, x.device, x.comm)
 
 
+# ----------------------------------------------------------------------
+# order statistics along one axis without a sorted copy (PERF.md, PR 33)
+# ----------------------------------------------------------------------
+#: Extent along the reduced axis from which ``percentile`` selects by
+#: counting passes; below it the sort (``jnp.percentile``) is the faster
+#: route and stays what it was, result for result.  Step 0 on the chip
+#: (PERF.md section 6, PR 33; 50 columns, three quantiles, ms): 4,096 rows
+#: 0.71 sorted / 0.69 selected, 16,384 rows 1.32 / 0.85, 65,536 rows
+#: 4.29 / 1.29, 4,194,304 rows 577 / 27.
+_SELECT_MIN_EXTENT = 1 << 14
+#: Compares a counting pass carries before they, and not the memory, bind
+#: it.  Step 0 (PR 33): a pass over 2^25 x 50 float32 reads 10.7 ms with 1
+#: to 6 compares, 14.6 with 9, 18.7 with 12, 22.6 with 15, 36.1 with 21.
+_SELECT_COMPARES = 12
+
+
+def _select_bits(ranks: int) -> int:
+    """Bits of the key one counting pass settles (``2**bits - 1`` pivots a
+    rank): the most that keeps a pass's compares within
+    ``_SELECT_COMPARES``.  One rank takes 3 bits (11 passes of 7 compares),
+    two to four ranks 2 bits (16 passes), more 1 bit (32 passes): by step
+    0's readings three ranks read 218 ms at 2 bits, 319 at 1, 339 at 3."""
+    return next((b for b in (3, 2) if ranks * ((1 << b) - 1) <= _SELECT_COMPARES), 1)
+
+
+def _order_key(x):
+    """The unsigned integer whose order is the float's: sign bit flipped
+    for positive values, all bits for negative ones.  -0.0 sorts just
+    under +0.0 and compares equal to it; NaNs lie beyond the infinities
+    and are dealt with by their count."""
+    wide = jnp.uint64 if x.dtype == jnp.float64 else jnp.uint32
+    if x.dtype not in (jnp.float32, jnp.float64):
+        x = x.astype(jnp.float32)  # exact and order-preserving for the narrow floats
+    nbits = 8 * x.dtype.itemsize
+    u = jax.lax.bitcast_convert_type(x, wide)
+    return jnp.where(u >> (nbits - 1) == 1, ~u, u | wide(1 << (nbits - 1)))
+
+
+def _key_value(k, dtype):
+    """The float of an order key (the inverse of :func:`_order_key`)."""
+    nbits = 8 * k.dtype.itemsize
+    top = k.dtype.type(1 << (nbits - 1))
+    u = jnp.where(k >> (nbits - 1) == 1, k & ~top, ~k)
+    return jax.lax.bitcast_convert_type(u, jnp.float64 if nbits == 64 else jnp.float32).astype(dtype)
+
+
+def _select_ranks(x, axis: int, lows: tuple, with_high: bool, valid, all_sum, all_min):
+    """Exact order statistics along ``axis`` by counting passes over the
+    order key: for every 0-based rank in ``lows`` the value of that rank
+    and, ``with_high``, of the next rank (``keepdims`` form, stacked along a
+    new leading axis), plus the count of NaNs.
+
+    A pass compares the keys with ``2**bits - 1`` pivots a rank
+    (:func:`_select_bits`) and sums along ``axis``; ``all_sum`` adds the
+    counts over the mesh (ONE all-reduce of a (ranks x pivots x columns)
+    array a pass).  The digit of a rank's key is the number of its pivots
+    that at most ``rank`` elements lie under, so ``ceil(width / bits)``
+    passes settle the key, and one more pass finds the next rank: the
+    smallest key above it, or the same key where ties reach that far.  Beside the input this holds
+    O(ranks x pivots x columns): nothing of the input's size is written.
+    ``valid`` masks the canonical padding along a split axis (None: no
+    padding)."""
+    key = _order_key(x)
+    top = ~key.dtype.type(0)
+    if valid is not None:
+        key = jnp.where(valid, key, top)  # padding sorts last, under no pivot
+    nbits = 8 * key.dtype.itemsize
+    kept = tuple(1 if d == axis else s for d, s in enumerate(x.shape))  # the `keepdims` shape
+    ranks = jnp.asarray(lows, jnp.int32).reshape((-1,) + (1,) * x.ndim)
+    # every rank's settled bits in ONE array: the pass's reductions then
+    # share their operands and the compiler makes them one fusion, one read
+    # of the input (a list of arrays a rank read the table three times a pass)
+    pre = jnp.zeros((len(lows),) + kept, key.dtype)
+
+    def count(mask):
+        return jnp.sum(mask, axis=axis, keepdims=True, dtype=jnp.int32)
+
+    with jax.named_scope("quantile.count"):
+        nan = jnp.isnan(x) if valid is None else jnp.isnan(x) & valid
+        nans = None
+        shift = nbits
+        step = _select_bits(len(lows))
+        while shift > 0:
+            bits = step if shift >= step else shift  # `min` is this module's reduction
+            shift -= bits
+            digits = range(1, 1 << bits)
+            masks = [key < (pre[r] | key.dtype.type(d << shift)) for r in range(len(lows)) for d in digits]
+            if nans is None:  # the first pass counts the NaNs too: one reduction of the pass, one all-reduce
+                masks.append(nan)
+            counts = all_sum(jnp.stack([count(m) for m in masks]))
+            if nans is None:
+                nans, counts = counts[-1], counts[:-1]
+            under = counts.reshape((len(lows), len(digits)) + kept) <= ranks[:, None]
+            pre = pre | (jnp.sum(under, axis=1).astype(key.dtype) << shift)
+        if not with_high:
+            return _key_value(pre, x.dtype), None, nans
+        upto = all_sum(jnp.stack([count(key <= pre[r]) for r in range(len(lows))]))
+        above = all_min(jnp.stack(
+            [jnp.min(jnp.where(key > pre[r], key, top), axis=axis, keepdims=True) for r in range(len(lows))]))
+        return _key_value(pre, x.dtype), _key_value(jnp.where(upto >= ranks + 2, pre, above), x.dtype), nans
+
+
+def _select_passes(dtype, ranks: int, with_high: bool) -> int:
+    """How many passes over the input :func:`_select_ranks` makes."""
+    nbits = 64 if dtype == jnp.float64 else 32
+    return -(-nbits // _select_bits(ranks)) + (1 if with_high else 0)
+
+
+def _interpolate(low, high, nans, plan: tuple, method: str, keepdims: bool, axis: int, scalar_q: bool):
+    """``jnp.percentile``'s rule on the selected values: ``plan`` holds, a
+    requested ``q``, the index of its rank among the selected ones, whether
+    the next rank is its upper neighbour, and the upper weight."""
+    wdt = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    out = []
+    for ix, has_high, hw in plan:
+        lo = low[ix].astype(wdt)
+        hi = high[ix].astype(wdt) if has_high and high is not None else lo
+        if method == "linear":
+            res = lo * wdt(1.0 - hw) + hi * wdt(hw)
+        elif method == "lower":
+            res = lo
+        elif method == "higher":
+            res = hi
+        elif method == "nearest":
+            res = lo if hw <= 0.5 else hi
+        else:  # midpoint
+            res = (lo + hi) * wdt(0.5)
+        out.append(jnp.where(nans > 0, jnp.nan, res))
+    res = jnp.stack(out)
+    if not keepdims:
+        res = jnp.squeeze(res, axis=axis + 1)
+    return (res[0] if scalar_q else res).astype(low.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("axis", "lows", "with_high", "plan", "method", "keepdims", "scalar_q", "n_true"))
+def _select_program(x, *, axis, lows, with_high, plan, method, keepdims, scalar_q, n_true):
+    """The whole selection as one program on one device, or on an array
+    whose reduced axis no mesh divides."""
+    valid = None
+    if n_true != x.shape[axis]:
+        valid = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) < n_true
+    low, high, nans = _select_ranks(x, axis, lows, with_high, valid, lambda a: a, lambda a: a)
+    return _interpolate(low, high, nans, plan, method, keepdims, axis, scalar_q)
+
+
+@functools.lru_cache(maxsize=64)
+def _select_program_split(comm, axis, lows, with_high, plan, method, keepdims, scalar_q, n_true, padded):
+    """The same over a mesh, along the split axis: each device counts in its
+    own rows and the counts are all-reduced; nothing is gathered or sorted
+    across devices."""
+    spec = _P(*((None,) * axis), comm.axis_name)
+
+    def body(block):
+        valid = None
+        if padded:
+            first = jax.lax.axis_index(comm.axis_name) * block.shape[axis]
+            valid = first + jax.lax.broadcasted_iota(jnp.int32, block.shape, axis) < n_true
+        low, high, nans = _select_ranks(block, axis, lows, with_high, valid, comm.psum, comm.pmin)
+        return _interpolate(low, high, nans, plan, method, keepdims, axis, scalar_q)
+
+    return jax.jit(_shard_map(body, mesh=comm.mesh, in_specs=spec, out_specs=_P()))
+
+
+def _percentile_selected(x: DNDarray, q, axis: int, interpolation: str, keepdims: bool) -> DNDarray:
+    """Percentiles along ONE axis by exact selection (all ``q`` in one
+    program, :func:`_select_ranks`), interpolated as ``jnp.percentile``
+    interpolates.  The ranks and weights are worked out on the host in
+    float64 from the static extent; the counts and pivots stay on the
+    device, and nothing is read back."""
+    if interpolation not in ("linear", "lower", "higher", "midpoint", "nearest"):
+        raise ValueError(f"unknown interpolation {interpolation!r}")
+    n = x.shape[axis]
+    pos = np.atleast_1d(np.asarray(q, np.float64)) / 100.0 * (n - 1)
+    low = np.clip(np.floor(pos), 0, n - 1).astype(np.int64)
+    high = np.clip(np.ceil(pos), 0, n - 1).astype(np.int64)
+    lows = tuple(sorted({int(v) for v in low}))
+    plan = tuple((lows.index(int(l)), bool(h > l), float(p - np.floor(p))) for l, h, p in zip(low, high, pos))
+    buf = x.larray_padded
+    if not types.heat_type_is_inexact(x.dtype):
+        buf = buf.astype(jnp.float32)
+    # the upper neighbours cost one more pass, and only where a rank interpolates
+    with_high = interpolation != "lower" and any(h for _, h, _ in plan)
+    static = dict(axis=axis, lows=lows, with_high=with_high, plan=plan, method=interpolation, keepdims=keepdims,
+                  scalar_q=np.ndim(q) == 0, n_true=n)
+    with _span("statistics.quantiles", route="select", q=tuple(float(v) for v in np.atleast_1d(q)),
+               passes=_select_passes(buf.dtype, len(lows), with_high), launches=1):
+        dispatch.record_external_dispatch()
+        if x.split == axis and x.comm.size > 1:
+            result = _select_program_split(x.comm, *static.values(), x._pad > 0)(buf)
+        else:
+            result = _select_program(buf, **static)
+            if x.split is not None and x.split != axis and x._pad:
+                kept = x.split - (0 if keepdims or x.split < axis else 1) + (0 if static["scalar_q"] else 1)
+                result = jax.lax.slice_in_dim(result, 0, x.shape[x.split], axis=kept)
+    return DNDarray.from_dense(result, None, x.device, x.comm)
+
+
 def median(x, axis=None, keepdims=False):
     """Median (statistics.py:1117): 50th percentile — for large 1-D split
     arrays this rides the PSRS sorted distribution, not a dense gather."""
@@ -400,9 +601,16 @@ def percentile(
 ):
     """q-th percentile (statistics.py:1443).
 
-    The reference runs a distributed sample-sort plus fractional-index
-    interpolation; the global jnp.percentile over the sharded dense view
-    compiles to the equivalent sort + gather.  ``sketched=True`` estimates
+    Along ONE axis of at least ``_SELECT_MIN_EXTENT`` elements all ``q``
+    are selected exactly in one program of counting passes over the
+    order-preserving integer key (:func:`_select_ranks`: no sorted copy,
+    O(columns) memory beside the input, over a mesh one all-reduce of
+    counts a pass) and interpolated as ``jnp.percentile`` does; NaN, the
+    zeros, infinities and ties give what it gives.  A shorter axis, several
+    axes or a flattened n-D array take ``jnp.percentile`` itself (a sort),
+    as before; a large 1-D split array rides the PSRS sorted distribution.
+    The span ``statistics.quantiles`` says which (``route``).
+    ``sketched=True`` estimates
     the percentile on a random subset of ``sketch_size`` samples along the
     reduction axis (statistics.py:1490-1532) — O(sketch_size log) instead
     of a full sort, with sampling error ~1/sqrt(sketch_size).
@@ -418,6 +626,15 @@ def percentile(
             if keepdims:
                 res = res.reshape(res.shape + (1,)) if res.ndim else res.reshape((1,))
             return res
+    select_axis = 0 if axis_s is None and x.ndim == 1 else axis_s
+    if (
+        not sketched
+        and isinstance(select_axis, int)
+        and x.shape[select_axis] >= _SELECT_MIN_EXTENT
+        and not types.heat_type_is_complexfloating(x.dtype)
+    ):
+        # a long axis: exact selection by counting passes, no sorted copy
+        return _to_out(_percentile_selected(x, q, select_axis, interpolation, keepdims), out)
     dense = x._dense()
     if not types.heat_type_is_inexact(x.dtype):
         dense = dense.astype(jnp.float32)
@@ -432,7 +649,10 @@ def percentile(
         if size < n:
             idx = ht_random.randint(0, n, size=(size,), comm=x.comm)._dense()
             dense = dense.ravel()[idx] if axis_s is None else jnp.take(dense, idx, axis=axis_s)
-    result = jnp.percentile(dense, qa, axis=axis_s, method=interpolation, keepdims=keepdims)
+    with _span("statistics.quantiles", route="sort", q=tuple(float(v) for v in np.atleast_1d(q_chk)),
+               passes=1, launches=1):
+        dispatch.record_external_dispatch()
+        result = jnp.percentile(dense, qa, axis=axis_s, method=interpolation, keepdims=keepdims)
     res = DNDarray.from_dense(result, None, x.device, x.comm)
     return _to_out(res, out)
 
@@ -456,6 +676,10 @@ def std(x, axis=None, ddof: int = 0, keepdims: bool = False, **kwargs):
     return exponential.sqrt(var(x, axis, ddof=ddof, keepdims=keepdims, **kwargs))
 
 
+def _var_fn(a, axis=None, ddof=0, keepdims=False):
+    return jnp.var(a, axis=axis, ddof=ddof, keepdims=keepdims)
+
+
 def var(x, axis=None, ddof: int = 0, keepdims: bool = False, **kwargs):
     """Variance (statistics.py:1903).
 
@@ -473,7 +697,11 @@ def var(x, axis=None, ddof: int = 0, keepdims: bool = False, **kwargs):
     if not types.heat_type_is_inexact(x.dtype):
         dense = dense.astype(jnp.float32)
     axis_s = sanitize_axis(x.shape, axis)
-    result = jnp.var(dense, axis=axis_s, ddof=ddof, keepdims=keepdims)
+    # one cached executable, counted among the launches like every other reduction
+    result = dispatch.eager_apply(
+        _var_fn, (dense,),
+        {"axis": tuple(axis_s) if isinstance(axis_s, list) else axis_s, "ddof": ddof, "keepdims": bool(keepdims)},
+    )
     if axis_s is None or x.split is None:
         out_split = None
     else:

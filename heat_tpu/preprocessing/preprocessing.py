@@ -2,17 +2,37 @@
 (StandardScaler :49, MinMaxScaler :158, Normalizer :284, MaxAbsScaler
 :358, RobustScaler :444).  All are pure compositions of the distributed
 ops layer (mean/var/min/max/percentile over the sharded sample axis).
+
+``copy`` means what upstream means by it.  With ``copy=True`` (the default)
+``transform`` / ``inverse_transform`` / ``fit_transform`` return a new array
+and leave their input untouched.  With ``copy=False`` they build the same
+chain (``(x - mean) / scale``), store it ONCE into the input through the
+library's one in-place store (``dndarray._iop`` -> ``dispatch.cast_store``:
+one program, one read and one write of the table, the input's buffer donated
+where it is provably unshared) and return that same object, so a table that
+fills the chip is scaled without a second generation of it.  Where the buffer
+is shared (a second ``DNDarray`` on it, a held ``larray_padded``) the result
+is still right and the span's ``inplace`` says that no donation happened.  An
+input that has to be cast first (integers) cannot be written in place: the
+store's cast check raises ``TypeError``, as upstream's does.
+
+Every ``fit`` / ``transform`` / ``inverse_transform`` is one root span
+``ht.preprocessing.<Class>.<method>`` (``docs/observability.md``).
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import contextlib
+from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
-from ..core import statistics, types
+from ..core import arithmetics, dispatch, exponential, rounding, statistics, types
+from ..core._operations import __local_op as _local_op
 from ..core.base import BaseEstimator, TransformMixin
-from ..core.dndarray import DNDarray
+from ..core.dndarray import DNDarray, _iop
+from ..telemetry.spans import span as _span
 
 __all__ = ["StandardScaler", "MinMaxScaler", "Normalizer", "MaxAbsScaler", "RobustScaler"]
 
@@ -25,7 +45,59 @@ def _check_2d_float(x, name="X"):
     return x
 
 
-class StandardScaler(BaseEstimator, TransformMixin):
+def _one_where_zero(a):
+    return jnp.where(a == 0, jnp.ones((), a.dtype), a)
+
+
+def _guard_zero(x: DNDarray) -> DNDarray:
+    """Zeros replaced by ones: a constant feature, or an empty sample, is
+    left as it is and not divided by zero (preprocessing.py:120)."""
+    return _local_op(_one_where_zero, x)
+
+
+@contextlib.contextmanager
+def _root(scaler, method: str, x):
+    """The root span of one scaler call and the scope its programs are
+    traced under.  Set at exit: ``launches`` (programs enqueued),
+    ``stores`` (in-place stores), ``donations`` (stores that took the
+    input's buffer) and ``inplace`` (every store did, and there was one)."""
+    before = dispatch.cache_stats()
+    shape = getattr(x, "shape", ())
+    with _span(f"ht.preprocessing.{type(scaler).__name__}.{method}",
+               rows=shape[0] if shape else None, features=shape[1] if len(shape) > 1 else None,
+               split=getattr(x, "split", None), copy=scaler.copy) as sp:
+        with jax.named_scope("scaler.fit" if method == "fit" else "scaler.apply"):
+            yield
+        after = dispatch.cache_stats()
+        stores, donations = (after[k] - before[k] for k in ("stores", "donations"))
+        sp.attrs.update(
+            launches=sum(after[k] - before[k] for k in ("dispatches", "external_dispatches")),
+            stores=stores, donations=donations, inplace=0 < stores == donations)
+
+
+class _Scaler(BaseEstimator, TransformMixin):
+    """What the five scalers share: the spans, and where a result goes."""
+
+    def _result(self, x: DNDarray, y: DNDarray) -> DNDarray:
+        """``y`` itself, or with ``copy=False`` the input holding it: ONE
+        in-place store of the pending chain."""
+        return y if self.copy else _iop(x, y)
+
+    def fit(self, x: DNDarray, *args, **kwargs):
+        with _root(self, "fit", x):
+            self._fit(_check_2d_float(x), *args, **kwargs)
+        return self
+
+    def transform(self, x: DNDarray) -> DNDarray:
+        with _root(self, "transform", x):
+            return self._result(x, self._transform(_check_2d_float(x)))
+
+    def inverse_transform(self, y: DNDarray) -> DNDarray:
+        with _root(self, "inverse_transform", y):
+            return self._result(y, self._inverse(_check_2d_float(y, "Y")))
+
+
+class StandardScaler(_Scaler):
     """Zero-mean unit-variance standardization (preprocessing.py:49)."""
 
     def __init__(self, copy: bool = True, with_mean: bool = True, with_std: bool = True):
@@ -35,43 +107,29 @@ class StandardScaler(BaseEstimator, TransformMixin):
         self.mean_ = None
         self.var_ = None
 
-    def fit(self, x: DNDarray, sample_weight=None) -> "StandardScaler":
+    def _fit(self, x: DNDarray, sample_weight=None) -> None:
         if sample_weight is not None:
             raise NotImplementedError("sample_weight is not yet supported (matching preprocessing.py:95)")
-        x = _check_2d_float(x)
         self.mean_ = statistics.mean(x, axis=0) if self.with_mean else None
-        if self.with_std:
-            v = statistics.var(x, axis=0)
-            # guard zero-variance features (preprocessing.py:120)
-            vd = v._dense()
-            v = DNDarray.from_dense(jnp.where(vd == 0, 1.0, vd), v.split, v.device, v.comm)
-            self.var_ = v
-        else:
-            self.var_ = None
-        return self
+        # zero-variance features are guarded (preprocessing.py:120)
+        self.var_ = _guard_zero(statistics.var(x, axis=0)) if self.with_std else None
 
-    def transform(self, x: DNDarray) -> DNDarray:
-        x = _check_2d_float(x)
+    def _transform(self, x: DNDarray) -> DNDarray:
         if self.with_mean and self.mean_ is not None:
             x = x - self.mean_
         if self.with_std and self.var_ is not None:
-            from ..core import exponential
-
             x = x / exponential.sqrt(self.var_)
         return x
 
-    def inverse_transform(self, y: DNDarray) -> DNDarray:
-        y = _check_2d_float(y, "Y")
+    def _inverse(self, y: DNDarray) -> DNDarray:
         if self.with_std and self.var_ is not None:
-            from ..core import exponential
-
             y = y * exponential.sqrt(self.var_)
         if self.with_mean and self.mean_ is not None:
             y = y + self.mean_
         return y
 
 
-class MinMaxScaler(BaseEstimator, TransformMixin):
+class MinMaxScaler(_Scaler):
     """Rescale features to a range (preprocessing.py:158)."""
 
     def __init__(self, feature_range: Tuple[float, float] = (0.0, 1.0), copy: bool = True, clip: bool = False):
@@ -85,33 +143,24 @@ class MinMaxScaler(BaseEstimator, TransformMixin):
         self.scale_ = None
         self.min_ = None
 
-    def fit(self, x: DNDarray) -> "MinMaxScaler":
-        x = _check_2d_float(x)
+    def _fit(self, x: DNDarray) -> None:
         self.data_min_ = statistics.min(x, axis=0)
         self.data_max_ = statistics.max(x, axis=0)
-        rng = self.data_max_._dense() - self.data_min_._dense()
-        rng = jnp.where(rng == 0, 1.0, rng)
         lo, hi = self.feature_range
-        scale = (hi - lo) / rng
-        self.scale_ = DNDarray.from_dense(scale, None, x.device, x.comm)
-        self.min_ = DNDarray.from_dense(lo - self.data_min_._dense() * scale, None, x.device, x.comm)
-        return self
+        self.scale_ = (hi - lo) / _guard_zero(self.data_max_ - self.data_min_)
+        self.min_ = lo - self.data_min_ * self.scale_
 
-    def transform(self, x: DNDarray) -> DNDarray:
-        x = _check_2d_float(x)
+    def _transform(self, x: DNDarray) -> DNDarray:
         y = x * self.scale_ + self.min_
         if self.clip:
-            from ..core import rounding
-
             y = rounding.clip(y, self.feature_range[0], self.feature_range[1])
         return y
 
-    def inverse_transform(self, y: DNDarray) -> DNDarray:
-        y = _check_2d_float(y, "Y")
+    def _inverse(self, y: DNDarray) -> DNDarray:
         return (y - self.min_) / self.scale_
 
 
-class Normalizer(BaseEstimator, TransformMixin):
+class Normalizer(_Scaler):
     """Scale each sample to unit norm (preprocessing.py:284)."""
 
     def __init__(self, norm: str = "l2", copy: bool = True):
@@ -120,23 +169,20 @@ class Normalizer(BaseEstimator, TransformMixin):
         self.norm = norm
         self.copy = copy
 
-    def fit(self, x: DNDarray) -> "Normalizer":
-        return self  # stateless (preprocessing.py:320)
+    def _fit(self, x: DNDarray) -> None:
+        pass  # stateless (preprocessing.py:320)
 
-    def transform(self, x: DNDarray) -> DNDarray:
-        x = _check_2d_float(x)
-        dense = x._dense()
+    def _transform(self, x: DNDarray) -> DNDarray:
         if self.norm == "l2":
-            n = jnp.sqrt(jnp.sum(dense * dense, axis=1, keepdims=True))
+            n = exponential.sqrt(arithmetics.sum(x * x, axis=1, keepdims=True))
         elif self.norm == "l1":
-            n = jnp.sum(jnp.abs(dense), axis=1, keepdims=True)
+            n = arithmetics.sum(rounding.abs(x), axis=1, keepdims=True)
         else:
-            n = jnp.max(jnp.abs(dense), axis=1, keepdims=True)
-        n = jnp.where(n == 0, 1.0, n)
-        return DNDarray.from_dense(dense / n, x.split, x.device, x.comm)
+            n = statistics.max(rounding.abs(x), axis=1, keepdims=True)
+        return x / _guard_zero(n)
 
 
-class MaxAbsScaler(BaseEstimator, TransformMixin):
+class MaxAbsScaler(_Scaler):
     """Scale by the per-feature maximum absolute value (preprocessing.py:358)."""
 
     def __init__(self, copy: bool = True):
@@ -144,26 +190,18 @@ class MaxAbsScaler(BaseEstimator, TransformMixin):
         self.max_abs_ = None
         self.scale_ = None
 
-    def fit(self, x: DNDarray) -> "MaxAbsScaler":
-        x = _check_2d_float(x)
-        from ..core import rounding
+    def _fit(self, x: DNDarray) -> None:
+        self.max_abs_ = statistics.max(rounding.abs(x), axis=0)
+        self.scale_ = _guard_zero(self.max_abs_)
 
-        m = statistics.max(rounding.abs(x), axis=0)
-        md = jnp.where(m._dense() == 0, 1.0, m._dense())
-        self.max_abs_ = m
-        self.scale_ = DNDarray.from_dense(md, None, x.device, x.comm)
-        return self
-
-    def transform(self, x: DNDarray) -> DNDarray:
-        x = _check_2d_float(x)
+    def _transform(self, x: DNDarray) -> DNDarray:
         return x / self.scale_
 
-    def inverse_transform(self, y: DNDarray) -> DNDarray:
-        y = _check_2d_float(y, "Y")
+    def _inverse(self, y: DNDarray) -> DNDarray:
         return y * self.scale_
 
 
-class RobustScaler(BaseEstimator, TransformMixin):
+class RobustScaler(_Scaler):
     """Median/IQR scaling (preprocessing.py:444)."""
 
     def __init__(
@@ -187,29 +225,26 @@ class RobustScaler(BaseEstimator, TransformMixin):
         self.center_ = None
         self.iqr_ = None
 
-    def fit(self, x: DNDarray) -> "RobustScaler":
-        x = _check_2d_float(x)
+    def _fit(self, x: DNDarray) -> None:
+        # the median and the range's two quantiles in ONE call: one pass
+        # over the table serves all three (statistics.percentile)
+        q = ([50.0] if self.with_centering else []) + (list(self.quantile_range) if self.with_scaling else [])
+        if not q:
+            return
+        got = statistics.percentile(x, q, axis=0)
         if self.with_centering:
-            self.center_ = statistics.median(x, axis=0)
+            self.center_ = got[0]
         if self.with_scaling:
-            lo, hi = self.quantile_range
-            q_lo = statistics.percentile(x, lo, axis=0)
-            q_hi = statistics.percentile(x, hi, axis=0)
-            iqr = q_hi._dense() - q_lo._dense()
-            iqr = jnp.where(iqr == 0, 1.0, iqr)
-            self.iqr_ = DNDarray.from_dense(iqr, None, x.device, x.comm)
-        return self
+            self.iqr_ = _guard_zero(got[len(q) - 1] - got[len(q) - 2])
 
-    def transform(self, x: DNDarray) -> DNDarray:
-        x = _check_2d_float(x)
+    def _transform(self, x: DNDarray) -> DNDarray:
         if self.with_centering and self.center_ is not None:
             x = x - self.center_
         if self.with_scaling and self.iqr_ is not None:
             x = x / self.iqr_
         return x
 
-    def inverse_transform(self, y: DNDarray) -> DNDarray:
-        y = _check_2d_float(y, "Y")
+    def _inverse(self, y: DNDarray) -> DNDarray:
         if self.with_scaling and self.iqr_ is not None:
             y = y * self.iqr_
         if self.with_centering and self.center_ is not None:

@@ -398,3 +398,130 @@ def test_bucketed_data_parallel_step_four_chips(comm4, for_the_chip):
     assert _device_bytes(compiled) < HBM_BYTES
     n_params = sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(params))
     assert n_params > 100_000  # the real CNN, not a stub
+
+
+# ---------------------------------------------------------------- the scalers' cell (PR 33)
+#: ``f32[33554432,50]`` as the chip lays it out: the long axis minor, 50 columns padded to 56 sublanes
+TABLE_ROWS, TABLE_COLS, TABLE_BYTES = 2**25, 50, 7_516_192_768
+
+
+@pytest.fixture()
+def programs_of_the_eager_path(monkeypatch):
+    """Run ``ht.*`` calls on SHAPES: every program the dispatch layer would
+    launch is compiled for the described chip instead and kept, and its result
+    is the shape it would have.  The code under test is the library's own
+    (``_iop`` -> ``cast_store`` -> its refcount proof -> ``donate_argnums``);
+    only the launch is taken out."""
+    from heat_tpu.core import dispatch
+
+    kept = []
+
+    def compile_not_run(compiled, leaves, n_ops, donated=False, fresh=False, key=None):
+        kept.append((key[0], donated, compiled.lower(*leaves).compile()))
+        return jax.eval_shape(compiled, *leaves)
+
+    put = jax.device_put
+    monkeypatch.setattr(dispatch, "_run", compile_not_run)
+    monkeypatch.setattr(jax, "device_put", lambda x, s=None, **kw: (
+        _sds(x.shape, x.dtype, s) if isinstance(x, jax.ShapeDtypeStruct) else put(x, s, **kw)))
+    return kept
+
+
+def _on_shapes(comm, shape, split):
+    import heat_tpu as ht
+    from heat_tpu.core import types
+
+    return ht.DNDarray(_sds(shape, jnp.float32, comm.sharding(split)), shape, types.float32, split, ht.get_device(), comm)
+
+
+def _table_stays_as_laid_out(compiled, rows=TABLE_ROWS):
+    """No row-major copy of the table (``{1,0:T(8,128)}`` pads 50 lanes to
+    128: 16 GiB for 6.7 GB of values) anywhere in the program."""
+    assert f"f32[{rows},{TABLE_COLS}]{{1,0" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["StandardScaler", "MinMaxScaler", "MaxAbsScaler", "RobustScaler", "Normalizer"])
+def test_scalers_write_the_table_in_place(topo, for_the_chip, programs_of_the_eager_path, name):
+    """At the benchmark cell's size every ``copy=False`` transform and inverse
+    is ONE program that takes the table as a donated argument and aliases its
+    output to it, with no temporary of the table's size: a scaler that wrote
+    a second generation, 15.03 GB, would not fit beside anything."""
+    import heat_tpu as ht
+    from heat_tpu.parallel.comm import Communication
+
+    comm = Communication([topo.devices[0]])
+    x = _on_shapes(comm, (TABLE_ROWS, TABLE_COLS), 0)
+    scaler = getattr(ht.preprocessing, name)(copy=False)
+    if name == "RobustScaler":  # its fit is the selection's own program, below
+        scaler.center_, scaler.iqr_ = (_on_shapes(comm, (TABLE_COLS,), None) for _ in range(2))
+    else:
+        scaler.fit(x)
+    fits = len(programs_of_the_eager_path)
+    assert scaler.transform(x) is x
+    if name != "Normalizer":
+        assert scaler.inverse_transform(x) is x
+    for kind, donated, compiled in programs_of_the_eager_path:
+        m = compiled.memory_analysis()
+        _table_stays_as_laid_out(compiled)
+        assert m.temp_size_in_bytes <= 2**27 + 2**20, (kind, m.temp_size_in_bytes)  # the Normalizer's norms: 128 MiB
+    stores = [(d, c.memory_analysis()) for k, d, c in programs_of_the_eager_path[fits:] if k == "cast_store"]
+    assert len(stores) == (1 if name == "Normalizer" else 2)
+    for donated, m in stores:
+        assert donated and m.alias_size_in_bytes == m.output_size_in_bytes == TABLE_BYTES
+        assert TABLE_BYTES <= m.argument_size_in_bytes < TABLE_BYTES + 2**28
+    # the fits read the table and write a row of statistics
+    for kind, donated, compiled in programs_of_the_eager_path[:fits]:
+        m = compiled.memory_analysis()
+        assert not donated and m.argument_size_in_bytes == TABLE_BYTES and m.output_size_in_bytes <= 4096
+
+
+def _selection(rows, q=(25.0, 50.0, 75.0)):
+    pos = np.asarray(q) / 100.0 * (rows - 1)
+    lows = tuple(int(v) for v in np.floor(pos))
+    return dict(axis=0, lows=lows, with_high=True, plan=tuple((i, True, float(p - np.floor(p))) for i, p in enumerate(pos)),
+                method="linear", keepdims=False, scalar_q=False, n_true=rows)
+
+
+def test_robust_scaler_fit_selects_without_a_sorted_copy(one_chip, for_the_chip):
+    """``RobustScaler.fit``'s one program at 2^25 x 50: no ``sort``, the
+    table in its argument layout and read once a pass (16 counting passes of
+    2 bits, and the upper neighbours' count and minimum: 18 reads), under
+    64 MiB beside it.  ``jnp.percentile`` of the same table is refused by the
+    compiler for 21 GB."""
+    from heat_tpu.core import statistics
+
+    compiled = statistics._select_program.lower(
+        _sds((TABLE_ROWS, TABLE_COLS), jnp.float32, one_chip), **_selection(TABLE_ROWS)).compile()
+    m = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert " sort(" not in text and "all-gather" not in text
+    _table_stays_as_laid_out(compiled)
+    assert m.argument_size_in_bytes == TABLE_BYTES and m.temp_size_in_bytes < 2**26 and m.output_size_in_bytes <= 4096
+    reads = [i for i in _entry_instructions(compiled) if "x.1" in i[3]]
+    assert len(reads) == statistics._select_passes(jnp.float32, 3, True) + 1 == 18, len(reads)
+    with pytest.raises(Exception, match="(?i)hbm|memory|RESOURCE_EXHAUSTED"):
+        jax.jit(lambda a: jnp.percentile(a, jnp.asarray([25.0, 50.0, 75.0]), axis=0)).lower(
+            _sds((TABLE_ROWS, TABLE_COLS), jnp.float32, one_chip)).compile()
+
+
+@pytest.mark.parametrize("pad", [0, 3], ids=["every_row_real", "three_pad_rows"])
+def test_selection_over_four_chips_exchanges_counts(comm4, for_the_chip, pad):
+    """Rows split over four chips: a counting pass holds ONE all-reduce, of a
+    (ranks x pivots x columns) array of counts, and the last pass one of
+    counts and one of minima; nothing is gathered or sorted across chips."""
+    from heat_tpu.core import statistics
+
+    rows = 2**24
+    args = dict(_selection(rows - pad))
+    program = statistics._select_program_split(comm4, *args.values(), pad > 0)
+    compiled = program.lower(_sds((rows, TABLE_COLS), jnp.float32, comm4.sharding(0))).compile()
+    text = compiled.as_text()
+    assert "all-gather" not in text and " sort(" not in text and "all-to-all" not in text
+    _table_stays_as_laid_out(compiled, rows // 4)
+    reduced = [re.sub(r"\{[^}]*\}", "", r) for r in re.findall(r"= (.*?) all-reduce(?:-start)?\(", text)]
+    passes = statistics._select_passes(jnp.float32, 3, True)
+    assert len(reduced) == passes + 1, reduced
+    # three ranks x three pivots; the first pass counts the NaNs too
+    assert reduced[0] == f"s32[10,1,{TABLE_COLS}]" and set(reduced[1:passes - 1]) == {f"s32[3,3,1,{TABLE_COLS}]"}, reduced[:3]
+    assert sorted(reduced[-2:]) == [f"s32[3,1,{TABLE_COLS}]", f"u32[3,1,{TABLE_COLS}]"], reduced[-2:]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**26

@@ -1,0 +1,158 @@
+"""``statistics.percentile`` / ``median`` along one long axis: exact selection
+by counting passes, no sorted copy (PR 33).
+
+The select route is held to ``numpy.percentile`` in float64 (every
+``interpolation`` kind, several ``q`` at once, both ends, every axis, split
+and not) and, where numpy and ``jax.numpy`` differ (infinities under a zero
+weight, the tie of ``nearest``), to ``jnp.percentile``, whose rule it keeps.
+The route is decided from the extent along the axis; the tests steer the
+threshold down so that small arrays take it.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import heat_tpu as ht
+from heat_tpu import telemetry
+from heat_tpu.core import statistics
+
+KINDS = ("linear", "lower", "higher", "midpoint", "nearest")
+
+
+@pytest.fixture()
+def select_from_8(monkeypatch):
+    monkeypatch.setattr(statistics, "_SELECT_MIN_EXTENT", 8)
+
+
+def _route_of(call):
+    """The ``route`` of the ``statistics.quantiles`` span the call leaves."""
+    prev = telemetry.set_tracing(True)
+    try:
+        telemetry.clear_spans()
+        out = call()
+        spans = [r for r in telemetry.get_spans() if r.name == "statistics.quantiles"]
+    finally:
+        telemetry.set_tracing(prev)
+        telemetry.clear_spans()
+    return out, [r.attrs for r in spans]
+
+
+def _values(dtype=np.float32):
+    """Negative values, duplicates, a tie across the quartiles, both zeros,
+    both infinities; odd rows, so that the quartiles interpolate."""
+    rng = np.random.default_rng(3)
+    a = (rng.standard_normal((1003, 7)) * [1, 10, 0.1, 1, 1, 1, 100] - [0, 5, 0, 0, 0, 0, 0]).astype(dtype)
+    a[:, 1] = np.round(a[:, 1])          # duplicates
+    a[:600, 3] = 1.5                     # a tie that holds a quartile and the median
+    a[10:20, 4], a[20:30, 4] = 0.0, -0.0
+    a[5, 5], a[7, 5], a[9, 5] = np.inf, -np.inf, np.inf
+    return a
+
+
+@pytest.mark.parametrize("split", [None, 0, 1])
+@pytest.mark.parametrize("kind", KINDS)
+def test_selection_against_numpy(select_from_8, kind, split):
+    """Several ``q`` at once, ``q`` 0 and 100, along both axes of a 2-D array.
+    ``nearest`` is compared where no rank ties at a half (numpy rounds such a
+    tie to even, ``jnp`` down); column 5 holds infinities and is compared
+    with ``jnp.percentile`` below."""
+    a = _values()
+    x = ht.array(a, split=split)
+    q = [0.0, 12.5, 33.3, 50.0, 99.9, 100.0] if kind == "nearest" else [0.0, 25.0, 50.0, 75.0, 99.9, 100.0]
+    for axis in (0, 1):
+        keep = [c for c in range(7) if c != 5] if axis == 0 else slice(None)
+        b = a[:, keep] if axis == 0 else a[:, :5]
+        y = ht.array(b, split=split)
+        (got, spans) = _route_of(lambda: ht.percentile(y, q, axis=axis, interpolation=kind))
+        assert [s["route"] for s in spans] == ["select" if b.shape[axis] >= 8 else "sort"]
+        want = np.percentile(b.astype(np.float64), q, axis=axis, method=kind)
+        assert got.shape == want.shape and got.dtype == ht.float32
+        np.testing.assert_allclose(got.numpy(), want, rtol=1e-6, atol=1e-6)
+    assert x.shape == a.shape
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("split", [None, 0])
+def test_special_values_as_jnp_percentile(select_from_8, kind, split):
+    """NaN, the zeros, infinities and ties give what ``jnp.percentile`` gives,
+    bit for bit where it gives a number."""
+    a = _values()
+    a[3, 6] = np.nan  # a NaN anywhere makes the column's quantiles NaN
+    q = np.asarray([0.0, 25.0, 50.0, 75.0, 100.0])
+    got = ht.percentile(ht.array(a, split=split), q, axis=0, interpolation=kind).numpy()
+    want = np.asarray(jnp.percentile(jnp.asarray(a), q, axis=0, method=kind))
+    assert np.all(np.isnan(got[:, 6])) and np.array_equal(got, want, equal_nan=True)
+
+
+@pytest.mark.parametrize("split", [None, 0, 1, 2])
+@pytest.mark.parametrize("axis", [0, 1, 2])
+def test_an_axis_of_a_3d_array(select_from_8, axis, split):
+    b = np.random.default_rng(9).standard_normal((40, 9, 33))
+    got, spans = _route_of(lambda: ht.percentile(ht.array(b, split=split), [10.0, 50.0], axis=axis, keepdims=True))
+    assert spans[0]["route"] == "select" and spans[0]["passes"] == 33  # float64: 64 bits, 2 a pass, and the neighbours'
+    np.testing.assert_allclose(got.numpy(), np.percentile(b, [10.0, 50.0], axis=axis, keepdims=True), rtol=1e-12)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64", "bfloat16", "int32"])
+def test_input_types(select_from_8, dtype):
+    """Narrow floats select on their float32 image (exact), integers are cast
+    as they were before, float64 takes a 64-bit key."""
+    rng = np.random.default_rng(1)
+    a = rng.integers(-50, 50, (257, 3)) if dtype == "int32" else rng.standard_normal((257, 3))
+    x = ht.array(a, split=0).astype(getattr(ht, dtype))
+    got = ht.median(x, axis=0).numpy().astype(np.float64)
+    want = np.median(np.asarray(x.numpy(), np.float64), axis=0)
+    np.testing.assert_allclose(got, want, rtol=1e-2 if dtype == "bfloat16" else 1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("q,passes", [(50.0, 11), ([25.0, 75.0], 17), ([25.0, 50.0, 75.0], 17), ([10, 20, 30, 40, 50], 33),
+                                       ([0.0, 100.0], 16)])
+def test_all_q_in_one_computation(select_from_8, q, passes):
+    """One span, one launch, however many ``q``; the passes follow the number
+    of ranks (3 bits a pass for one rank, 2 for up to four, then 1), plus one
+    for the upper neighbours where a rank interpolates."""
+    x = ht.array(np.random.default_rng(2).standard_normal((1003, 4)).astype(np.float32), split=0)
+    got, spans = _route_of(lambda: ht.percentile(x, q, axis=0))
+    assert len(spans) == 1 and spans[0]["launches"] == 1 and spans[0]["passes"] == passes
+    np.testing.assert_allclose(got.numpy(), np.percentile(x.numpy().astype(np.float64), q, axis=0), rtol=1e-6, atol=1e-6)
+
+
+def test_robust_scaler_asks_once(select_from_8):
+    x = ht.array(np.random.default_rng(4).standard_normal((200, 5)).astype(np.float32), split=0)
+    _, spans = _route_of(lambda: ht.preprocessing.RobustScaler().fit(x))
+    assert len(spans) == 1 and spans[0]["q"] == (50.0, 25.0, 75.0)
+
+
+@pytest.mark.parametrize("case", ["short_axis", "flattened", "two_axes", "sketched"])
+def test_the_sort_route_returns_what_it_returned(case):
+    """Below the threshold, over several axes, flattened or sketched, the
+    call is ``jnp.percentile`` as before, result for result."""
+    a = np.random.default_rng(6).standard_normal((300, 6)).astype(np.float32)
+    x = ht.array(a, split=0)
+    kwargs = {"short_axis": dict(axis=0), "flattened": dict(axis=None), "two_axes": dict(axis=(0, 1)),
+              "sketched": dict(axis=0, sketched=True, sketch_size=300)}[case]
+    got, spans = _route_of(lambda: ht.percentile(x, [30.0, 60.0], **kwargs))
+    assert [s["route"] for s in spans] == ["sort"]
+    if case != "sketched":
+        want = jnp.percentile(jnp.asarray(a), jnp.asarray([30.0, 60.0]), axis=kwargs["axis"])
+        assert np.array_equal(got.numpy(), np.asarray(want))
+    assert statistics._SELECT_MIN_EXTENT == 1 << 14
+
+
+def test_a_long_axis_selects_by_itself():
+    """No steering: from 2^14 elements on the route is ``select``."""
+    a = np.random.default_rng(8).standard_normal((1 << 14, 2)).astype(np.float32)
+    got, spans = _route_of(lambda: ht.percentile(ht.array(a, split=0), [25.0, 50.0, 75.0], axis=0))
+    assert spans[0]["route"] == "select" and spans[0]["passes"] == 17
+    np.testing.assert_allclose(got.numpy(), np.percentile(a.astype(np.float64), [25, 50, 75], axis=0), rtol=1e-6, atol=1e-7)
+
+
+def test_out_of_range_q_still_raises():
+    x = ht.array(np.zeros((20000, 2), np.float32), split=0)
+    with pytest.raises(ValueError):
+        ht.percentile(x, 101.0, axis=0)
+    with pytest.raises(ValueError, match="interpolation"):
+        ht.percentile(x, 50.0, axis=0, interpolation="cubic")
+    assert np.array_equal(ht.percentile(x, 50.0, axis=0).numpy(), np.zeros(2, np.float32))
