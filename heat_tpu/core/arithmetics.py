@@ -290,7 +290,9 @@ def sum(a, axis=None, out=None, keepdims=False):
 # backing array with a cast-safety check (dndarray._iop).  Under the
 # dispatch layer the out-of-place result is a PENDING chain, so `a += b`
 # compiles as one cached executable whose output aliases a's donated
-# backing buffer when it is provably unshared (core/dispatch.cast_store).
+# backing buffer when it is provably unshared (core/dispatch.cast_store);
+# where the chain reads no other buffer of a's size (`a *= 2`) it waits,
+# with whatever follows it, for a's first reader (dispatch.defer_store).
 # ----------------------------------------------------------------------
 from .dndarray import _iop as __iop  # noqa: E402
 

@@ -34,7 +34,11 @@ without any user opt-in:
    (:func:`_refcount_at_most`): two DNDarrays sharing a backing array, a
    pending expression holding the buffer as a leaf, or a user-held
    ``larray_padded`` reference all suppress donation (donating a shared
-   buffer would poison every other holder).
+   buffer would poison every other holder).  An in-place store whose
+   chain reads the target's buffer and no other of its size does not run
+   where it is asked for: the target takes the chain
+   (:func:`defer_store`) and its first reader runs all that has gathered
+   as one donating store.
 
 Environment knobs (all default-on):
 
@@ -97,6 +101,7 @@ __all__ = [
     "clear_cache",
     "cost_accounting_enabled",
     "cost_summary",
+    "defer_store",
     "eager_apply",
     "fusion_enabled",
     "make_node",
@@ -135,7 +140,8 @@ def fusion_enabled() -> bool:
 # byte-compatible view over them.
 # ----------------------------------------------------------------------
 _COUNTER_NAMES = ("hits", "misses", "dispatches", "fused_ops", "donations",
-                  "external_dispatches", "compile_fallbacks", "stores")
+                  "external_dispatches", "compile_fallbacks", "stores",
+                  "deferred_stores")
 _C = {n: _tm.counter(f"dispatch.{n}") for n in _COUNTER_NAMES}
 
 #: per-compile wall time (jit trace + XLA compile + first execution of a
@@ -182,7 +188,10 @@ def cache_stats() -> dict:
     ``stores`` the in-place stores asked of :func:`cast_store` (``a += b``,
     ``out=``, a scaler's ``copy=False``): ``stores - donations`` of a
     region that only stores is the number of them that wrote a second
-    buffer.
+    buffer.  ``deferred_stores`` counts the in-place stores that did not
+    run where they were asked for (:func:`defer_store`): each is folded
+    into the one store that its target's first reader runs, and steps
+    ``stores`` there, once for all of them.
     ``external_dispatches`` are launches recorded by consumers with their
     own jitted programs (kmeans' Lloyd loop, lasso's CD loop,
     ``fusion.jit``).  ``compile_fallbacks`` counts compiled executions
@@ -550,6 +559,18 @@ def scalar_leaf(value, dtype):
             _scalar_cache.clear()
         _scalar_cache[key] = buf
     return buf
+
+
+def _stored(a, one, *, dtype):
+    """``a`` as a store into a ``dtype`` buffer would have left it: cast,
+    and rounded THERE.  Inside one program the compiler rewrites across
+    what used to be two (``(x / a) / b`` becomes ``x / (a * b)``; a product
+    and the sum behind it contract into one rounding), and a deferred store
+    must not change a bit of what the stores would have written one by one.
+    ``one`` is a 1 the compiler cannot know (a run-time scalar): the
+    product changes no value, stands between the patterns, and absorbs a
+    contraction (``fma(t, 1, b)`` is ``t + b`` rounded once, as stored)."""
+    return a.astype(dtype) * one
 
 
 def cast_node(x, dtype) -> Optional[PendingExpr]:
@@ -1084,6 +1105,36 @@ def repad(buf, old_slice, pad_widths, sharding, donate: bool = False):
     if fresh:
         _maybe_analyze(compiled, (buf,), key, donate_argnums=(0,))
     return _run(compiled, (buf,), 1, donated=True, fresh=fresh, key=key)
+
+
+def defer_store(dst_buf, src, dtype) -> Optional[PendingExpr]:
+    """The chain an in-place target takes in place of its buffer, the
+    cast to ``dtype`` folded in, where the store ``dst <- src`` can wait
+    for the target's first reader; None where it has to run now.
+
+    It can wait where ``src`` is a pending chain under the depth limit
+    that reads ``dst_buf`` and no other buffer of its shape: the chain
+    then keeps nothing alive that the store would have released (in
+    ``x += y`` with a full-size ``y`` it would keep ``y``), and a later
+    in-place operation on the same target grows the chain instead of
+    making another pass over the buffer.  Nothing is launched here; the
+    target runs the chain through :func:`cast_store` when it is read."""
+    if (
+        dst_buf is None
+        or not fusion_enabled()
+        or not isinstance(src, PendingExpr)
+        or src.depth >= FUSION_DEPTH
+    ):
+        return None
+    _, leaves, _ = _linearize(src)
+    if [leaf is dst_buf for leaf in leaves if tuple(leaf.shape) == src.shape] != [True]:
+        return None
+    _C["deferred_stores"].inc()
+    # no make_node: the shape is the chain's, and what marks a store is no
+    # operation of the user's, so the chain is no deeper for it
+    dtype = jnp.dtype(dtype)
+    return PendingExpr(_stored, (src, scalar_leaf(1, dtype)), {"dtype": dtype}, src.shape,
+                       dtype, src.depth, src.nops + 1)
 
 
 def cast_store(dst_buf, src, dtype, out_sharding=None):

@@ -90,6 +90,9 @@ class DNDarray:
         self.__array = array
         self.__planar = planar
         self.__pending = pending
+        # the buffer a deferred in-place store (``_defer_store``) will be
+        # written into; set only beside the pending chain that is its value
+        self.__deferred: Optional[jax.Array] = None
         self.__gshape = tuple(int(s) for s in gshape)
         self.__dtype = types.canonical_heat_type(dtype)
         self.__split = split
@@ -188,8 +191,33 @@ class DNDarray:
         """The concrete padded backing buffer for donation accounting
         (None when planar- or pending-backed: nothing donatable).  Pass
         the result straight into the donating call — binding it to an
-        extra local would defeat the refcount proof."""
+        extra local would defeat the refcount proof.
+
+        The caller's store replaces this array's value, so a deferred
+        store need not run on its own: the array falls back on its
+        buffer and lets the chain go (where the new store's source was
+        built on this array the chain is part of it, and runs there)."""
+        if self.__deferred is not None:
+            self._replace(self.__deferred)
         return self.__array
+
+    def _defer_store(self, result: "DNDarray", jdt) -> bool:
+        """Take ``result``'s pending chain as this array's value in place
+        of running the in-place store now (``_iop``); False where
+        :func:`dispatch.defer_store` says that it has to run.  The buffer
+        stays with the array as the deferred store's target, which
+        :attr:`larray_padded` runs at the first read."""
+        chain = _dispatch.defer_store(
+            self.__array if self.__pending is None else self.__deferred, result._pending, jdt
+        )
+        if chain is None:
+            return False
+        if self.__pending is None:
+            self.__deferred = self.__array
+        self.__array = None
+        self.__pending = chain
+        self.__ragged_buffer = None
+        return True
 
     def __materialize_planar(self) -> jax.Array:
         re, im = self.__planar
@@ -206,6 +234,7 @@ class DNDarray:
         self.__array = padded
         self.__planar = None
         self.__pending = None
+        self.__deferred = None
         self.__ragged_buffer = None
 
     def _replace_local(self, local: jax.Array) -> None:
@@ -220,6 +249,7 @@ class DNDarray:
         padded_gshape = self._padded_shape  # planar-safe (read before nulling)
         self.__planar = None
         self.__pending = None
+        self.__deferred = None
         self.__target_map = None
         self.__ragged_buffer = None
         if jax.process_count() == 1:
@@ -264,12 +294,19 @@ class DNDarray:
         boundary: a pending elementwise chain compiles and runs here as
         one cached executable (reductions, collectives, indexing,
         printing, and host reads all funnel through this property);
-        planar planes materialize here too."""
+        planar planes materialize here too.  A deferred in-place store
+        runs here as that one program, through the donating store: its
+        output aliases the array's old buffer where that is unshared."""
         if self.__array is None:
             if self.__pending is not None:
-                self.__array = _dispatch.materialize(
-                    self.__pending, self.__comm.sharding(self.__split)
-                )
+                sharding = self.__comm.sharding(self.__split)
+                if self.__deferred is not None:
+                    self.__array = _dispatch.cast_store(
+                        self.__deferred, self.__pending, self.__dtype.jax_type(), sharding
+                    )
+                    self.__deferred = None
+                else:
+                    self.__array = _dispatch.materialize(self.__pending, sharding)
                 self.__pending = None
             else:
                 self.__array = self.__materialize_planar()
@@ -1478,12 +1515,20 @@ def _iop(self: DNDarray, result: DNDarray) -> DNDarray:
         raise TypeError(f"cannot cast {result.dtype} back to {self.dtype} for in-place operation")
     if result.split != self.split:
         result = result.resplit(self.split)
+    if result is self:
+        return self
     jdt = self.dtype.jax_type()
     if (
         result._planar is None
         and not jnp.issubdtype(jdt, jnp.complexfloating)
         and result._padded_shape == self._padded_shape
     ):
+        # nobody has asked for the values yet: where the chain reads this
+        # array's buffer and no other of its size, the array takes the
+        # chain and the store waits for its first reader (larray_padded),
+        # so `x -= m; x /= s; x *= s` is one pass over x and not three
+        if self._defer_store(result, jdt):
+            return self
         # one cached executable: the pending chain (if any) + the cast,
         # donating this array's dead backing buffer when unshared — the
         # `a += b` path aliases a's buffer to the output

@@ -6,15 +6,17 @@ ops layer (mean/var/min/max/percentile over the sharded sample axis).
 ``copy`` means what upstream means by it.  With ``copy=True`` (the default)
 ``transform`` / ``inverse_transform`` / ``fit_transform`` return a new array
 and leave their input untouched.  With ``copy=False`` they build the same
-chain (``(x - mean) / scale``), store it ONCE into the input through the
-library's one in-place store (``dndarray._iop`` -> ``dispatch.cast_store``:
-one program, one read and one write of the table, the input's buffer donated
-where it is provably unshared) and return that same object, so a table that
-fills the chip is scaled without a second generation of it.  Where the buffer
-is shared (a second ``DNDarray`` on it, a held ``larray_padded``) the result
-is still right and the span's ``inplace`` says that no donation happened.  An
-input that has to be cast first (integers) cannot be written in place: the
-store's cast check raises ``TypeError``, as upstream's does.
+chain (``(x - mean) / scale``), hand it to the input through the library's
+one in-place store (``dndarray._iop``) and return that same object.  The
+store waits for the input's first reader (``docs/dispatch.md``, "The deferred
+in-place store"): a transform and its inverse, or several scalers in a row,
+are then ONE program, one read and one write of the table, the input's buffer
+donated where it is provably unshared (``dispatch.cast_store``), so a table
+that fills the chip is scaled without a second generation of it.  Where the
+buffer is shared (a second ``DNDarray`` on it, a held ``larray_padded``) the
+result is still right and the store does not donate.  An input that has to be
+cast first (integers) cannot be written in place: the store's cast check
+raises ``TypeError`` at the call, as upstream's does.
 
 Every ``fit`` / ``transform`` / ``inverse_transform`` is one root span
 ``ht.preprocessing.<Class>.<method>`` (``docs/observability.md``).
@@ -59,8 +61,10 @@ def _guard_zero(x: DNDarray) -> DNDarray:
 def _root(scaler, method: str, x):
     """The root span of one scaler call and the scope its programs are
     traced under.  Set at exit: ``launches`` (programs enqueued),
-    ``stores`` (in-place stores), ``donations`` (stores that took the
-    input's buffer) and ``inplace`` (every store did, and there was one)."""
+    ``stores`` (in-place stores that ran inside the call), ``donations``
+    (of them, those that took the buffer), ``deferred`` (in-place stores
+    handed to the input to run at its first read) and ``inplace`` (there
+    was a store or a deferral, and every store that ran donated)."""
     before = dispatch.cache_stats()
     shape = getattr(x, "shape", ())
     with _span(f"ht.preprocessing.{type(scaler).__name__}.{method}",
@@ -69,10 +73,11 @@ def _root(scaler, method: str, x):
         with jax.named_scope("scaler.fit" if method == "fit" else "scaler.apply"):
             yield
         after = dispatch.cache_stats()
-        stores, donations = (after[k] - before[k] for k in ("stores", "donations"))
+        stores, donations, deferred = (after[k] - before[k] for k in ("stores", "donations", "deferred_stores"))
         sp.attrs.update(
             launches=sum(after[k] - before[k] for k in ("dispatches", "external_dispatches")),
-            stores=stores, donations=donations, inplace=0 < stores == donations)
+            stores=stores, donations=donations, deferred=deferred,
+            inplace=0 < stores + deferred and stores == donations)
 
 
 class _Scaler(BaseEstimator, TransformMixin):
@@ -80,7 +85,7 @@ class _Scaler(BaseEstimator, TransformMixin):
 
     def _result(self, x: DNDarray, y: DNDarray) -> DNDarray:
         """``y`` itself, or with ``copy=False`` the input holding it: ONE
-        in-place store of the pending chain."""
+        in-place store of the pending chain, which waits for its reader."""
         return y if self.copy else _iop(x, y)
 
     def fit(self, x: DNDarray, *args, **kwargs):

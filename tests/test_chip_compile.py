@@ -442,8 +442,9 @@ def _table_stays_as_laid_out(compiled, rows=TABLE_ROWS):
 
 @pytest.mark.parametrize("name", ["StandardScaler", "MinMaxScaler", "MaxAbsScaler", "RobustScaler", "Normalizer"])
 def test_scalers_write_the_table_in_place(topo, for_the_chip, programs_of_the_eager_path, name):
-    """At the benchmark cell's size every ``copy=False`` transform and inverse
-    is ONE program that takes the table as a donated argument and aliases its
+    """At the benchmark cell's size a ``copy=False`` transform and its inverse
+    launch nothing (PR 34: their stores wait), and the read that ends them is
+    ONE program that takes the table as a donated argument and aliases its
     output to it, with no temporary of the table's size: a scaler that wrote
     a second generation, 15.03 GB, would not fit beside anything."""
     import heat_tpu as ht
@@ -460,19 +461,64 @@ def test_scalers_write_the_table_in_place(topo, for_the_chip, programs_of_the_ea
     assert scaler.transform(x) is x
     if name != "Normalizer":
         assert scaler.inverse_transform(x) is x
+    reads = len(programs_of_the_eager_path)  # the Normalizer's row sums, through the chain that waits
+    assert reads - fits == (1 if name == "Normalizer" else 0)
+    x.larray_padded
     for kind, donated, compiled in programs_of_the_eager_path:
         m = compiled.memory_analysis()
         _table_stays_as_laid_out(compiled)
         assert m.temp_size_in_bytes <= 2**27 + 2**20, (kind, m.temp_size_in_bytes)  # the Normalizer's norms: 128 MiB
-    stores = [(d, c.memory_analysis()) for k, d, c in programs_of_the_eager_path[fits:] if k == "cast_store"]
-    assert len(stores) == (1 if name == "Normalizer" else 2)
-    for donated, m in stores:
-        assert donated and m.alias_size_in_bytes == m.output_size_in_bytes == TABLE_BYTES
-        assert TABLE_BYTES <= m.argument_size_in_bytes < TABLE_BYTES + 2**28
+    (kind, donated, compiled), = programs_of_the_eager_path[reads:]
+    m = compiled.memory_analysis()
+    assert kind == "cast_store" and donated and m.alias_size_in_bytes == m.output_size_in_bytes == TABLE_BYTES
+    assert TABLE_BYTES <= m.argument_size_in_bytes < TABLE_BYTES + 2**28
     # the fits read the table and write a row of statistics
     for kind, donated, compiled in programs_of_the_eager_path[:fits]:
         m = compiled.memory_analysis()
         assert not donated and m.argument_size_in_bytes == TABLE_BYTES and m.output_size_in_bytes <= 4096
+
+
+def test_the_store_robust_scaler_forces_is_one_donating_program(topo, for_the_chip, programs_of_the_eager_path):
+    """The benchmark's solve up to ``RobustScaler.fit``: six in-place calls
+    launch nothing; ``MinMaxScaler.fit`` and ``MaxAbsScaler.fit`` read the
+    table THROUGH the waiting chain (four and eight operations deep) and
+    write a row of 512 B and no table; the read that the selection forces
+    runs the ten operations as one store: the table a donated argument of
+    7,516,192,768 B, the output aliased to it, 0 B of temporaries, one
+    fusion, and every division still there (the simplifier would make
+    ``(x / a) / b`` ``x / (a * b)`` across MinMax's inverse and MaxAbs's
+    transform: ``dispatch._stored`` stands between)."""
+    import heat_tpu as ht
+    from heat_tpu.parallel.comm import Communication
+
+    comm = Communication([topo.devices[0]])
+    x = _on_shapes(comm, (TABLE_ROWS, TABLE_COLS), 0)
+    through = {}
+    for name in ("StandardScaler", "MinMaxScaler", "MaxAbsScaler"):
+        scaler = getattr(ht.preprocessing, name)(copy=False)
+        before = len(programs_of_the_eager_path)
+        scaler.fit(x)
+        through[name] = programs_of_the_eager_path[before:]
+        before = len(programs_of_the_eager_path)
+        assert scaler.inverse_transform(scaler.transform(x)) is x and len(programs_of_the_eager_path) == before
+    assert [len(v) for v in through.values()] == [2, 2, 1]
+    for name, fits in through.items():
+        for kind, donated, compiled in fits:
+            m = compiled.memory_analysis()
+            _table_stays_as_laid_out(compiled)
+            assert not donated and m.output_size_in_bytes <= 512 + 8 and m.temp_size_in_bytes < 2**20, (name, m)
+            assert TABLE_BYTES <= m.argument_size_in_bytes < TABLE_BYTES + 2**16
+    before = len(programs_of_the_eager_path)
+    x.larray_padded  # what `statistics.percentile` does first
+    (kind, donated, compiled), = programs_of_the_eager_path[before:]
+    m = compiled.memory_analysis()
+    _table_stays_as_laid_out(compiled)
+    assert kind == "cast_store" and donated and m.alias_size_in_bytes == m.output_size_in_bytes == TABLE_BYTES
+    assert TABLE_BYTES <= m.argument_size_in_bytes < TABLE_BYTES + 2**16 and m.temp_size_in_bytes == 0
+    text = compiled.as_text()
+    table = f"f32[{TABLE_ROWS},{TABLE_COLS}]"
+    assert len(re.findall(rf"= {re.escape(table)}\S* fusion\(", text)) == 1
+    assert len(re.findall(rf"= {re.escape(table)}\S* divide\(", text)) == 3  # by sqrt(var_), scale_ and scale_
 
 
 def _selection(rows, q=(25.0, 50.0, 75.0)):

@@ -167,7 +167,8 @@ def _live_count() -> int:
 @pytest.mark.parametrize("name", SCALERS)
 def test_copy_false_donates_the_old_buffer(name, split):
     """Proved as ``test_dispatch_donation.py`` proves it, without holding the
-    buffer: the stores are counted as donations, the population of live
+    buffer: every call defers its store (PR 34), the one store that the
+    caller's read runs is counted as a donation, the population of live
     device buffers does not grow, and the root span says ``inplace``."""
     if not dispatch._DONATE_ENABLED:
         pytest.skip("donation is off")
@@ -178,20 +179,24 @@ def test_copy_false_donates_the_old_buffer(name, split):
         y = scaler.fit_transform(x)
         return scaler.inverse_transform(y) if name != "Normalizer" else y
 
-    upstream()  # warm the executables
-    upstream()
+    for _ in range(2):  # warm the executables
+        upstream()
+        x.larray_padded
     prev = telemetry.set_tracing(True)
     try:
         telemetry.clear_spans()
         dispatch.reset_stats()
         before = _live_count()
         assert upstream() is x
-        stats = dispatch.cache_stats()
         calls = 1 if name == "Normalizer" else 2
-        assert stats["stores"] == calls and stats["donations"] == calls
+        assert dispatch.cache_stats()["stores"] == 0 and dispatch.cache_stats()["deferred_stores"] == calls
+        x.larray_padded
+        stats = dispatch.cache_stats()
+        assert stats["stores"] == stats["donations"] == 1 and stats["deferred_stores"] == calls
         assert _live_count() <= before
         applied = [r for r in telemetry.get_spans() if r.name.endswith(("transform",)) and r.name.startswith("ht.preprocessing")]
-        assert len(applied) == calls and all(r.attrs["inplace"] and r.attrs["stores"] == r.attrs["donations"] == 1 for r in applied)
+        assert len(applied) == calls and all(r.attrs["inplace"] and r.attrs["deferred"] == 1
+                                             and r.attrs["stores"] == r.attrs["donations"] == 0 for r in applied)
     finally:
         telemetry.set_tracing(prev)
         telemetry.clear_spans()
@@ -201,8 +206,9 @@ def test_copy_false_donates_the_old_buffer(name, split):
 @pytest.mark.parametrize("name", ["StandardScaler", "Normalizer"])
 def test_a_shared_buffer_is_not_donated(name, holder):
     """A second ``DNDarray`` on the buffer, or a held ``larray_padded``: the
-    result is still right, the holder stays readable and the span says that
-    no donation happened."""
+    result is still right, the holder stays readable and the store, where
+    the reader runs it, does not donate (the call's span can only say that
+    it deferred one)."""
     a = _table("constant")
     x = ht.array(a, split=0)
     scaler = getattr(ht.preprocessing, name)(copy=False).fit(x)
@@ -216,8 +222,10 @@ def test_a_shared_buffer_is_not_donated(name, holder):
     finally:
         telemetry.set_tracing(prev)
         telemetry.clear_spans()
-    assert span.attrs["stores"] == 1 and span.attrs["donations"] == 0 and span.attrs["inplace"] is False
+    assert span.attrs["deferred"] == 1 and span.attrs["stores"] == 0 and span.attrs["donations"] == 0
+    dispatch.reset_stats()
     np.testing.assert_allclose(x.numpy(), _numpy_scaled(name, a), rtol=3e-5, atol=3e-6)
+    assert dispatch.cache_stats()["stores"] == 1 and dispatch.cache_stats()["donations"] == 0
     assert np.array_equal(np.asarray(held)[:ROWS], a)
     if other is not None:
         assert np.array_equal(other.numpy(), a)

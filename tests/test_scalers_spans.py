@@ -48,27 +48,36 @@ def _upstream(x, copy):
 @pytest.mark.parametrize("copy", [False, True])
 def test_a_solve_leaves_its_root_spans(one_device, copy):
     """Fourteen root spans a solve, each with the table's shape and what the
-    call launched; the in-place ones say so, and no span is left a launch."""
+    call launched.  Since PR 34 an in-place call defers its store, so the
+    sums are a solve's and not a call's: nine deferrals, and the stores that
+    ran (``RobustScaler.fit``'s read inside its root, the caller's read after
+    the last) all donated."""
     x = ht.array(np.random.default_rng(0).standard_normal((600, 50)).astype(np.float32), split=0)
     _upstream(x, copy)  # warm
+    x.larray_padded
     telemetry.clear_spans()
     before = dispatch.cache_stats()
     _upstream(x, copy)
+    x.larray_padded  # the caller's read ends the solve, as the benchmark's `block_until_ready` does
     after = dispatch.cache_stats()
+    step = {k: after[k] - before[k] for k in ("stores", "donations", "deferred_stores", "dispatches", "external_dispatches")}
     spans = telemetry.get_spans()
     roots = [r for r in spans if r.name.startswith("ht.preprocessing.")]
     assert [r.name for r in roots] == CALLS
     assert all(r.depth == 0 and r.attrs["rows"] == 600 and r.attrs["features"] == 50 and r.attrs["split"] == 0
                and r.attrs["copy"] is copy for r in roots)
-    stores = sum(r.attrs["stores"] for r in roots)
-    assert stores == (0 if copy else 9) == after["stores"] - before["stores"]
-    assert sum(r.attrs["donations"] for r in roots) == stores
+    assert sum(r.attrs["deferred"] for r in roots) == (0 if copy else 9) == step["deferred_stores"]
+    assert step["stores"] == step["donations"] == (0 if copy else 2) and step["stores"] <= 3
+    forced = [r.name for r in roots if r.attrs["stores"]]
+    assert forced == ([] if copy else ["ht.preprocessing.RobustScaler.fit"])  # the last store runs outside every root
+    assert sum(r.attrs["donations"] for r in roots) == len(forced)
     for r in roots:
         applies = r.name.endswith("transform")
-        assert r.attrs["inplace"] is (applies and not copy), r
+        assert r.attrs["deferred"] == int(applies and not copy), r
+        assert r.attrs["inplace"] is (not copy and (applies or r.name in forced)), r
     launches = sum(r.attrs["launches"] for r in roots)
-    assert launches == sum(after[k] - before[k] for k in ("dispatches", "external_dispatches"))
-    assert launches >= stores + 6  # every store is a launch, and so is every fit's reduction
+    assert launches == step["dispatches"] + step["external_dispatches"] - (0 if copy else 1)
+    assert launches >= len(forced) + 6  # every store is a launch, and so is every fit's reduction
     assert len(spans) <= len(roots) + 3  # the quantiles' span and nothing a launch (the ring holds 4,096)
     inner = [r for r in spans if r.name == "statistics.quantiles"]
     fit = next(r for r in roots if r.name == "ht.preprocessing.RobustScaler.fit")
@@ -105,8 +114,10 @@ def test_the_programs_carry_their_scopes(one_device):
 
     assert "scaler.fit" in scoped(lambda t: scaler.fit(t).max_abs_)
     scaler.fit(x)
-    # the in-place store runs inside the call, under its scope (a copy=True chain is traced where it is first read)
-    assert "scaler.apply" in scoped(scaler.transform)
+    # a deferred store, like a copy=True chain, is traced where it is first read: by the caller, under no
+    # scope of the scalers, or inside the fit that reads it
+    assert "scaler.apply" not in scoped(scaler.transform)
+    assert "scaler.fit" in scoped(lambda t: ht.preprocessing.RobustScaler().fit(scaler.transform(t)).center_)
 
 
 # ------------------------------------------------------------------- the readers

@@ -193,7 +193,8 @@ class TestLegacyViews:
         s = dispatch.cache_stats()
         assert set(s) == {
             "hits", "misses", "dispatches", "fused_ops", "donations",
-            "external_dispatches", "compile_fallbacks", "stores", "hit_rate", "cache_size",
+            "external_dispatches", "compile_fallbacks", "stores", "deferred_stores", "hit_rate",
+            "cache_size",
         }
         before = s["external_dispatches"]
         dispatch.record_external_dispatch(5)
