@@ -734,12 +734,53 @@ def _get_compiled(key, builder, donate_argnums=None, out_sharding=None, leaves=N
     return entry, fresh
 
 
-def _run(compiled, leaves, n_ops: int, donated: bool = False, fresh: bool = False,
-         key=None):
+class _launch(_span):
+    """The one ``dispatch.launch`` span of a program that goes through
+    :func:`_run`: opened where an entrance (``kind``: ``expr``
+    :func:`materialize`, ``chain`` :func:`chain_apply`, ``apply``
+    :func:`eager_apply`, ``cast_store``, ``repad``) starts to decide
+    (linearize, key, cache lookup, donation proof) and ended by
+    :func:`_run` at the return of the enqueue, so its interval is this
+    layer's host time for the program and never the device's.  The span it
+    ran under is the innermost one open on the thread; at depth 0 the
+    program ran at a read outside every ``ht.*`` call.
+
+    Attributes, plain values only (a buffer, a leaf list or a chain held
+    here would be one holder more than the donation proof allows):
+    ``ops`` (operations fused into the program), ``fresh`` (a cache miss;
+    the ``dispatch.compile`` span lies inside), ``store`` (an in-place
+    store), ``donated`` (it took the target's buffer), ``folded`` (the
+    deferred stores it runs: the :func:`_stored` marks of its chain);
+    ``fallback`` where the work ran eagerly after all (the compile failed,
+    or the key cannot be hashed) and ``error``, the exception's type name,
+    where the launch raises."""
+
+    __slots__ = ()
+
+    def __init__(self, kind: str, ops: int = 0, store: bool = False):
+        super().__init__("dispatch.launch", kind=kind, ops=ops, fresh=False, store=store,
+                         donated=False, folded=0)
+
+    def end(self) -> None:
+        """Close the interval (:func:`_run`, behind the enqueue); the
+        entrance's ``with`` then has nothing left to close."""
+        _span.__exit__(self, None, None, None)
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        # a no-op after ``end``; before it the launch did not come to its
+        # enqueue, and the record says why
+        if exc_type is not None:
+            self.attrs["error"] = exc_type.__name__
+        return super().__exit__(exc_type, exc, tb)
+
+
+def _run(compiled, leaves, n_ops: int, sp: _launch, donated: bool = False,
+         fresh: bool = False, key=None):
     _C["dispatches"].inc()
     _C["fused_ops"].inc(n_ops)
     if donated:
         _C["donations"].inc()
+    sp.attrs.update(fresh=fresh, donated=donated)
 
     def call():
         if donated:
@@ -750,38 +791,40 @@ def _run(compiled, leaves, n_ops: int, donated: bool = False, fresh: bool = Fals
                 return compiled(*leaves)
         return compiled(*leaves)
 
-    if not fresh:
-        if key is not None and _obsv.armed():
-            # roofline observatory: every warm call is a measurement
-            # (monotonic enqueue time; every Nth per key is fenced
-            # inside note() so the sample measures device time)
-            t0 = time.perf_counter()
-            out = call()
-            _obsv.note(key, time.perf_counter() - t0, out)
-            _meter_note(key)
-            return out
-        out = call()
-        _meter_note(key)
-        return out
-    # cache miss: the first call traces + compiles; record the wall time
-    # so ``where did the compile time go?`` is answerable from telemetry
     t0 = time.perf_counter()
-    with _span("dispatch.compile", ops=n_ops):
+    if fresh:
+        # cache miss: the first call traces + compiles
+        with _span("dispatch.compile", ops=n_ops):
+            out = call()
+    else:
         out = call()
-    _COMPILE_MS.observe((time.perf_counter() - t0) * 1e3)
-    if _COST_ENABLED and key is not None:
-        # outside the timed window: the accounting re-lower must not
-        # inflate the compile_ms histogram it sits next to
-        with warnings.catch_warnings():
-            warnings.filterwarnings("ignore", message=".*[Dd]onat")
-            _record_cost(key, compiled, leaves)
+    dt = time.perf_counter() - t0
+    # the launch ends with the enqueue: what follows may wait for the
+    # device (every Nth note() of a key is fenced) or lower again (cost)
+    sp.end()
+    if fresh:
+        # record the wall time so ``where did the compile time go?`` is
+        # answerable from telemetry
+        _COMPILE_MS.observe(dt * 1e3)
+        if _COST_ENABLED and key is not None:
+            # outside the timed window: the accounting re-lower must not
+            # inflate the compile_ms histogram it sits next to
+            with warnings.catch_warnings():
+                warnings.filterwarnings("ignore", message=".*[Dd]onat")
+                _record_cost(key, compiled, leaves)
+    elif key is not None and _obsv.armed():
+        # roofline observatory: every warm call is a measurement
+        # (monotonic enqueue time; every Nth per key is fenced
+        # inside note() so the sample measures device time)
+        _obsv.note(key, dt, out)
     _meter_note(key)
     return out
 
 
-def _compiled_or_fallback(key, builder, leaves, n_ops, eager_fn, out_sharding=None):
+def _compiled_or_fallback(sp: _launch, key, builder, leaves, n_ops, eager_fn, out_sharding=None):
     """Run through the executable cache; on a trace/compile/run failure
-    fall back to ONE eager execution instead of crashing the op.
+    fall back to ONE eager execution instead of crashing the op, inside
+    the launch's span ``sp`` and marked ``fallback`` there.
 
     The broken cache entry is dropped so the next call re-attempts a
     compile (a transient compile failure — injected or an XLA hiccup —
@@ -797,7 +840,7 @@ def _compiled_or_fallback(key, builder, leaves, n_ops, eager_fn, out_sharding=No
         )
         if fresh:
             _maybe_analyze(compiled, leaves, key)
-        return _run(compiled, leaves, n_ops, fresh=fresh, key=key)
+        return _run(compiled, leaves, n_ops, sp, fresh=fresh, key=key)
     except (_PermanentFault, _ChecksumError):
         # non-retryable resilience faults must propagate — an eager
         # fallback here would SWALLOW a permanent failure the caller's
@@ -811,6 +854,7 @@ def _compiled_or_fallback(key, builder, leaves, n_ops, eager_fn, out_sharding=No
             # check: importing analysis here would cycle through core)
             raise
         _C["compile_fallbacks"].inc()
+        sp.attrs["fallback"] = True
         with _CACHE_LOCK:
             _tsan.note_access("dispatch.cache")
             _cache.pop(key, None)
@@ -852,17 +896,21 @@ def materialize(expr: PendingExpr, out_sharding=None):
     cache; returns the concrete jax.Array.  ``out_sharding`` (the array's
     canonical NamedSharding) pins the result placement the eager path
     used to establish with a per-op device_put."""
-    nodes, leaves, _ = _linearize(expr)
     if not _CACHE_ENABLED:
+        nodes, leaves, _ = _linearize(expr)
         return _eval_nodes(nodes, leaves)
-    try:
-        key = _program_key("expr", nodes, leaves, (out_sharding,))
-    except TypeError:
-        return _eval_nodes(nodes, leaves)
-    return _compiled_or_fallback(
-        key, lambda: _build_program(nodes), leaves, len(nodes),
-        lambda: _eval_nodes(nodes, leaves), out_sharding=out_sharding,
-    )
+    with _launch("expr") as sp:
+        nodes, leaves, _ = _linearize(expr)
+        sp.attrs["ops"] = len(nodes)
+        try:
+            key = _program_key("expr", nodes, leaves, (out_sharding,))
+        except TypeError:
+            sp.attrs["fallback"] = True
+            return _eval_nodes(nodes, leaves)
+        return _compiled_or_fallback(
+            sp, key, lambda: _build_program(nodes), leaves, len(nodes),
+            lambda: _eval_nodes(nodes, leaves), out_sharding=out_sharding,
+        )
 
 
 def eager_apply(op, args: Sequence, kwargs: Optional[dict] = None):
@@ -872,16 +920,18 @@ def eager_apply(op, args: Sequence, kwargs: Optional[dict] = None):
     kwargs = kwargs or {}
     if not _CACHE_ENABLED:
         return op(*args, **kwargs)
-    try:
-        key = ("apply", op, _kw_key(kwargs),
-               tuple(_leaf_spec(a) for a in args))
-        hash(key)
-    except TypeError:
-        return op(*args, **kwargs)
-    return _compiled_or_fallback(
-        key, lambda: (lambda *a: op(*a, **kwargs)), args, 1,
-        lambda: op(*args, **kwargs),
-    )
+    with _launch("apply", ops=1) as sp:
+        try:
+            key = ("apply", op, _kw_key(kwargs),
+                   tuple(_leaf_spec(a) for a in args))
+            hash(key)
+        except TypeError:
+            sp.attrs["fallback"] = True
+            return op(*args, **kwargs)
+        return _compiled_or_fallback(
+            sp, key, lambda: (lambda *a: op(*a, **kwargs)), args, 1,
+            lambda: op(*args, **kwargs),
+        )
 
 
 def chain_apply(op, x, kwargs: Optional[dict] = None, mask=None):
@@ -893,7 +943,25 @@ def chain_apply(op, x, kwargs: Optional[dict] = None, mask=None):
     ``mask``: None, or ``(split, true_extent, neutral)`` — the padding
     rows are overwritten with ``neutral`` before ``op`` (the fused analog
     of ``DNDarray._masked``)."""
-    kwargs = dict(kwargs or {})
+    if not _CACHE_ENABLED:
+        return _eval_nodes(*_chain_nodes(op, x, kwargs, mask))
+    with _launch("chain") as sp:
+        nodes, leaves = _chain_nodes(op, x, kwargs, mask)
+        sp.attrs["ops"] = len(nodes)
+        try:
+            key = _program_key("chain", nodes, leaves)
+        except TypeError:
+            sp.attrs["fallback"] = True
+            return _eval_nodes(nodes, leaves)
+        return _compiled_or_fallback(
+            sp, key, lambda: _build_program(nodes), leaves, len(nodes),
+            lambda: _eval_nodes(nodes, leaves),
+        )
+
+
+def _chain_nodes(op, x, kwargs, mask):
+    """``(nodes, leaves)`` of :func:`chain_apply`'s one program: the chain,
+    the pad-masking, the op."""
     if isinstance(x, PendingExpr):
         nodes, leaves, _ = _linearize(x)
         root = (True, len(nodes) - 1)
@@ -906,17 +974,8 @@ def chain_apply(op, x, kwargs: Optional[dict] = None, mask=None):
                       {"split": int(split), "extent": int(extent), "neutral": neutral},
                       (root,)))
         root = (True, len(nodes) - 1)
-    nodes.append((op, kwargs, (root,)))
-    if not _CACHE_ENABLED:
-        return _eval_nodes(nodes, leaves)
-    try:
-        key = _program_key("chain", nodes, leaves)
-    except TypeError:
-        return _eval_nodes(nodes, leaves)
-    return _compiled_or_fallback(
-        key, lambda: _build_program(nodes), leaves, len(nodes),
-        lambda: _eval_nodes(nodes, leaves),
-    )
+    nodes.append((op, dict(kwargs or {}), (root,)))
+    return nodes, leaves
 
 
 # ----------------------------------------------------------------------
@@ -1071,7 +1130,6 @@ def repad(buf, old_slice, pad_widths, sharding, donate: bool = False):
     (the array's dead backing buffer) when a refcount proof shows it is
     unshared.  Call with the buffer in argument position (no extra local
     bindings) so the calibrated refcount accounting holds."""
-    donate = donate and _refcount_at_most(buf)
     if pad_widths is not None:
         pad_widths = tuple((int(a), int(b)) for a, b in pad_widths)
         if not any(b for _, b in pad_widths) and not any(a for a, _ in pad_widths):
@@ -1091,20 +1149,23 @@ def repad(buf, old_slice, pad_widths, sharding, donate: bool = False):
 
     if not _CACHE_ENABLED:
         return jax.device_put(build()(buf), sharding)
-    try:
-        key = ("repad", _leaf_spec(buf), old_slice, pad_widths, sharding, donate)
-        hash(key)
-    except TypeError:
-        return jax.device_put(build()(buf), sharding)
-    if not donate:
-        return _compiled_or_fallback(
-            key, build, (buf,), 1,
-            lambda: jax.device_put(build()(buf), sharding), out_sharding=sharding,
-        )
-    compiled, fresh = _get_compiled(key, build, donate_argnums=(0,), out_sharding=sharding)
-    if fresh:
-        _maybe_analyze(compiled, (buf,), key, donate_argnums=(0,))
-    return _run(compiled, (buf,), 1, donated=True, fresh=fresh, key=key)
+    with _launch("repad", ops=1) as sp:
+        donate = donate and _refcount_at_most(buf)
+        try:
+            key = ("repad", _leaf_spec(buf), old_slice, pad_widths, sharding, donate)
+            hash(key)
+        except TypeError:
+            sp.attrs["fallback"] = True
+            return jax.device_put(build()(buf), sharding)
+        if not donate:
+            return _compiled_or_fallback(
+                sp, key, build, (buf,), 1,
+                lambda: jax.device_put(build()(buf), sharding), out_sharding=sharding,
+            )
+        compiled, fresh = _get_compiled(key, build, donate_argnums=(0,), out_sharding=sharding)
+        if fresh:
+            _maybe_analyze(compiled, (buf,), key, donate_argnums=(0,))
+        return _run(compiled, (buf,), 1, sp, donated=True, fresh=fresh, key=key)
 
 
 def defer_store(dst_buf, src, dtype) -> Optional[PendingExpr]:
@@ -1156,71 +1217,78 @@ def cast_store(dst_buf, src, dtype, out_sharding=None):
     plumbing plus the leaf-list and arg-slot references when it is a
     leaf."""
     _C["stores"].inc()
-    if isinstance(src, PendingExpr):
-        nodes, leaves, leaf_slots = _linearize(src)
-        root = (True, len(nodes) - 1)
-    else:
-        nodes, leaves, leaf_slots = [], [src], {0: 1}
-        root = (False, 0)
-    nodes.append((_astype, {"dtype": dtype}, (root,)))
-
-    donate_ix = None
-    trailing_dst = False
-    if dst_buf is not None and _DONATE_ENABLED:
-        for i, leaf in enumerate(leaves):
-            if leaf is dst_buf:
-                # the `a += b` aliasing case: donating an OPERAND needs
-                # both proofs — the buffer itself is unshared (beyond
-                # the calibrated plumbing: the leaves-list entry, this
-                # loop's `leaf` binding, and one per expression arg-slot)
-                # AND the whole chain is private (no other DNDarray
-                # holds a sub-expression that would later materialize
-                # against the deleted buffer)
-                if (
-                    isinstance(src, PendingExpr)
-                    and _refcount_leaf_at_most(dst_buf, leaf_slots.get(i, 1))
-                    and _expr_private(src, dst_buf)
-                ):
-                    donate_ix = i
-                break
-        else:
-            # dst is not an operand: donated as an extra trailing
-            # argument so XLA may reuse its allocation for the output
-            if _refcount_at_most(dst_buf):
-                donate_ix = len(leaves)
-                trailing_dst = True
-
-    if trailing_dst:
-        n_real = len(leaves)
-        inner = _build_program(nodes)
-
-        def build():
-            def program(*args):
-                return inner(*args[:n_real])
-            return program
-
-        leaves = leaves + [dst_buf]
-    else:
-        def build():
-            return _build_program(nodes)
-
     if not _CACHE_ENABLED:
-        return _eval_nodes(nodes, leaves if not trailing_dst else leaves[:-1])
-    try:
-        key = _program_key(
-            "cast_store", nodes, leaves,
-            (out_sharding, donate_ix, trailing_dst),
+        # no cache and so no program: the chain (fusion is off with the
+        # cache, so one built before it went) and the cast, eagerly
+        return _astype(materialize(src) if isinstance(src, PendingExpr) else src, dtype=dtype)
+    with _launch("cast_store", store=True) as sp:
+        if isinstance(src, PendingExpr):
+            nodes, leaves, leaf_slots = _linearize(src)
+            root = (True, len(nodes) - 1)
+        else:
+            nodes, leaves, leaf_slots = [], [src], {0: 1}
+            root = (False, 0)
+        nodes.append((_astype, {"dtype": dtype}, (root,)))
+        # counted from the chain's own marks: no array and no node carries
+        # a count of the stores that waited
+        sp.attrs.update(ops=len(nodes), folded=sum(1 for op, _, _ in nodes if op is _stored))
+
+        donate_ix = None
+        trailing_dst = False
+        if dst_buf is not None and _DONATE_ENABLED:
+            for i, leaf in enumerate(leaves):
+                if leaf is dst_buf:
+                    # the `a += b` aliasing case: donating an OPERAND needs
+                    # both proofs — the buffer itself is unshared (beyond
+                    # the calibrated plumbing: the leaves-list entry, this
+                    # loop's `leaf` binding, and one per expression arg-slot)
+                    # AND the whole chain is private (no other DNDarray
+                    # holds a sub-expression that would later materialize
+                    # against the deleted buffer)
+                    if (
+                        isinstance(src, PendingExpr)
+                        and _refcount_leaf_at_most(dst_buf, leaf_slots.get(i, 1))
+                        and _expr_private(src, dst_buf)
+                    ):
+                        donate_ix = i
+                    break
+            else:
+                # dst is not an operand: donated as an extra trailing
+                # argument so XLA may reuse its allocation for the output
+                if _refcount_at_most(dst_buf):
+                    donate_ix = len(leaves)
+                    trailing_dst = True
+
+        if trailing_dst:
+            n_real = len(leaves)
+            inner = _build_program(nodes)
+
+            def build():
+                def program(*args):
+                    return inner(*args[:n_real])
+                return program
+
+            leaves = leaves + [dst_buf]
+        else:
+            def build():
+                return _build_program(nodes)
+
+        try:
+            key = _program_key(
+                "cast_store", nodes, leaves,
+                (out_sharding, donate_ix, trailing_dst),
+            )
+        except TypeError:
+            sp.attrs["fallback"] = True
+            return _eval_nodes(nodes, leaves if not trailing_dst else leaves[:-1])
+        if donate_ix is None:
+            return _compiled_or_fallback(
+                sp, key, build, leaves, len(nodes),
+                lambda: _eval_nodes(nodes, leaves), out_sharding=out_sharding,
+            )
+        compiled, fresh = _get_compiled(
+            key, build, donate_argnums=(donate_ix,), out_sharding=out_sharding
         )
-    except TypeError:
-        return _eval_nodes(nodes, leaves if not trailing_dst else leaves[:-1])
-    if donate_ix is None:
-        return _compiled_or_fallback(
-            key, build, leaves, len(nodes),
-            lambda: _eval_nodes(nodes, leaves), out_sharding=out_sharding,
-        )
-    compiled, fresh = _get_compiled(
-        key, build, donate_argnums=(donate_ix,), out_sharding=out_sharding
-    )
-    if fresh:
-        _maybe_analyze(compiled, leaves, key, donate_argnums=(donate_ix,))
-    return _run(compiled, leaves, len(nodes), donated=True, fresh=fresh, key=key)
+        if fresh:
+            _maybe_analyze(compiled, leaves, key, donate_argnums=(donate_ix,))
+        return _run(compiled, leaves, len(nodes), sp, donated=True, fresh=fresh, key=key)

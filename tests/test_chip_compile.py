@@ -416,7 +416,7 @@ def programs_of_the_eager_path(monkeypatch):
 
     kept = []
 
-    def compile_not_run(compiled, leaves, n_ops, donated=False, fresh=False, key=None):
+    def compile_not_run(compiled, leaves, n_ops, sp, donated=False, fresh=False, key=None):
         kept.append((key[0], donated, compiled.lower(*leaves).compile()))
         return jax.eval_shape(compiled, *leaves)
 

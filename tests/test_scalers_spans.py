@@ -78,7 +78,8 @@ def test_a_solve_leaves_its_root_spans(one_device, copy):
     launches = sum(r.attrs["launches"] for r in roots)
     assert launches == step["dispatches"] + step["external_dispatches"] - (0 if copy else 1)
     assert launches >= len(forced) + 6  # every store is a launch, and so is every fit's reduction
-    assert len(spans) <= len(roots) + 3  # the quantiles' span and nothing a launch (the ring holds 4,096)
+    # the quantiles' span, and since PR 35 one `dispatch.launch` a program (tests/test_dispatch_spans.py): the ring holds 4,096
+    assert len([r for r in spans if r.name != "dispatch.launch"]) <= len(roots) + 3
     inner = [r for r in spans if r.name == "statistics.quantiles"]
     fit = next(r for r in roots if r.name == "ht.preprocessing.RobustScaler.fit")
     assert len(inner) == 1 and inner[0].depth == 1 and inner[0].attrs["q"] == (50.0, 25.0, 75.0)
