@@ -437,22 +437,23 @@ def __reduce_op(
         arr = x._masked(neutral) if mask is not None else x.larray_padded
         result = operation(arr, **red_kwargs)
 
-    if split_reduced or x.split is None:
-        out_split = None if not keepdims or x.split is None else None
-        res = DNDarray.from_dense(result, out_split, x.device, x.comm)
-    else:
-        # split axis survives; result is still canonically padded along it
-        new_split = _reduced_split(x.split, axes, keepdims, reduced=False)
-        gshape = _reduced_shape(x.shape, axes, keepdims)
-        res = DNDarray(
-            jax.device_put(result, x.comm.sharding(new_split)),
-            gshape,
-            types.canonical_heat_type(result.dtype),
-            new_split,
-            x.device,
-            x.comm,
-        )
-    return _finalize_reduce(res, out)
+    return _finalize_reduce(_wrap_reduced(result, x, axes, keepdims), out)
+
+
+def _wrap_reduced(result, x: DNDarray, axes: Tuple[int, ...], keepdims: bool) -> DNDarray:
+    """The ``DNDarray`` of ``x``'s padded buffer reduced over ``axes``."""
+    if x.split is None or x.split in axes:
+        return DNDarray.from_dense(result, None, x.device, x.comm)
+    # split axis survives; result is still canonically padded along it
+    new_split = _reduced_split(x.split, axes, keepdims, reduced=False)
+    return DNDarray(
+        jax.device_put(result, x.comm.sharding(new_split)),
+        _reduced_shape(x.shape, axes, keepdims),
+        types.canonical_heat_type(result.dtype),
+        new_split,
+        x.device,
+        x.comm,
+    )
 
 
 def _finalize_reduce(res: DNDarray, out: Optional[DNDarray]) -> DNDarray:

@@ -643,9 +643,24 @@ def _build_program(nodes):
     return program
 
 
-def _eval_nodes(nodes, leaves):
+def _build_lazy_program(nodes):
+    """The program of a ``lazy`` :func:`chain_apply`: the last node's op is
+    handed, in the value's place, the chain itself as a function of what is
+    taken of each leaf."""
+    *chain, (op, kwargs, ((is_node, ix),)) = nodes
+    evaluate = _build_program(chain) if is_node else None
+
+    def program(*leaves):
+        def value(take):
+            taken = [take(leaf) for leaf in leaves]
+            return evaluate(*taken) if is_node else taken[ix]
+        return op(value, **kwargs)
+    return program
+
+
+def _eval_nodes(nodes, leaves, build=_build_program):
     """Uncached eager evaluation (cache disabled / unhashable key)."""
-    return _build_program(nodes)(*leaves)
+    return build(nodes)(*leaves)
 
 
 def _maybe_analyze(entry, leaves, key, donate_argnums=()) -> None:
@@ -934,7 +949,7 @@ def eager_apply(op, args: Sequence, kwargs: Optional[dict] = None):
         )
 
 
-def chain_apply(op, x, kwargs: Optional[dict] = None, mask=None):
+def chain_apply(op, x, kwargs: Optional[dict] = None, mask=None, lazy: bool = False):
     """Apply ``op(arr, **kwargs)`` where ``x`` is a pending chain or a
     concrete buffer: the chain, the optional pad-masking, and the op
     itself compile as ONE cached executable (the reduction/cum-op
@@ -942,20 +957,29 @@ def chain_apply(op, x, kwargs: Optional[dict] = None, mask=None):
 
     ``mask``: None, or ``(split, true_extent, neutral)`` — the padding
     rows are overwritten with ``neutral`` before ``op`` (the fused analog
-    of ``DNDarray._masked``)."""
+    of ``DNDarray._masked``).
+
+    ``lazy``: ``op`` is called as ``op(value, **kwargs)`` where
+    ``value(take)`` evaluates the chain on ``take(leaf)`` of every leaf
+    (``value(lambda leaf: leaf)`` is ``arr``).  For an op that reads a few
+    rows of its input before the whole of it, or the whole of it again inside
+    a loop: given the chain's value twice the compiler keeps it (a slice and
+    a reduction of one elementwise result are not fused into both: a second
+    table), given the leaves it computes the chain where it is read."""
+    build = _build_lazy_program if lazy else _build_program
     if not _CACHE_ENABLED:
-        return _eval_nodes(*_chain_nodes(op, x, kwargs, mask))
+        return _eval_nodes(*_chain_nodes(op, x, kwargs, mask), build)
     with _launch("chain") as sp:
         nodes, leaves = _chain_nodes(op, x, kwargs, mask)
         sp.attrs["ops"] = len(nodes)
         try:
-            key = _program_key("chain", nodes, leaves)
+            key = _program_key("chain", nodes, leaves, (lazy,))
         except TypeError:
             sp.attrs["fallback"] = True
-            return _eval_nodes(nodes, leaves)
+            return _eval_nodes(nodes, leaves, build)
         return _compiled_or_fallback(
-            sp, key, lambda: _build_program(nodes), leaves, len(nodes),
-            lambda: _eval_nodes(nodes, leaves),
+            sp, key, lambda: build(nodes), leaves, len(nodes),
+            lambda: _eval_nodes(nodes, leaves, build),
         )
 
 

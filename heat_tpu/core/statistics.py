@@ -11,6 +11,7 @@ split bookkeeping.
 
 from __future__ import annotations
 
+import builtins
 import functools
 from typing import Optional, Tuple, Union
 
@@ -24,7 +25,7 @@ from ..telemetry.spans import span as _span
 from . import dispatch, types
 from ._operations import __binary_op as _binary_op
 from ._operations import __reduce_op as _reduce_op
-from ._operations import _reduced_shape, _reduced_split
+from ._operations import _reduced_shape, _reduced_split, _wrap_reduced
 from .dndarray import DNDarray
 from .stride_tricks import sanitize_axis
 
@@ -384,20 +385,33 @@ def _select_bits(ranks: int) -> int:
 
 
 def _order_key(x):
-    """The unsigned integer whose order is the float's: sign bit flipped
-    for positive values, all bits for negative ones.  -0.0 sorts just
-    under +0.0 and compares equal to it; NaNs lie beyond the infinities
-    and are dealt with by their count."""
-    wide = jnp.uint64 if x.dtype == jnp.float64 else jnp.uint32
+    """The SIGNED integer whose order is the float's: the float's own bits
+    for positive values, the lower bits flipped for negative ones.  -0.0
+    sorts just under +0.0 and compares equal to it; NaNs lie beyond the
+    infinities and are dealt with by their count.  Signed, because that is
+    what the chip compares and takes minima of natively: on the unsigned key
+    (this one with the top bit flipped: :func:`_offset`) a counting pass of
+    nine compares read 13.89 ms for 12.97 and the neighbours' pass 17.14 for
+    10.29 (step 0, PERF.md section 6, PR 36).  Three operations a value."""
     if x.dtype not in (jnp.float32, jnp.float64):
         x = x.astype(jnp.float32)  # exact and order-preserving for the narrow floats
-    nbits = 8 * x.dtype.itemsize
-    u = jax.lax.bitcast_convert_type(x, wide)
-    return jnp.where(u >> (nbits - 1) == 1, ~u, u | wide(1 << (nbits - 1)))
+    s = jax.lax.bitcast_convert_type(x, jnp.int64 if x.dtype == jnp.float64 else jnp.int32)
+    return s ^ ((s >> (8 * s.dtype.itemsize - 1)) & jnp.iinfo(s.dtype).max)
+
+
+def _offset(k):
+    """The signed key of an unsigned one and back: the top bit flipped, the
+    bits taken as the other type.  The unsigned form is the one whose bit
+    prefixes are in order, which the digits of a selection are settled in."""
+    nbits = 8 * k.dtype.itemsize
+    if jnp.issubdtype(k.dtype, jnp.signedinteger):
+        u = jax.lax.bitcast_convert_type(k, jnp.uint64 if nbits == 64 else jnp.uint32)
+        return u ^ u.dtype.type(1 << (nbits - 1))
+    return jax.lax.bitcast_convert_type(k ^ k.dtype.type(1 << (nbits - 1)), jnp.int64 if nbits == 64 else jnp.int32)
 
 
 def _key_value(k, dtype):
-    """The float of an order key (the inverse of :func:`_order_key`)."""
+    """The float of an unsigned order key (:func:`_offset` of :func:`_order_key`)."""
     nbits = 8 * k.dtype.itemsize
     top = k.dtype.type(1 << (nbits - 1))
     u = jnp.where(k >> (nbits - 1) == 1, k & ~top, ~k)
@@ -415,13 +429,14 @@ def _select_ranks(x, axis: int, lows: tuple, with_high: bool, valid, all_sum, al
     counts over the mesh (ONE all-reduce of a (ranks x pivots x columns)
     array a pass).  The digit of a rank's key is the number of its pivots
     that at most ``rank`` elements lie under, so ``ceil(width / bits)``
-    passes settle the key, and one more pass finds the next rank: the
-    smallest key above it, or the same key where ties reach that far.  Beside the input this holds
+    passes settle the key, and ONE more pass, one read of the input, finds
+    the next rank: the smallest key above it, or the same key where ties
+    reach that far.  Beside the input this holds
     O(ranks x pivots x columns): nothing of the input's size is written.
     ``valid`` masks the canonical padding along a split axis (None: no
     padding)."""
     key = _order_key(x)
-    top = ~key.dtype.type(0)
+    top = key.dtype.type(jnp.iinfo(key.dtype).max)
     if valid is not None:
         key = jnp.where(valid, key, top)  # padding sorts last, under no pivot
     nbits = 8 * key.dtype.itemsize
@@ -430,7 +445,7 @@ def _select_ranks(x, axis: int, lows: tuple, with_high: bool, valid, all_sum, al
     # every rank's settled bits in ONE array: the pass's reductions then
     # share their operands and the compiler makes them one fusion, one read
     # of the input (a list of arrays a rank read the table three times a pass)
-    pre = jnp.zeros((len(lows),) + kept, key.dtype)
+    pre = jnp.zeros((len(lows),) + kept, jnp.uint64 if nbits == 64 else jnp.uint32)
 
     def count(mask):
         return jnp.sum(mask, axis=axis, keepdims=True, dtype=jnp.int32)
@@ -444,24 +459,37 @@ def _select_ranks(x, axis: int, lows: tuple, with_high: bool, valid, all_sum, al
             bits = step if shift >= step else shift  # `min` is this module's reduction
             shift -= bits
             digits = range(1, 1 << bits)
-            masks = [key < (pre[r] | key.dtype.type(d << shift)) for r in range(len(lows)) for d in digits]
+            masks = [key < _offset(pre[r] | pre.dtype.type(d << shift)) for r in range(len(lows)) for d in digits]
             if nans is None:  # the first pass counts the NaNs too: one reduction of the pass, one all-reduce
                 masks.append(nan)
             counts = all_sum(jnp.stack([count(m) for m in masks]))
             if nans is None:
                 nans, counts = counts[-1], counts[:-1]
             under = counts.reshape((len(lows), len(digits)) + kept) <= ranks[:, None]
-            pre = pre | (jnp.sum(under, axis=1).astype(key.dtype) << shift)
+            pre = pre | (jnp.sum(under, axis=1).astype(pre.dtype) << shift)
         if not with_high:
             return _key_value(pre, x.dtype), None, nans
-        upto = all_sum(jnp.stack([count(key <= pre[r]) for r in range(len(lows))]))
-        above = all_min(jnp.stack(
-            [jnp.min(jnp.where(key > pre[r], key, top), axis=axis, keepdims=True) for r in range(len(lows))]))
+        # the neighbours' pass: how many keys lie at or under each rank's, and
+        # the smallest above it.  One compare a rank serves both (`above` is
+        # its complement), and ONE `reduce` of all the operands is one read of
+        # the input by construction: a sum and a minimum as two reductions were
+        # left two fusions by the compiler, two reads
+        k = len(lows)
+        under = [key <= _offset(pre[r]) for r in range(k)]
+        found = jax.lax.reduce(
+            [u.astype(jnp.int32) for u in under] + [jnp.where(u, top, key) for u in under],
+            [jnp.int32(0)] * k + [top] * k,
+            lambda a, b: [p + q for p, q in zip(a[:k], b[:k])] + [jnp.minimum(p, q) for p, q in zip(a[k:], b[k:])],
+            (axis,))
+        upto = all_sum(jnp.stack(found[:k])).reshape(pre.shape)
+        above = all_min(_offset(jnp.stack(found[k:]))).reshape(pre.shape)
         return _key_value(pre, x.dtype), _key_value(jnp.where(upto >= ranks + 2, pre, above), x.dtype), nans
 
 
 def _select_passes(dtype, ranks: int, with_high: bool) -> int:
-    """How many passes over the input :func:`_select_ranks` makes."""
+    """How many passes over the input :func:`_select_ranks` makes, each of
+    them one read of it (``tests/test_chip_compile.py`` counts them in the
+    program compiled for the chip)."""
     nbits = 64 if dtype == jnp.float64 else 32
     return -(-nbits // _select_bits(ranks)) + (1 if with_high else 0)
 
@@ -639,8 +667,6 @@ def percentile(
     if not types.heat_type_is_inexact(x.dtype):
         dense = dense.astype(jnp.float32)
     if sketched:
-        import builtins
-
         from . import random as ht_random
 
         # NB: min/max in this module are the DNDarray reductions
@@ -676,16 +702,129 @@ def std(x, axis=None, ddof: int = 0, keepdims: bool = False, **kwargs):
     return exponential.sqrt(var(x, axis, ddof=ddof, keepdims=keepdims, **kwargs))
 
 
-def _var_fn(a, axis=None, ddof=0, keepdims=False):
-    return jnp.var(a, axis=axis, ddof=ddof, keepdims=keepdims)
+# ----------------------------------------------------------------------
+# several reductions of one array in ONE read of it (PERF.md, PR 36)
+# ----------------------------------------------------------------------
+#: Rows the moments' shift is the median of, spread evenly over the reduced
+#: extent, and how many standard deviations it may lie off the mean before
+#: the sums are taken again about the mean itself (:func:`_moments_fn`).
+_SHIFT_ROWS = 8
+_SHIFT_FAR = 4.0
+
+
+def _whole(leaf):
+    return leaf
+
+
+def _masked(a, pad, neutral):
+    """``a`` with the canonical padding of the reduced split axis (``pad``:
+    ``(axis, true extent)``, or None) overwritten by ``neutral``."""
+    return a if pad is None else dispatch._mask_pad(a, split=pad[0], extent=pad[1], neutral=neutral)
+
+
+def _extrema_fn(value, *, axes, keepdims, pad, neutrals):
+    """Minimum and maximum over ``axes``: two reductions of one operand, which
+    the compiler makes one fusion and one read."""
+    a = value(_whole)
+    return (jnp.min(_masked(a, pad, neutrals[0]), axis=axes, keepdims=keepdims),
+            jnp.max(_masked(a, pad, neutrals[1]), axis=axes, keepdims=keepdims))
+
+
+def _moments_fn(value, *, axes, keepdims, pad, ddof):
+    """Mean and variance over ``axes`` from ONE read of the input: the sums of
+    ``x - shift`` and of its square (two reductions of one operand: one
+    fusion), ``mean = shift + S1 / n``, ``var = (S2 - S1^2 / n) / (n - ddof)``,
+    in float32 at least.
+
+    The shift is the median of ``_SHIFT_ROWS`` elements spread evenly over the
+    longest reduced axis (an element of the data, so a constant column's
+    variance is exactly 0), taken of the LEAVES' rows and not of the chain's
+    value.  What it costs to be ``d`` standard deviations off the mean is a
+    relative error of ``(1 + d^2)`` times the sums' own rounding, and a median
+    lies within one standard deviation of the mean.  The sample's median
+    need not: where it turns out more than ``_SHIFT_FAR`` off in some column
+    (known from the sums themselves), the sums are taken once more about the
+    first pass's mean, so the result is never worse than the two-pass one and
+    the second read is paid only by such an input (rows sorted by a heavy
+    tail, the sampled rows all outliers)."""
+    a = value(_whole)
+    wide = jnp.promote_types(a.dtype, jnp.float32) if jnp.issubdtype(a.dtype, jnp.inexact) else jnp.dtype(jnp.float32)
+    kept = tuple(1 if d in axes else s for d, s in enumerate(a.shape))
+    extent = {d: pad[1] if pad is not None and d == pad[0] else a.shape[d] for d in axes}
+    n = int(np.prod([extent[d] for d in axes], dtype=np.int64))
+    if not axes:  # nothing is reduced: every element is its own mean
+        mean, m2 = a.astype(wide), jnp.zeros(kept, wide)
+    elif n == 0:  # nothing to reduce: no mean
+        mean = m2 = jnp.full(kept, jnp.nan, wide)
+    else:
+        long = builtins.max(axes, key=extent.get)
+        rows = {d: [i * (extent[d] // _SHIFT_ROWS) for i in range(_SHIFT_ROWS)] if d == long and extent[d] >= _SHIFT_ROWS
+                else [0] for d in axes}
+
+        def sampled(leaf):
+            # the rows of a leaf that spans a reduced axis; one that is broadcast along it as it is
+            for d, at in rows.items():
+                own = d - (a.ndim - np.ndim(leaf))
+                if own >= 0 and leaf.shape[own] == a.shape[d]:
+                    leaf = jnp.concatenate([jax.lax.slice_in_dim(leaf, i, i + 1, axis=own) for i in at], axis=own)
+            return leaf
+
+        def sums(about):
+            d = _masked(value(_whole).astype(wide) - about, pad, 0)
+            s1, s2 = (jnp.sum(v, axis=axes, keepdims=True) for v in (d, d * d))
+            off = s1 / n
+            return about + off, s2 - s1 * off, off
+
+        sample = jnp.sort(value(sampled).astype(wide), axis=long)  # NaNs last
+        shift = jax.lax.slice_in_dim(sample, len(rows[long]) // 2, len(rows[long]) // 2 + 1, axis=long)
+        mean, m2, off = sums(jnp.where(jnp.isfinite(shift), shift, 0))
+        far = jnp.any(off * off * n > _SHIFT_FAR ** 2 * jnp.abs(m2))  # false for a NaN, which stays one
+        # a loop of at most one turn and not `lax.cond`: a branch of a conditional takes the table
+        # row-major on the chip (16 GiB at 2^25 x 50, where the long axis is laid out minor), a loop as it lies
+        _, mean, m2 = jax.lax.while_loop(lambda s: s[0], lambda s: (jnp.zeros((), bool),) + sums(s[1])[:2], (far, mean, m2))
+    var = jnp.maximum(m2, 0) / builtins.max(n - ddof, 0)
+    out = a.dtype if jnp.issubdtype(a.dtype, jnp.inexact) else wide
+    return tuple((v if keepdims else jnp.squeeze(v, axis=axes)).astype(out) for v in (mean, var))
+
+
+def _reduce_together(fn, x: DNDarray, axis, keepdims: bool, **kwargs) -> Tuple[DNDarray, ...]:
+    """``fn``'s results (several reductions of ``x`` over the same axes) from
+    ONE program that reads ``x`` once, through its pending chain where it has
+    one (``dispatch.chain_apply``, as :func:`__reduce_op`): a waiting in-place
+    store stays waiting.  The canonical padding of a reduced split axis is
+    masked inside ``fn`` (``pad``), each result in its own neutral."""
+    axis = sanitize_axis(x.shape, axis)
+    axes = tuple(range(x.ndim)) if axis is None else axis if isinstance(axis, tuple) else (axis,)
+    split_reduced = x.split is not None and x.split in axes
+    kwargs.update(axes=axes, keepdims=bool(keepdims),
+                  pad=(x.split, x.shape[x.split]) if split_reduced and x._pad > 0 else None)
+    if x._planar is None and not types.heat_type_is_complexfloating(x.dtype):
+        results = dispatch.chain_apply(fn, x._fusion_source, kwargs, lazy=True)
+    else:
+        buf = x.larray_padded
+        results = fn(lambda take: take(buf), **kwargs)
+    return tuple(_wrap_reduced(r, x, axes, bool(keepdims)) for r in results)
+
+
+def min_max(x: DNDarray, axis=None, keepdims: bool = False) -> Tuple[DNDarray, DNDarray]:
+    """``(min(x, axis), max(x, axis))`` from one read of ``x``."""
+    return _reduce_together(_extrema_fn, x, axis, keepdims, neutrals=(_max_neutral(x), _min_neutral(x)))
+
+
+def mean_var(x: DNDarray, axis=None, ddof: int = 0, keepdims: bool = False) -> Tuple[DNDarray, DNDarray]:
+    """``(mean(x, axis), var(x, axis, ddof))`` of a real array from one read
+    of ``x`` (:func:`_moments_fn`); integers are taken as float32."""
+    return _reduce_together(_moments_fn, x, axis, keepdims, ddof=ddof)
 
 
 def var(x, axis=None, ddof: int = 0, keepdims: bool = False, **kwargs):
     """Variance (statistics.py:1903).
 
-    Two-pass global computation; the reference's Welford-style pairwise
-    merge (``__merge_moments``) is unnecessary because the global reduction
-    already sees all shards.
+    One read of ``x``: shifted sums about the median of a few of its rows,
+    taken again about the mean where that shift proves far off
+    (:func:`_moments_fn`, which says what bounds the error).  The reference's
+    pairwise merge of the processes' moments (``__merge_moments``) is
+    unnecessary because the global reduction already sees all shards.
     """
     if kwargs:
         raise TypeError(f"var() got unexpected keyword arguments {sorted(kwargs)}")
@@ -693,20 +832,7 @@ def var(x, axis=None, ddof: int = 0, keepdims: bool = False, **kwargs):
         raise ValueError(f"ddof must be integer, is {type(ddof)}")
     if ddof < 0:
         raise ValueError(f"Expected ddof >= 0, got {ddof}")
-    dense = x._dense()
-    if not types.heat_type_is_inexact(x.dtype):
-        dense = dense.astype(jnp.float32)
-    axis_s = sanitize_axis(x.shape, axis)
-    # one cached executable, counted among the launches like every other reduction
-    result = dispatch.eager_apply(
-        _var_fn, (dense,),
-        {"axis": tuple(axis_s) if isinstance(axis_s, list) else axis_s, "ddof": ddof, "keepdims": bool(keepdims)},
-    )
-    if axis_s is None or x.split is None:
-        out_split = None
-    else:
-        axes = axis_s if isinstance(axis_s, tuple) else (axis_s,)
-        out_split = None if x.split in axes else _reduced_split(x.split, axes, keepdims, reduced=False)
-    if out_split is not None and out_split >= result.ndim:
-        out_split = None
-    return DNDarray.from_dense(result, out_split, x.device, x.comm)
+    if x._planar is None and not types.heat_type_is_complexfloating(x.dtype):
+        return mean_var(x, axis, ddof, keepdims)[1]
+    # a complex array: the dense two-pass form, as `__reduce_op` keeps such arrays off the chain
+    return _dense_reduce(lambda a, axis, keepdims: jnp.var(a, axis=axis, ddof=ddof, keepdims=keepdims), x, axis, keepdims)

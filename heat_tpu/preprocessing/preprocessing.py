@@ -115,9 +115,14 @@ class StandardScaler(_Scaler):
     def _fit(self, x: DNDarray, sample_weight=None) -> None:
         if sample_weight is not None:
             raise NotImplementedError("sample_weight is not yet supported (matching preprocessing.py:95)")
-        self.mean_ = statistics.mean(x, axis=0) if self.with_mean else None
+        if not self.with_std:
+            self.mean_, self.var_ = statistics.mean(x, axis=0) if self.with_mean else None, None
+            return
+        # both moments from ONE program, one read of the table (statistics.mean_var)
+        mean, var = statistics.mean_var(x, axis=0)
+        self.mean_ = mean if self.with_mean else None
         # zero-variance features are guarded (preprocessing.py:120)
-        self.var_ = _guard_zero(statistics.var(x, axis=0)) if self.with_std else None
+        self.var_ = _guard_zero(var)
 
     def _transform(self, x: DNDarray) -> DNDarray:
         if self.with_mean and self.mean_ is not None:
@@ -149,8 +154,8 @@ class MinMaxScaler(_Scaler):
         self.min_ = None
 
     def _fit(self, x: DNDarray) -> None:
-        self.data_min_ = statistics.min(x, axis=0)
-        self.data_max_ = statistics.max(x, axis=0)
+        # both extrema from ONE program, one read of the table (statistics.min_max)
+        self.data_min_, self.data_max_ = statistics.min_max(x, axis=0)
         lo, hi = self.feature_range
         self.scale_ = (hi - lo) / _guard_zero(self.data_max_ - self.data_min_)
         self.min_ = lo - self.data_min_ * self.scale_

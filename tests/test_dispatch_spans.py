@@ -208,10 +208,12 @@ def test_tracing_off_records_nothing(one_device):
 # ------------------------------------------------------------------- the cells
 def test_the_scalers_solve_by_span(one_device):
     """The driver's solve, as the chip runs it (no address probe, which reads
-    the table after every call): 14 programs through the dispatch layer and
-    the selection's one, 7 of them outside every ``ht.*`` span (six small
-    chains of fitted attributes and the last store), 2 stores that donated
-    with the 9 deferred ones folded into them."""
+    the table after every call): 11 programs through the dispatch layer and
+    the selection's one (14 + 1 until PR 36 made a fit one program; ``mean_``
+    leaves that program finished, where ``sum / n`` waited for the caller),
+    6 of them outside every ``ht.*`` span (five small chains of fitted
+    attributes and the last store), 2 stores that donated with the 9 deferred
+    ones folded into them."""
     drv = load_py("drivers", "scalers_inplace")
     state = drv.build(load_json("configs", "scalers-inplace.json"), 7, 65536)
     state["table_bytes"] = 1
@@ -224,14 +226,18 @@ def test_the_scalers_solve_by_span(one_device):
     spans = telemetry.get_spans()
     launches, roots = _launches(), [r for r in spans if r.name.startswith("ht.preprocessing.")]
     quantiles = [r for r in spans if r.name == "statistics.quantiles"]
-    assert len(launches) == after["dispatches"] - before["dispatches"] == 14
+    assert len(launches) == after["dispatches"] - before["dispatches"] == 11
     assert sum(q.attrs["launches"] for q in quantiles) == after["external_dispatches"] - before["external_dispatches"] == 1
     outside = [r for r in launches if r.depth == 0]
-    assert len(outside) == 7 and sorted(r.attrs["kind"] for r in outside) == ["cast_store"] + ["expr"] * 6
+    assert len(outside) == 6 and sorted(r.attrs["kind"] for r in outside) == ["cast_store"] + ["expr"] * 5
     for r in launches:
         held = [o for o in roots if o.start_ns <= r.start_ns and _end(r) <= _end(o)]
         assert len(held) == (0 if r.depth == 0 else 1) and not r.attrs["fresh"]
-    assert sum(o.attrs["launches"] for o in roots) == 8  # what `scalers_launches` reads
+    assert sum(o.attrs["launches"] for o in roots) == 6  # what `scalers_launches` reads
+    by_fit = {o.name.split(".")[2]: [r.attrs["kind"] for r in launches if o.start_ns <= r.start_ns and _end(r) <= _end(o)]
+              for o in roots if o.name.endswith(".fit")}
+    assert by_fit == {"StandardScaler": ["chain"], "MinMaxScaler": ["chain"], "MaxAbsScaler": ["chain"],
+                      "RobustScaler": ["cast_store"], "Normalizer": []}
     stores = [r for r in launches if r.attrs["store"]]
     assert [(r.attrs["donated"], r.attrs["folded"], r.depth) for r in stores] == [(True, 6, 1), (True, 3, 0)]
     assert after["deferred_stores"] - before["deferred_stores"] == 9
@@ -266,8 +272,8 @@ def test_the_jitted_cells_open_no_launch_span(one_device, cell):
 # ------------------------------------------------------------------- the readers
 CALLS = 14
 #: the launches of one solve as the CPU counts them: (depth, kind, store, donated, folded)
-SOLVE = ([(1, "chain", False, False, 0), (1, "apply", False, False, 0)] + [(0, "expr", False, False, 0)] * 2
-         + [(1, "chain", False, False, 0)] * 2 + [(0, "expr", False, False, 0)] * 2 + [(1, "chain", False, False, 0)]
+SOLVE = ([(1, "chain", False, False, 0), (0, "expr", False, False, 0)]
+         + [(1, "chain", False, False, 0)] + [(0, "expr", False, False, 0)] * 2 + [(1, "chain", False, False, 0)]
          + [(0, "expr", False, False, 0), (1, "cast_store", True, True, 6), (0, "expr", False, False, 0),
             (1, "chain", False, False, 0), (0, "cast_store", True, True, 3)])
 
@@ -298,7 +304,7 @@ def _ring(solves, warmup=2, launches=True, copied=False):
 
 #: (reader, case) -> (what fills the ring, the reading wanted, the notes wanted; None: nothing read, with a note)
 READER_CASES = {
-    ("scalers_programs", "read"): (lambda: _ring(4), 15.0, {"scalers_programs_outside_spans": 7.0}),
+    ("scalers_programs", "read"): (lambda: _ring(4), 12.0, {"scalers_programs_outside_spans": 6.0}),
     ("scalers_programs", "ring_wrapped"): (lambda: _ring(3, warmup=0), None, None),
     ("scalers_programs", "no_launch_span"): (lambda: _ring(4, launches=False), None, None),
     ("scalers_programs", "tracing_off"): (telemetry.clear_spans, None, None),
@@ -306,7 +312,7 @@ READER_CASES = {
     ("scalers_undonated_stores", "the_last_store_copied"): (lambda: _ring(4, copied=True), 1.0,
                                                             {"scalers_store_launches": 2.0, "scalers_folded_stores": 9.0}),
     ("scalers_undonated_stores", "no_launch_span"): (lambda: _ring(4, launches=False), None, None),
-    ("scalers_dispatch_host_ms", "read"): (lambda: _ring(4), 1.4, {"scalers_dispatch_host_ms_outside_spans": 0.7}),
+    ("scalers_dispatch_host_ms", "read"): (lambda: _ring(4), 1.1, {"scalers_dispatch_host_ms_outside_spans": 0.6}),
     ("scalers_dispatch_host_ms", "ring_wrapped"): (lambda: _ring(3, warmup=0), None, None),
     ("scalers_dispatch_host_ms", "no_launch_span"): (lambda: _ring(4, launches=False), None, None),
 }

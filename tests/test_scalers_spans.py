@@ -77,7 +77,9 @@ def test_a_solve_leaves_its_root_spans(one_device, copy):
         assert r.attrs["inplace"] is (not copy and (applies or r.name in forced)), r
     launches = sum(r.attrs["launches"] for r in roots)
     assert launches == step["dispatches"] + step["external_dispatches"] - (0 if copy else 1)
-    assert launches >= len(forced) + 6  # every store is a launch, and so is every fit's reduction
+    assert launches >= len(forced) + 5  # every store is a launch, and so is every fit's ONE reduction (PR 36)
+    fits = {r.name.split(".")[2]: r.attrs["launches"] for r in roots if r.name.endswith(".fit")}
+    assert fits == {"StandardScaler": 1, "MinMaxScaler": 1, "MaxAbsScaler": 1, "RobustScaler": 2 - copy, "Normalizer": 0}
     # the quantiles' span, and since PR 35 one `dispatch.launch` a program (tests/test_dispatch_spans.py): the ring holds 4,096
     assert len([r for r in spans if r.name != "dispatch.launch"]) <= len(roots) + 3
     inner = [r for r in spans if r.name == "statistics.quantiles"]
