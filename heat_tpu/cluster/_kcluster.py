@@ -116,17 +116,20 @@ class _KCluster(BaseEstimator, ClusteringMixin):
             ht_random.seed(self.random_state)
         from ..core import random as ht_random
 
-        dense = x._dense()
-        if not types.heat_type_is_inexact(x.dtype):
-            dense = dense.astype(jnp.float32)
-        n, f = dense.shape
+        n, f = x.shape
         k = self.n_clusters
+        exact = types.heat_type_is_inexact(x.dtype)
+
+        def points():
+            # only where points are drawn: the true-shape view of a padded array is a copy of it
+            return x._dense() if exact else x._dense().astype(jnp.float32)
 
         if isinstance(self.init, DNDarray):
             if self.init.shape != (k, f):
                 raise ValueError(f"passed centroids need to be of shape ({k}, {f}), but are {self.init.shape}")
-            centers = self.init._dense().astype(dense.dtype)
+            centers = self.init._dense().astype(x.larray_padded.dtype if exact else jnp.float32)
         elif self.init == "random":
+            dense = points()
             # k DISTINCT data points (argsort of one uniform draw = a
             # random sample without replacement).  Sampling indices WITH
             # replacement could seed two centers on the same point — a
@@ -137,6 +140,7 @@ class _KCluster(BaseEstimator, ClusteringMixin):
             idx = jnp.argsort(u)[:k]
             centers = dense[idx]
         elif self.init in ("kmeans++", "probability_based", "++"):
+            dense = points()
             # kmeans++ sampling (_kcluster.py:112-180): greedy D^2 weighting.
             # The uniforms are pre-drawn one call per added center — the
             # exact draw sequence of the release before the loop was fused,
@@ -188,9 +192,6 @@ class _KCluster(BaseEstimator, ClusteringMixin):
             site=site,
             what="cluster centers",
         )
-
-    def _update_centroids(self, x: DNDarray, matching_centroids: DNDarray):
-        raise NotImplementedError()
 
     def fit(self, x: DNDarray):
         raise NotImplementedError()

@@ -71,7 +71,7 @@ class KMedoids(_KCluster):
         checkpoint_dir: Optional[str] = None,
         resume_from: Optional[str] = None,
     ):
-        if init == "kmedoids++":
+        if isinstance(init, str) and init == "kmedoids++":
             init = "probability_based"
         super().__init__(
             metric=lambda x, y: distance.manhattan(x, y),
@@ -84,30 +84,6 @@ class KMedoids(_KCluster):
             checkpoint_dir=checkpoint_dir,
             resume_from=resume_from,
         )
-
-    def _update_centroids(self, x: DNDarray, matching_centroids: DNDarray) -> DNDarray:
-        """Mean update then snap to the nearest sample (kmedoids.py:70+)."""
-        dense = x._dense()
-        if not types.heat_type_is_inexact(x.dtype):
-            dense = dense.astype(jnp.float32)
-        labels = matching_centroids._dense()
-        old = self._cluster_centers._dense()
-        new_centers = []
-        for c in range(self.n_clusters):
-            mask = labels == c
-            cnt = jnp.sum(mask)
-            mean = jnp.where(
-                cnt > 0,
-                jnp.sum(jnp.where(mask[:, None], dense, 0.0), axis=0) / jnp.maximum(cnt, 1),
-                old[c],
-            )
-            # snap to closest member of the cluster (or global closest when empty)
-            d = jnp.sum(jnp.abs(dense - mean[None, :]), axis=1)
-            d = jnp.where(mask, d, jnp.inf)
-            d = jnp.where(cnt > 0, d, jnp.sum(jnp.abs(dense - mean[None, :]), axis=1))
-            new_centers.append(dense[jnp.argmin(d)])
-        new = jnp.stack(new_centers)
-        return DNDarray.from_dense(new, None, x.device, x.comm)
 
     def fit(self, x: DNDarray) -> "KMedoids":
         """Iterate until the medoids stop moving (kmedoids.py:~110)."""

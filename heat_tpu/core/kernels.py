@@ -1,9 +1,13 @@
-"""The one hand-written Pallas kernel of the array core: ``gram_syrk``.
+"""The hand-written Pallas kernels of the array core: ``gram_syrk`` and
+``grouped_digit_counts``.
 
 The framework's compute path is XLA-compiled jnp; a Pallas kernel exists
 only where a trace demands it, that is where a device trace shows XLA
-streaming an operand more often than the algorithm needs and a benchmark
-cell shows the kernel ahead.  ``gram_syrk`` is hSVD's Gram pass: XLA lowers
+streaming an operand more often than the algorithm needs, or spending
+several times the memory's time on operations a kernel can do without, and
+a benchmark cell shows the kernel ahead.  ``grouped_digit_counts`` is one
+counting pass of KMedians' grouped selection (its section below has the
+readings; ``kmedians-spheres3d.loop1`` times it).  ``gram_syrk`` is hSVD's Gram pass: XLA lowers
 ``x.T @ x`` as a generic dot with two operand streams, the kernel reads each
 row tile of ``x`` once, at the rate the chip's memory streams (8.51 ms for
 6.44 GB, 757 GB/s; ``hsvd-tallskinny.loop1`` times it, ``gram_syrk_ms``).
@@ -17,6 +21,8 @@ points packed 128 // f to a lane row is VPU-bound on v5e (its in-lane argmin),
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -24,6 +30,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "gram_syrk",
+    "grouped_digit_counts",
+    "pack_columns",
     "syrk_supported",
 ]
 
@@ -145,3 +153,113 @@ def gram_syrk(x: jax.Array) -> jax.Array:
         tail = x[steps * rows :]
         g = g + jnp.matmul(tail.T, tail, precision=jax.lax.Precision.HIGH)
     return g
+
+
+# ----------------------------------------------------------------------
+# grouped digit counts: one counting pass of a selection by group
+# (KMedians' per-cluster medians), all groups in ONE read of the values.
+#
+# As XLA writes the pass on the table as it lies (rows x 3, the long axis
+# minor, a quarter of every register padding: one compare, one mask, one
+# convert and one add for each group's each pivot) a value costs some 55
+# operations at 2 bits and four groups, and the pass reads 28.3 ms at
+# 2^28 x 3 where the memory needs 7 (chip run, PERF.md, PR 37).  The kernel
+# does what a fusion cannot: it asks only whether the value lies in ITS
+# group's range and which digit it holds there, and adds ``1 << 8 * digit``
+# to its group's packed counters, four digits' counts a 32-bit lane,
+# unpacked once a block: some 25 operations a value.  It reads the values
+# column by column from a copy that fills every register (`pack_columns`);
+# on the table itself (columns on the sublanes, three of eight used, the
+# groups' bits broadcast along the lanes in every step) the same kernel read
+# 51.8 ms a pass (chip run, PR 37).
+# ----------------------------------------------------------------------
+#: the packed copy's rows are this long, and one step of the kernel's inner
+#: loop holds eight of them in registers
+COUNT_LANES = 512
+#: rows of the packed copy a block of the kernel holds: 32 steps a column,
+#: under the 255 a packed counter, a byte, can count (a lane counts once a step)
+COUNT_ROWS = 256
+
+
+def pack_columns(x: jax.Array) -> jax.Array:
+    """``x`` (rows x columns) column by column, each column as whole rows of
+    ``COUNT_LANES`` values, zeros behind the last: ``(columns, R, COUNT_LANES)``
+    with ``R`` a whole number of the kernel's blocks.  On the chip every
+    register of this copy is full, where the table's own are a quarter
+    padding at three columns; a caller that reads its values many times
+    makes it once."""
+    rows, f = x.shape
+    whole = COUNT_ROWS * COUNT_LANES
+    padded = -(-rows // whole) * whole
+    return jnp.pad(x.T, ((0, 0), (0, padded - rows))).reshape(f, padded // COUNT_LANES, COUNT_LANES)
+
+
+def _count_kernel(shift_ref, phi_ref, x_ref, lab_ref, out_ref, *, groups, bits):
+    f = x_ref.shape[0]
+    kdt, nbits = (jnp.int64, 64) if x_ref.dtype == jnp.float64 else (jnp.int32, 32)
+    some = (8, COUNT_LANES)  # what one step holds of a column
+    by_shift, by_bits = jnp.full(some, shift_ref[0], kdt), jnp.full(some, bits, kdt)  # a shift's second operand has the first's shape
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    for c in range(f):
+        def step(j, accs, c=c):
+            at = pl.multiple_of(j * 8, 8)
+            s = jax.lax.bitcast_convert_type(x_ref[c, pl.ds(at, 8), :], kdt)
+            # the bits in the order of the values, to be read without a sign: all
+            # flipped for a negative value, the top one for the others
+            u = s ^ ((s >> (nbits - 1)) | kdt(-1 << (nbits - 1)))
+            lab = lab_ref[pl.ds(at, 8), :]
+            member = [lab == g for g in range(groups)]
+            phi = jnp.full(some, phi_ref[(groups - 1) * f + c], kdt)
+            for g in range(groups - 2, -1, -1):
+                phi = jnp.where(member[g], phi_ref[g * f + c], phi)
+            low = jax.lax.shift_right_logical(u, by_shift)  # the digit and what lies above it
+            inc = jax.lax.shift_left(jnp.int32(1), ((low & kdt((1 << bits) - 1)) << 3).astype(jnp.int32))
+            inc = jnp.where(jax.lax.shift_right_logical(low, by_bits) == phi, inc, 0)  # in its group's range
+            return tuple(acc + jnp.where(m, inc, 0) for acc, m in zip(accs, member))
+
+        accs = jax.lax.fori_loop(0, x_ref.shape[1] // 8, step, tuple(jnp.zeros(some, jnp.int32) for _ in range(groups)))
+        for g in range(groups):
+            for b in range(1 << bits):
+                out_ref[g, b, c] += jax.lax.shift_right_logical(accs[g], jnp.int32(8 * b)) & 0xFF
+
+
+def grouped_digit_counts(packed: jax.Array, labels: jax.Array, prefix: jax.Array, shift, bits: int, groups: int) -> jax.Array:
+    """For each of ``groups`` groups and each column of ``packed``
+    (`pack_columns`' copy: columns x R x ``COUNT_LANES``, float32; float64
+    through the interpreter), how many of the group's values hold each value
+    of the ``bits``-wide digit at ``shift`` in their order key, among those
+    whose key agrees with the group's settled ``prefix`` (groups x columns,
+    the unsigned key's bits above the digit in place, zeros below) in every
+    bit above the digit: ``int32[groups, 2**bits, columns]``.  ``labels``
+    (R x ``COUNT_LANES``, int32) name each value's group, any other number
+    none (so the zeros behind the last row).  ``bits`` is 1 or 2: four
+    digits' counters share a lane.  ``shift`` may be a device value: a
+    caller's loop over the passes is then ONE call of the kernel, one name in
+    the device trace.
+
+    ONE read of the values and of the labels, whatever ``groups``; nothing of
+    their size is written."""
+    f, rows, lanes = packed.shape
+    assert 1 <= bits <= 2 and labels.shape == (rows, lanes) and lanes == COUNT_LANES and rows % COUNT_ROWS == 0
+    kdt = jnp.int64 if packed.dtype == jnp.float64 else jnp.int32
+    shift = jnp.asarray(shift, jnp.int32).reshape(1)
+    # the bits above the digit, in two shifts: the first pass's digit is the top one, and no shift takes all the bits
+    above = jax.lax.shift_right_logical(jax.lax.bitcast_convert_type(prefix, kdt), jnp.broadcast_to(shift.astype(kdt), prefix.shape))
+    phi = jax.lax.shift_right_logical(above, jnp.full(prefix.shape, bits, kdt)).reshape(groups * f)
+    counts = pl.pallas_call(
+        functools.partial(_count_kernel, groups=groups, bits=bits),
+        out_shape=jax.ShapeDtypeStruct((groups, 1 << bits, f, 8, lanes), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows // COUNT_ROWS,),
+            in_specs=[pl.BlockSpec((f, COUNT_ROWS, lanes), lambda i, *_: (0, i, 0)),
+                      pl.BlockSpec((COUNT_ROWS, lanes), lambda i, *_: (i, 0))],
+            out_specs=pl.BlockSpec((groups, 1 << bits, f, 8, lanes), lambda i, *_: (0, 0, 0, 0, 0))),
+        interpret=_interpret(),
+        name="kmedians_count",  # the device trace names the custom call by it (%kmedians_count.N)
+    )(shift, phi, packed, labels)
+    return jnp.sum(counts, axis=(-2, -1))

@@ -22,7 +22,7 @@ from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as _P
 
 from ..telemetry.spans import span as _span
-from . import dispatch, types
+from . import dispatch, kernels, types
 from ._operations import __binary_op as _binary_op
 from ._operations import __reduce_op as _reduce_op
 from ._operations import _reduced_shape, _reduced_split, _wrap_reduced
@@ -373,6 +373,10 @@ _SELECT_MIN_EXTENT = 1 << 14
 #: it.  Step 0 (PR 33): a pass over 2^25 x 50 float32 reads 10.7 ms with 1
 #: to 6 compares, 14.6 with 9, 18.7 with 12, 22.6 with 15, 36.1 with 21.
 _SELECT_COMPARES = 12
+#: Bits of the key a counting pass BY GROUP settles, whatever the number of
+#: groups: its kernel packs a digit's counters four to a lane, so a group
+#: more costs two operations a value and not a compare and a count a pivot.
+_GROUP_BITS = 2
 
 
 def _select_bits(ranks: int) -> int:
@@ -418,7 +422,7 @@ def _key_value(k, dtype):
     return jax.lax.bitcast_convert_type(u, jnp.float64 if nbits == 64 else jnp.float32).astype(dtype)
 
 
-def _select_ranks(x, axis: int, lows: tuple, with_high: bool, valid, all_sum, all_min):
+def _select_ranks(x, axis: int, lows, with_high: bool, valid, all_sum, all_min, group=None):
     """Exact order statistics along ``axis`` by counting passes over the
     order key: for every 0-based rank in ``lows`` the value of that rank
     and, ``with_high``, of the next rank (``keepdims`` form, stacked along a
@@ -434,38 +438,81 @@ def _select_ranks(x, axis: int, lows: tuple, with_high: bool, valid, all_sum, al
     reach that far.  Beside the input this holds
     O(ranks x pivots x columns): nothing of the input's size is written.
     ``valid`` masks the canonical padding along a split axis (None: no
-    padding)."""
+    padding).
+
+    With ``group=(labels, k)`` the statistics are taken group by group:
+    ``x`` is then `kernels.pack_columns`' copy of a table (columns x R x
+    lanes), ``axis`` its last two axes, ``labels`` (R x lanes, int32) name
+    each value's group, any other number none (so a caller's padding), and
+    ``lows`` is a DEVICE array of shape ``(k, columns, 1, 1)`` (or
+    broadcastable to it), one rank a group, which only the caller's counts
+    know.  A group is then what a rank is without: its own pivots and its own
+    counts, over its own values alone, and all ``k`` groups share every pass.
+    The counts of a pass come from the kernel ``kernels.grouped_digit_counts``
+    (``_GROUP_BITS`` a pass: its docstring has why), as each digit's count
+    among the keys that agree with the group's settled bits, and are made
+    the pivots' counts here.  The rank of an empty group selects nothing a
+    caller may use; the NaNs are counted by group, in the neighbours' pass,
+    which a grouped call always makes (``with_high``)."""
     key = _order_key(x)
     top = key.dtype.type(jnp.iinfo(key.dtype).max)
     if valid is not None:
         key = jnp.where(valid, key, top)  # padding sorts last, under no pivot
     nbits = 8 * key.dtype.itemsize
-    kept = tuple(1 if d == axis else s for d, s in enumerate(x.shape))  # the `keepdims` shape
-    ranks = jnp.asarray(lows, jnp.int32).reshape((-1,) + (1,) * x.ndim)
+    axes = axis if isinstance(axis, tuple) else (axis,)
+    kept = tuple(1 if d in axes else s for d, s in enumerate(x.shape))  # the `keepdims` shape
+    if group is None:
+        k = len(lows)
+        ranks = jnp.asarray(lows, jnp.int32).reshape((-1,) + (1,) * x.ndim)
+        member = None
+    else:
+        labels, k = group
+        assert x.ndim == 3 and axes == (1, 2) and valid is None and with_high
+        ranks = lows.astype(jnp.int32)
+        member = [labels == g for g in range(k)]
+
+    def counted(r, pivot, cmp):
+        """Which elements rank ``r`` counts: those that compare with the pivot, of its group."""
+        return cmp(key, pivot) if member is None else cmp(key, pivot) & member[r]
+
     # every rank's settled bits in ONE array: the pass's reductions then
     # share their operands and the compiler makes them one fusion, one read
     # of the input (a list of arrays a rank read the table three times a pass)
-    pre = jnp.zeros((len(lows),) + kept, jnp.uint64 if nbits == 64 else jnp.uint32)
+    pre = jnp.zeros((k,) + kept, jnp.uint64 if nbits == 64 else jnp.uint32)
 
     def count(mask):
         return jnp.sum(mask, axis=axis, keepdims=True, dtype=jnp.int32)
 
+    def grouped_pass(turn, settled):
+        """One pass by group: each digit's count among the keys in the
+        group's range, made the pivots' counts by what lies under the range
+        (the count at the pivot chosen the pass before)."""
+        pre, below = settled
+        shift = nbits - _GROUP_BITS * (turn + 1)
+        held = all_sum(kernels.grouped_digit_counts(x, labels, pre[:, :, 0, 0], shift, _GROUP_BITS, k))
+        counts = below + jnp.cumsum(held, axis=1, dtype=jnp.int32)[:, :-1, :, None, None]
+        found = counts <= ranks[:, None]
+        digit = jnp.sum(found, axis=1).astype(pre.dtype)
+        return pre | (digit << shift.astype(pre.dtype)), jnp.max(jnp.where(found, counts, below), axis=1, keepdims=True)
+
     with jax.named_scope("quantile.count"):
-        nan = jnp.isnan(x) if valid is None else jnp.isnan(x) & valid
         nans = None
-        shift = nbits
-        step = _select_bits(len(lows))
+        if member is not None:  # the passes as a loop: ONE call of the kernel in the program
+            below = jnp.zeros((k, 1) + kept, jnp.int32)  # a group's keys under its settled bits' range
+            pre, _ = jax.lax.fori_loop(0, nbits // _GROUP_BITS, grouped_pass, (pre, below))
+        shift = nbits if member is None else 0  # by group every bit is settled by now
+        step = _select_bits(k)
         while shift > 0:
             bits = step if shift >= step else shift  # `min` is this module's reduction
             shift -= bits
             digits = range(1, 1 << bits)
-            masks = [key < _offset(pre[r] | pre.dtype.type(d << shift)) for r in range(len(lows)) for d in digits]
+            masks = [key < _offset(pre[r] | pre.dtype.type(d << shift)) for r in range(k) for d in digits]
             if nans is None:  # the first pass counts the NaNs too: one reduction of the pass, one all-reduce
-                masks.append(nan)
+                masks.append(jnp.isnan(x) if valid is None else jnp.isnan(x) & valid)
             counts = all_sum(jnp.stack([count(m) for m in masks]))
             if nans is None:
                 nans, counts = counts[-1], counts[:-1]
-            under = counts.reshape((len(lows), len(digits)) + kept) <= ranks[:, None]
+            under = counts.reshape((k, len(digits)) + kept) <= ranks[:, None]
             pre = pre | (jnp.sum(under, axis=1).astype(pre.dtype) << shift)
         if not with_high:
             return _key_value(pre, x.dtype), None, nans
@@ -473,25 +520,36 @@ def _select_ranks(x, axis: int, lows: tuple, with_high: bool, valid, all_sum, al
         # the smallest above it.  One compare a rank serves both (`above` is
         # its complement), and ONE `reduce` of all the operands is one read of
         # the input by construction: a sum and a minimum as two reductions were
-        # left two fusions by the compiler, two reads
-        k = len(lows)
-        under = [key <= _offset(pre[r]) for r in range(k)]
+        # left two fusions by the compiler, two reads.  The groups' NaNs are
+        # counted here, by the key (they lie beyond the infinities)
+        upto = [counted(r, _offset(pre[r]), jnp.less_equal) for r in range(k)]
+        if member is None:
+            sums, beyond = upto, [jnp.where(u, top, key) for u in upto]
+        else:
+            inf = _order_key(jnp.asarray(jnp.inf, x.dtype))
+            sums = upto + [counted(g, inf, jnp.greater) | counted(g, ~inf, jnp.less) for g in range(k)]
+            beyond = [jnp.where(counted(r, _offset(pre[r]), jnp.greater), key, top) for r in range(k)]
+        n_sums = len(sums)
         found = jax.lax.reduce(
-            [u.astype(jnp.int32) for u in under] + [jnp.where(u, top, key) for u in under],
-            [jnp.int32(0)] * k + [top] * k,
-            lambda a, b: [p + q for p, q in zip(a[:k], b[:k])] + [jnp.minimum(p, q) for p, q in zip(a[k:], b[k:])],
-            (axis,))
-        upto = all_sum(jnp.stack(found[:k])).reshape(pre.shape)
-        above = all_min(_offset(jnp.stack(found[k:]))).reshape(pre.shape)
+            [u.astype(jnp.int32) for u in sums] + beyond,
+            [jnp.int32(0)] * n_sums + [top] * k,
+            lambda a, b: ([p + q for p, q in zip(a[:n_sums], b[:n_sums])]
+                          + [jnp.minimum(p, q) for p, q in zip(a[n_sums:], b[n_sums:])]),
+            axes)
+        sums = all_sum(jnp.stack(found[:n_sums]))
+        if member is not None:
+            nans = sums[k:].reshape(pre.shape)
+        upto = sums[:k].reshape(pre.shape)
+        above = all_min(_offset(jnp.stack(found[n_sums:]))).reshape(pre.shape)
         return _key_value(pre, x.dtype), _key_value(jnp.where(upto >= ranks + 2, pre, above), x.dtype), nans
 
 
-def _select_passes(dtype, ranks: int, with_high: bool) -> int:
+def _select_passes(dtype, ranks: int, with_high: bool, grouped: bool = False) -> int:
     """How many passes over the input :func:`_select_ranks` makes, each of
     them one read of it (``tests/test_chip_compile.py`` counts them in the
-    program compiled for the chip)."""
+    program compiled for the chip); ``grouped``: with ``ranks`` groups."""
     nbits = 64 if dtype == jnp.float64 else 32
-    return -(-nbits // _select_bits(ranks)) + (1 if with_high else 0)
+    return -(-nbits // (_GROUP_BITS if grouped else _select_bits(ranks))) + (1 if with_high else 0)
 
 
 def _interpolate(low, high, nans, plan: tuple, method: str, keepdims: bool, axis: int, scalar_q: bool):

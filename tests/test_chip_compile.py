@@ -590,3 +590,119 @@ def test_selection_over_four_chips_exchanges_counts(comm4, for_the_chip, pad):
     assert reduced[0] == f"s32[10,1,{TABLE_COLS}]" and set(reduced[1:passes - 1]) == {f"s32[3,3,1,{TABLE_COLS}]"}, reduced[:3]
     assert sorted(reduced[-2:]) == [f"s32[3,1,{TABLE_COLS}]", f"u32[3,1,{TABLE_COLS}]"], reduced[-2:]
     assert compiled.memory_analysis().temp_size_in_bytes < 2**26
+
+
+# ------------------------------------------------------------ the KMedians cell (PR 37)
+KMED_ROWS, KMED_COLS, KMED_K = 2**28, 3, 4
+KMED_TABLE_BYTES = KMED_ROWS * 16  # the long axis minor, 3 columns padded to 4 sublanes
+
+
+def _while_bodies(text: str) -> dict:
+    """{body's name: (its instructions, its condition's text)} of a program's loops."""
+    found = {}
+    for cond, body in set(re.findall(r" while\([^)]*\), condition=%([^ ,]+), body=%([^ ,]+)", text)):
+        cond_text = re.search(r"^%" + re.escape(cond) + r" [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+        found[body] = (_computation_instructions(text, body), cond_text)
+    return found
+
+
+#: opcodes that move nothing: a value passed on is not a read of it
+PASSED_ON = ("parameter", "tuple", "get-tuple-element", "bitcast", "opt-barrier")
+
+
+def test_kmedians_loop_at_the_benchmark_cell(one_chip, for_the_chip):
+    """The KMedians cell, 2^28 x 3 points and 4 clusters.  The fit loop makes
+    ONE copy of the table before its first turn, column by column with every
+    register full (3.22 GB for the table's 4.29 as laid out, as KMeans makes
+    its bfloat16 copy), and every turn reads that copy 18 times: the
+    assignment (ONE fusion), 16 counting passes of 2 bits that all four
+    clusters share (the Pallas kernel ``kmedians_count``, one call in a loop
+    of 16 turns) and the neighbours' ``reduce``, = ``passes_an_iteration`` =
+    the root span's ``passes`` = the benchmark's ``kmedians_passes``.  Beside
+    table and copy it holds the labels and the four groups' masks of them (a
+    byte a row each, written once a turn for the neighbours' pass) and under
+    64 MiB more: no ``sort``, no array of rows x clusters, nothing else of the
+    copy's shape (the order key, a masked copy: each was one while the points
+    were a constant of the loop).  The parent's loop (``jnp.nanmedian`` of a
+    masked copy, once a cluster) is refused for 137 GB."""
+    from heat_tpu.cluster import kmedians
+    from heat_tpu.core import kernels
+
+    n, f, k = KMED_ROWS, KMED_COLS, KMED_K
+    packed = f"[{f},{n // kernels.COUNT_LANES},{kernels.COUNT_LANES}]"
+    column = f"[{n // kernels.COUNT_LANES},{kernels.COUNT_LANES}]"
+    compiled = kmedians._programs(None, n, 5, -1.0)[0].lower(
+        _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip)).compile()
+    m = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert " sort(" not in text
+    assert KMED_TABLE_BYTES <= m.argument_size_in_bytes < KMED_TABLE_BYTES + 2**16
+    assert m.temp_size_in_bytes < n * f * 4 + n * 4 + k * n + 2**26 and m.output_size_in_bytes <= 4096
+    assert _device_bytes(compiled) < 0.6 * HBM_BYTES
+    copies = [(opcode, shape) for _, opcode, shape, _ in _entry_instructions(compiled) if packed in shape and opcode not in PASSED_ON]
+    assert copies == [("fusion", "f32" + packed), ("while", copies[-1][1])], copies  # the copy, made once, and the loop that holds it
+    loops = _while_bodies(text)
+    (turn,) = [v for v in loops.values() if any(opcode == "while" for _, opcode, _, _ in v[0])]  # the fit loop holds the passes'
+    (passes,) = [v for v in loops.values() if v is not turn]
+
+    def reads_of_the_copy(body):
+        (copy,) = {name for name, opcode, shape, _ in body if opcode == "get-tuple-element" and shape == "f32" + packed}
+        views = {copy} | {name for name, opcode, _, operands in body if opcode == "bitcast" and copy in operands}
+        return [(opcode, shape) for _, opcode, shape, operands in body if views & set(operands) and opcode not in PASSED_ON]
+
+    # a turn of the fit loop: the assignment (ONE fusion), the counting passes (a loop of their own), the neighbours' `reduce`
+    reads = reads_of_the_copy(turn[0])  # (the passes' loop takes the copy inside a tuple)
+    assert [opcode for opcode, _ in reads] == ["fusion", "fusion"] and reads[0] == ("fusion", "s32" + column), reads
+    # a counting pass: the kernel, ONE call site, 16 turns
+    assert reads_of_the_copy(passes[0]) == [("custom-call", f"s32[{k},4,{f},8,{kernels.COUNT_LANES}]")]
+    assert text.count('custom_call_target="tpu_custom_call"') == len(re.findall(r"%kmedians_count[.\d]* = ", text)) == 1
+    assert re.search(r"constant\(16\)", passes[1]) and kmedians.passes_an_iteration(jnp.float32, k) == 1 + 16 + 1 == 18
+    for body, _ in (turn, passes):
+        made = [(opcode, shape) for _, opcode, shape, _ in body if packed in shape and opcode not in PASSED_ON + ("while",)]
+        assert made == [], made  # nothing of the copy's shape is written
+    # what a turn writes of the rows' length (inside a fusion nothing is written): no rows x clusters among it
+    tall = [(opcode, shape) for _, opcode, shape, _ in turn[0] if column in shape and opcode not in PASSED_ON + ("while",)]
+    assert tall == [("fusion", "s32" + column), ("fusion", "(" + ", ".join(["pred" + column] * k) + ")")], tall
+
+
+def test_kmedians_final_pass_at_the_benchmark_cell(one_chip, for_the_chip):
+    """The fit's last pass: the copy, the labels (one fusion, one read of the
+    copy) and the inertia (one more read); no distances to every center in
+    between, and the labels laid out as the caller's (one pass over them)."""
+    from heat_tpu.cluster import kmedians
+    from heat_tpu.core import kernels
+
+    n, f, k = KMED_ROWS, KMED_COLS, KMED_K
+    packed = f"f32[{f},{n // kernels.COUNT_LANES},{kernels.COUNT_LANES}]"
+    compiled = kmedians._programs(None, n, 5, -1.0)[1].lower(
+        _sds((n, f), jnp.float32, one_chip), _sds((k, f), jnp.float32, one_chip)).compile()
+    m = compiled.memory_analysis()
+    assert n * 4 <= m.output_size_in_bytes < n * 4 + 2**16 and m.temp_size_in_bytes < n * f * 4 + n * 4 + 2**26
+    instructions = _entry_instructions(compiled)
+    (copy,) = [name for name, opcode, shape, _ in instructions if shape == packed and opcode == "fusion"]
+    reads = [(opcode, shape) for _, opcode, shape, operands in instructions if copy in operands and opcode not in PASSED_ON]
+    assert sorted(reads) == [("fusion", "f32[]"), ("fusion", f"s32[{n // kernels.COUNT_LANES},{kernels.COUNT_LANES}]")], reads
+
+
+@pytest.mark.parametrize("pad", [0, 3], ids=["every_row_real", "three_pad_rows"])
+def test_kmedians_over_four_chips_exchanges_counts(comm4, for_the_chip, pad):
+    """Rows split over four chips: each chip assigns and counts in its own
+    rows; what crosses the chips are integers of (clusters x pivots x
+    features) a pass, the clusters' sizes, and the neighbours' counts and
+    minima; nothing is gathered, exchanged or sorted."""
+    from heat_tpu.cluster import kmedians
+
+    n, f, k = KMED_ROWS, KMED_COLS, KMED_K
+    loop, final = kmedians._programs(comm4, n - pad, 5, -1.0)
+    args = (_sds((n, f), jnp.float32, comm4.sharding(0)), _sds((k, f), jnp.float32, comm4.sharding(None)))
+    compiled = loop.lower(*args).compile()
+    text = compiled.as_text()
+    assert "all-gather" not in text and " sort(" not in text and "all-to-all" not in text
+    reduced = [re.sub(r"\{[^}]*\}", "", r) for r in re.findall(r"= (.*?) all-reduce(?:-start)?\(", text)]
+    # the sizes, a pass's counts (a digit's a group a column; once in the text: the passes are a loop), the neighbours' sums and minima
+    assert sorted(reduced) == sorted([f"s32[{k}]", f"s32[{k},4,{f}]", f"s32[{2 * k},{f}]", f"u32[{k},{f},1,1]"]), reduced
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < (n // 4) * (f * 4 + 4 + k) + 2**26  # a chip's copy, labels and masks
+    text = final.lower(*args).compile().as_text()
+    assert "all-gather" not in text and " sort(" not in text
+    assert [re.sub(r"\{[^}]*\}", "", r) for r in re.findall(r"= (.*?) all-reduce(?:-start)?\(", text)] == ["f32[]"]

@@ -9,6 +9,8 @@ The route is decided from the extent along the axis; the tests steer the
 threshold down so that small arrays take it.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,3 +158,142 @@ def test_out_of_range_q_still_raises():
     with pytest.raises(ValueError, match="interpolation"):
         ht.percentile(x, 50.0, axis=0, interpolation="cubic")
     assert np.array_equal(ht.percentile(x, 50.0, axis=0).numpy(), np.zeros(2, np.float32))
+
+
+# ------------------------------------------------------ by group, ranks on the device
+GROUPS = 4
+
+
+def _grouped_values(dtype):
+    """Five columns under four labels: duplicates, a tie that holds a group's
+    middle, both zeros, both infinities, negative values; group 2 small,
+    label 4 a row that belongs to no group."""
+    rng = np.random.default_rng(12)
+    a = (rng.standard_normal((211, 5)) * [1, 10, 0.1, 1, 100]).astype(dtype)
+    a[:, 1] = np.round(a[:, 1])
+    a[:90, 3] = 1.5
+    a[10:20, 2], a[20:30, 2] = 0.0, -0.0
+    a[5, 4], a[7, 4] = np.inf, -np.inf
+    labels = rng.integers(0, GROUPS, 211).astype(np.int32)
+    labels[labels == 2] = np.where(rng.random((labels == 2).sum()) < 0.8, 0, 2)
+    labels[::37] = GROUPS
+    return a, labels
+
+
+def _packed(a, labels):
+    """The table as the grouped selection takes it: `kernels.pack_columns`'
+    copy, and the labels in one column's shape, no group's behind the last row."""
+    from heat_tpu.core import kernels
+
+    cols = kernels.pack_columns(jnp.asarray(a))
+    behind = cols.shape[1] * cols.shape[2] - len(labels)
+    return cols, jnp.pad(jnp.asarray(labels), (0, behind), constant_values=GROUPS).reshape(cols.shape[1:])
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "groups"))
+def _grouped(cols, labels, ranks, bits, groups=GROUPS):
+    """(low, high, nans), each groups x columns; ``ranks`` groups x columns or groups x 1."""
+    real = statistics._GROUP_BITS
+    statistics._GROUP_BITS = bits
+    try:
+        found = statistics._select_ranks(cols, (1, 2), ranks[:, :, None, None], True, None, lambda v: v, lambda v: v,
+                                         group=(labels, groups))
+        return tuple(v[:, :, 0, 0] for v in found)
+    finally:
+        statistics._GROUP_BITS = real
+
+
+@pytest.mark.parametrize("bits", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_every_groups_every_rank_bit_for_bit(bits, dtype):
+    """``_select_ranks`` with a group and ranks that are device values: for
+    every group, every rank and its upper neighbour are the elements
+    ``np.sort`` of the group's members puts there, to the bit but for a zero's
+    sign (``np.sort`` leaves -0.0 and 0.0 as they came; the key puts -0.0
+    first), at 1 and 2 bits a pass (what the counting kernel packs into a
+    lane).  One compiled program serves
+    every rank: they are its arguments."""
+    a, labels = _grouped_values(dtype)
+    members = [np.sort(a[labels == g], axis=0) for g in range(GROUPS)]
+    bits_of = lambda v: (np.asarray(v) + 0.0).view(np.int32 if dtype == "float32" else np.int64)  # noqa: E731
+    x, lab = _packed(a, labels)
+    for r in range(max(len(m) for m in members)):
+        ranks = np.asarray([min(r, len(m) - 1) for m in members], np.int32)
+        low, high, nans = _grouped(x, lab, jnp.asarray(ranks)[:, None], bits=bits)
+        assert low.shape == high.shape == nans.shape == (GROUPS, 5) and not np.asarray(nans).any()
+        for g, m in enumerate(members):
+            assert np.array_equal(bits_of(low[g]), bits_of(m[ranks[g]])), (g, r)
+            if ranks[g] + 1 < len(m):  # the largest member has no neighbour, and nothing reads one
+                assert np.array_equal(bits_of(high[g]), bits_of(m[ranks[g] + 1])), (g, r)
+
+
+def test_ranks_of_a_group_may_differ_by_column():
+    a, labels = _grouped_values("float32")
+    members = [np.sort(a[labels == g], axis=0) for g in range(GROUPS)]
+    ranks = np.random.default_rng(3).integers(0, [[len(m)] for m in members], (GROUPS, 5)).astype(np.int32)
+    low, _, _ = _grouped(*_packed(a, labels), jnp.asarray(ranks), bits=2)
+    want = np.stack([m[ranks[g], np.arange(5)] for g, m in enumerate(members)])
+    assert np.array_equal(np.asarray(low), want)
+
+
+def test_nans_are_counted_by_group_and_an_empty_group_harms_no_other():
+    a, labels = _grouped_values("float32")
+    a[np.flatnonzero(labels == 0)[:3], 2] = np.nan
+    a[np.flatnonzero(labels == 3)[0], 4] = -np.nan
+    labels[labels == 2] = GROUPS  # group 2 is empty
+    want = np.zeros((GROUPS, 5), np.int32)
+    want[0, 2], want[3, 4] = 3, 1
+    ranks = jnp.asarray([(np.sum(labels == g) - 1) // 2 if np.any(labels == g) else 0 for g in range(GROUPS)], jnp.int32)
+    low, high, nans = _grouped(*_packed(a, labels), ranks[:, None], bits=2)
+    assert np.array_equal(np.asarray(nans), want)
+    for g in (0, 1, 3):
+        clean = [c for c in range(5) if not want[g, c]]
+        m = np.sort(a[labels == g], axis=0)
+        assert np.array_equal(np.asarray(low)[g, clean], m[int(ranks[g])][clean])
+
+
+def test_the_ungrouped_selection_is_the_case_of_no_group():
+    """The static ranks of ``percentile`` on the table as it lies, and the
+    same ranks as one group's device values on its packed copy (the counts
+    then the kernel's), give the same elements."""
+    a, _ = _grouped_values("float32")
+    static = jax.jit(lambda x: statistics._select_ranks(x, 0, (52, 105), True, None, lambda v: v, lambda v: v))(jnp.asarray(a))
+    cols, one_group = _packed(a, np.zeros(len(a), np.int32))
+    for i, r in enumerate((52, 105)):
+        low, high, _ = _grouped(cols, one_group, jnp.full((1, 1), r, jnp.int32), bits=2, groups=1)
+        assert np.array_equal(np.asarray(low[0]), np.asarray(static[0][i, 0]))
+        assert np.array_equal(np.asarray(high[0]), np.asarray(static[1][i, 0]))
+
+
+@pytest.mark.parametrize("rows", [1000, 140000], ids=["one_block", "two_blocks"])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_the_counting_kernel_counts_digits_by_group(dtype, rows):
+    """``kernels.grouped_digit_counts`` (through the interpreter here): for
+    every group and column, how many of the group's keys hold each digit,
+    among those that agree with the group's settled bits above it; the first
+    pass (no bit above), a middle one, the last, and one bit a pass; rows
+    under a label that names no group, and the zeros behind the last row,
+    count nowhere."""
+    from heat_tpu.core import kernels
+
+    rng = np.random.default_rng(0)
+    a = (rng.standard_normal((rows, 3)) * [1, 100, 0.01]).astype(dtype)
+    a[5, 0], a[7, 1] = -0.0, np.inf
+    lab = rng.integers(0, GROUPS + 1, rows).astype(np.int32)
+    cols, labels = _packed(a, lab)
+    assert cols.shape == (3, 256 * -(-rows // (256 * 512)), 512) and np.array_equal(np.asarray(cols).reshape(3, -1)[:, :rows], a.T)
+    key = np.asarray(statistics._offset(statistics._order_key(jnp.asarray(a))))
+    nb, u = key.dtype.itemsize * 8, key.dtype.type
+    settled = np.stack([key[np.flatnonzero(lab == g)[0]] for g in range(GROUPS)])  # some member's key, a group
+    for shift, bits in ((nb - 2, 2), (nb - 4, 2), (10, 2), (0, 2), (7, 1)):
+        above = u(shift + bits)
+        prefix = settled >> above << above if shift + bits < nb else np.zeros_like(settled)
+        got = np.asarray(kernels.grouped_digit_counts(cols, labels, jnp.asarray(prefix), shift, bits, GROUPS))
+        want = np.zeros((GROUPS, 1 << bits, 3), np.int64)
+        for g in range(GROUPS):
+            for c in range(3):
+                mine = key[lab == g, c]
+                if shift + bits < nb:
+                    mine = mine[mine >> above == prefix[g, c] >> above]
+                want[g, :, c] = np.bincount(((mine >> u(shift)) & u((1 << bits) - 1)).astype(np.int64), minlength=1 << bits)
+        assert np.array_equal(got, want), (shift, bits)
