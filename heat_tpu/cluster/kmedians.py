@@ -6,10 +6,11 @@ register full (`kernels.pack_columns`), and an iteration is one assignment
 over it (Manhattan distances, first index on ties, no ``rows x clusters``
 array written) and ONE grouped selection of every cluster's median:
 `statistics._select_ranks` with the labels as the group, counting passes over
-the order key that all clusters share (the kernel
-`kernels.grouped_digit_counts`), no sorted copy and no masked copy of the
-points.  Over a mesh each device counts in its own rows and the counts are
-all-reduced.
+the order key that all clusters share and one more for the upper neighbours
+(the two bodies of the kernel, `kernels.grouped_digit_counts` and
+`kernels.grouped_neighbours`: the selection reads the points through them
+alone), no sorted copy and no masked copy of the points.  Over a mesh each
+device counts in its own rows and the counts are all-reduced.
 """
 
 from __future__ import annotations
@@ -81,17 +82,15 @@ def _medians(cols, labels, centers, all_sum, all_min):
     element of rank ``(count - 1) // 2``, or the mean of it and the next one
     where the count is even; NaN where a member is), exact, all clusters in
     the same counting passes.  The ranks are device values: a cluster's count
-    is known only after the assignment.  An empty cluster keeps its center."""
+    is known only after the assignment, and the selection's first pass counts
+    it (no pass over the labels here).  An empty cluster keeps its center."""
     k = centers.shape[0]
     with jax.named_scope("kmedians.select"):
-        groups = jnp.arange(k, dtype=labels.dtype)[:, None, None]
-        counts = all_sum(jnp.sum(labels[None] == groups, axis=(1, 2), dtype=jnp.int32))
-        ranks = (jnp.maximum(counts, 1) - 1) // 2
-        low, high, nans = (v[:, :, 0, 0] for v in statistics._select_ranks(
-            cols, (1, 2), ranks[:, None, None, None], True, None, all_sum, all_min, group=(labels, k)))
-        median = jnp.where((counts % 2 == 0)[:, None], 0.5 * (low + high), low)
+        low, high, nans, sizes = (v[:, :, 0, 0] for v in statistics._select_ranks(
+            cols, (1, 2), lambda sizes: (jnp.maximum(sizes, 1) - 1) // 2, True, None, all_sum, all_min, group=(labels, k)))
+        median = jnp.where(sizes % 2 == 0, 0.5 * (low + high), low)
         median = jnp.where(nans > 0, jnp.nan, median).astype(centers.dtype)
-        return jnp.where(counts[:, None] > 0, median, centers)
+        return jnp.where(sizes > 0, median, centers)
 
 
 def passes_an_iteration(dtype, k: int) -> int:
@@ -228,7 +227,9 @@ class KMedians(_KCluster):
         if not types.heat_type_is_inexact(x.dtype):
             xp = xp.astype(jnp.float32)
         dtype = xp.dtype
-        plan = dict(bits=statistics._GROUP_BITS, passes=passes_an_iteration(dtype, k))
+        # of a turn's reads of the points, the selection's are calls of a kernel, every one
+        plan = dict(bits=statistics._GROUP_BITS, passes=passes_an_iteration(dtype, k),
+                    kernel_passes=statistics._select_passes(dtype, k, True, grouped=True))
         on = dict(n_true=n, comm=x.comm if x.split == 0 and x.comm.size > 1 else None)
 
         def init_centers():

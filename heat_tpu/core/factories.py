@@ -104,7 +104,9 @@ def array(
     if isinstance(obj, (jax.Array, jnp.ndarray)):
         data = obj
     else:
-        data = np.asarray(obj, order=order)
+        # `copy=True` copies HERE: on the CPU `jnp.asarray` takes an aligned numpy buffer as it is, so a caller's
+        # later write to `obj` would show in the array (by the luck of the allocation: tests/test_factories_grid.py)
+        data = np.array(obj, order=order, copy=True) if copy else np.asarray(obj, order=order)
 
     if dtype is not None:
         dtype = types.canonical_heat_type(dtype)

@@ -1,13 +1,15 @@
-"""The hand-written Pallas kernels of the array core: ``gram_syrk`` and
-``grouped_digit_counts``.
+"""The hand-written Pallas kernels of the array core: ``gram_syrk`` and the
+two bodies of a selection by group, ``grouped_digit_counts`` and
+``grouped_neighbours``.
 
 The framework's compute path is XLA-compiled jnp; a Pallas kernel exists
 only where a trace demands it, that is where a device trace shows XLA
 streaming an operand more often than the algorithm needs, or spending
 several times the memory's time on operations a kernel can do without, and
 a benchmark cell shows the kernel ahead.  ``grouped_digit_counts`` is one
-counting pass of KMedians' grouped selection (its section below has the
-readings; ``kmedians-spheres3d.loop1`` times it).  ``gram_syrk`` is hSVD's Gram pass: XLA lowers
+counting pass of KMedians' grouped selection and ``grouped_neighbours`` its
+last pass (their section below has the readings; ``kmedians-spheres3d.loop1``
+times them).  ``gram_syrk`` is hSVD's Gram pass: XLA lowers
 ``x.T @ x`` as a generic dot with two operand streams, the kernel reads each
 row tile of ``x`` once, at the rate the chip's memory streams (8.51 ms for
 6.44 GB, 757 GB/s; ``hsvd-tallskinny.loop1`` times it, ``gram_syrk_ms``).
@@ -31,6 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = [
     "gram_syrk",
     "grouped_digit_counts",
+    "grouped_neighbours",
     "pack_columns",
     "syrk_supported",
 ]
@@ -156,8 +159,8 @@ def gram_syrk(x: jax.Array) -> jax.Array:
 
 
 # ----------------------------------------------------------------------
-# grouped digit counts: one counting pass of a selection by group
-# (KMedians' per-cluster medians), all groups in ONE read of the values.
+# a selection by group (KMedians' per-cluster medians): one counting pass
+# and the neighbours' pass, all groups in ONE read of the values each.
 #
 # As XLA writes the pass on the table as it lies (rows x 3, the long axis
 # minor, a quarter of every register padding: one compare, one mask, one
@@ -172,6 +175,24 @@ def gram_syrk(x: jax.Array) -> jax.Array:
 # on the table itself (columns on the sublanes, three of eight used, the
 # groups' bits broadcast along the lanes in every step) the same kernel read
 # 51.8 ms a pass (chip run, PR 37).
+#
+# The selection's last pass, the smallest key above each group's median's,
+# is a second body on the same operands, block and step
+# (`grouped_neighbours`; PR 38), so that a grouped selection reads the copy
+# through 17 calls of a kernel and no fusion reads it: as one XLA `reduce`
+# of twelve operands over the copy and four masks of the labels the pass
+# read 14.4 + 3.25 ms.  What holds the bodies (chip run, PR 38, step 0,
+# 2^28 x 3 float32 and four groups, ms a pass; the plain read of copy and
+# labels as an XLA fusion 6.99):
+#
+#   operations a value    20      23      25 (counts)   31
+#   accumulators          16      16      16            32 registers
+#   ms a pass             6.10    6.33    6.25          8.01
+#
+# Up to some 25 operations the memory's 6.1 ms hide them (704 GB/s); at
+# 31, a count of each group's NaNs in accumulators of its own, they bind.
+# So the NaNs cost two operations, not eleven: a NaN is made the smallest
+# key there is, and its group's minimum tells of it (the 23 above).
 # ----------------------------------------------------------------------
 #: the packed copy's rows are this long, and one step of the kernel's inner
 #: loop holds eight of them in registers
@@ -227,6 +248,58 @@ def _count_kernel(shift_ref, phi_ref, x_ref, lab_ref, out_ref, *, groups, bits):
                 out_ref[g, b, c] += jax.lax.shift_right_logical(accs[g], jnp.int32(8 * b)) & 0xFF
 
 
+def _neighbours_kernel(pivot_ref, x_ref, lab_ref, above_ref, *, groups):
+    f = x_ref.shape[0]
+    kdt, nbits = (jnp.int64, 64) if x_ref.dtype == jnp.float64 else (jnp.int32, 32)
+    some = (8, COUNT_LANES)  # what one step holds of a column
+    top, bottom = kdt(jnp.iinfo(kdt).max), kdt(jnp.iinfo(kdt).min)
+    inf = kdt(((1 << jnp.finfo(x_ref.dtype).nexp) - 1) << jnp.finfo(x_ref.dtype).nmant)  # the bits of +inf: a NaN's lie above, the sign apart
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        above_ref[...] = jnp.full_like(above_ref, top)
+
+    for c in range(f):
+        def step(j, lows, c=c):
+            at = pl.multiple_of(j * 8, 8)
+            s = jax.lax.bitcast_convert_type(x_ref[c, pl.ds(at, 8), :], kdt)
+            key = s ^ ((s >> (nbits - 1)) & top)  # the signed order key: the lower bits flipped for a negative value
+            lab = lab_ref[pl.ds(at, 8), :]
+            member = [lab == g for g in range(groups)]
+            pivot = jnp.full(some, pivot_ref[(groups - 1) * f + c], kdt)
+            for g in range(groups - 2, -1, -1):
+                pivot = jnp.where(member[g], pivot_ref[g * f + c], pivot)
+            beyond = jnp.where(key > pivot, key, top)  # above ITS group's key, or the minimum's identity
+            beyond = jnp.where((s & top) > inf, bottom, beyond)  # a NaN: under every number's key, so the minimum tells of it
+            return tuple(jnp.minimum(low, jnp.where(m, beyond, top)) for low, m in zip(lows, member))
+
+        lows = jax.lax.fori_loop(0, x_ref.shape[1] // 8, step, tuple(jnp.full(some, top, kdt) for _ in range(groups)))
+        for g in range(groups):
+            above_ref[g, c] = jnp.minimum(above_ref[g, c], lows[g])
+
+
+def _grouped_call(kernel, name: str, out_shapes, scalars, packed: jax.Array, labels: jax.Array):
+    """One pass of ``kernel`` over `pack_columns`' copy and its labels, a block
+    of ``COUNT_ROWS`` rows a grid step; ``scalars`` are prefetched, the outputs
+    (``out_shapes``: a few registers a group and column each) stay where they
+    are from the first block to the last.  The device trace names the custom
+    call by ``name`` (``%<name>.N``)."""
+    f, rows, lanes = packed.shape
+    assert labels.shape == (rows, lanes) and lanes == COUNT_LANES and rows % COUNT_ROWS == 0
+    return pl.pallas_call(
+        kernel,
+        out_shape=out_shapes,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(rows // COUNT_ROWS,),
+            in_specs=[pl.BlockSpec((f, COUNT_ROWS, lanes), lambda i, *_: (0, i, 0)),
+                      pl.BlockSpec((COUNT_ROWS, lanes), lambda i, *_: (i, 0))],
+            out_specs=[pl.BlockSpec(o.shape, lambda i, *_, n=len(o.shape): (0,) * n) for o in out_shapes]),
+        interpret=_interpret(),
+        name=name,
+    )(*scalars, packed, labels)
+
+
 def grouped_digit_counts(packed: jax.Array, labels: jax.Array, prefix: jax.Array, shift, bits: int, groups: int) -> jax.Array:
     """For each of ``groups`` groups and each column of ``packed``
     (`pack_columns`' copy: columns x R x ``COUNT_LANES``, float32; float64
@@ -243,23 +316,31 @@ def grouped_digit_counts(packed: jax.Array, labels: jax.Array, prefix: jax.Array
 
     ONE read of the values and of the labels, whatever ``groups``; nothing of
     their size is written."""
-    f, rows, lanes = packed.shape
-    assert 1 <= bits <= 2 and labels.shape == (rows, lanes) and lanes == COUNT_LANES and rows % COUNT_ROWS == 0
+    f, lanes = packed.shape[0], packed.shape[2]
+    assert 1 <= bits <= 2
     kdt = jnp.int64 if packed.dtype == jnp.float64 else jnp.int32
     shift = jnp.asarray(shift, jnp.int32).reshape(1)
     # the bits above the digit, in two shifts: the first pass's digit is the top one, and no shift takes all the bits
     above = jax.lax.shift_right_logical(jax.lax.bitcast_convert_type(prefix, kdt), jnp.broadcast_to(shift.astype(kdt), prefix.shape))
     phi = jax.lax.shift_right_logical(above, jnp.full(prefix.shape, bits, kdt)).reshape(groups * f)
-    counts = pl.pallas_call(
-        functools.partial(_count_kernel, groups=groups, bits=bits),
-        out_shape=jax.ShapeDtypeStruct((groups, 1 << bits, f, 8, lanes), jnp.int32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(rows // COUNT_ROWS,),
-            in_specs=[pl.BlockSpec((f, COUNT_ROWS, lanes), lambda i, *_: (0, i, 0)),
-                      pl.BlockSpec((COUNT_ROWS, lanes), lambda i, *_: (i, 0))],
-            out_specs=pl.BlockSpec((groups, 1 << bits, f, 8, lanes), lambda i, *_: (0, 0, 0, 0, 0))),
-        interpret=_interpret(),
-        name="kmedians_count",  # the device trace names the custom call by it (%kmedians_count.N)
-    )(shift, phi, packed, labels)
+    (counts,) = _grouped_call(functools.partial(_count_kernel, groups=groups, bits=bits), "kmedians_count",
+                              [jax.ShapeDtypeStruct((groups, 1 << bits, f, 8, lanes), jnp.int32)], (shift, phi), packed, labels)
     return jnp.sum(counts, axis=(-2, -1))
+
+
+def grouped_neighbours(packed: jax.Array, labels: jax.Array, pivots: jax.Array, groups: int) -> jax.Array:
+    """For each of ``groups`` groups and each column of ``packed``, over the
+    group's values alone (``packed``, ``labels`` as `grouped_digit_counts`
+    takes them): the smallest order key strictly above the group's ``pivots``
+    entry (groups x columns, the SIGNED key, as the result), the key type's
+    largest value where none lies above, and its SMALLEST value, which is no
+    number's key, where a NaN is among the group's values: a minimum over
+    several devices' results keeps that evidence.
+
+    The counting passes' operands, block and step in a body of its own: ONE
+    read of the values and of the labels, nothing of their size written."""
+    f, lanes = packed.shape[0], packed.shape[2]
+    kdt = jnp.int64 if packed.dtype == jnp.float64 else jnp.int32
+    (above,) = _grouped_call(functools.partial(_neighbours_kernel, groups=groups), "kmedians_neighbours",
+                             [jax.ShapeDtypeStruct((groups, f, 8, lanes), kdt)], (pivots.reshape(groups * f),), packed, labels)
+    return jnp.min(above, axis=(-2, -1))

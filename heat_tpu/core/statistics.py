@@ -440,41 +440,22 @@ def _select_ranks(x, axis: int, lows, with_high: bool, valid, all_sum, all_min, 
     ``valid`` masks the canonical padding along a split axis (None: no
     padding).
 
-    With ``group=(labels, k)`` the statistics are taken group by group:
-    ``x`` is then `kernels.pack_columns`' copy of a table (columns x R x
-    lanes), ``axis`` its last two axes, ``labels`` (R x lanes, int32) name
-    each value's group, any other number none (so a caller's padding), and
-    ``lows`` is a DEVICE array of shape ``(k, columns, 1, 1)`` (or
-    broadcastable to it), one rank a group, which only the caller's counts
-    know.  A group is then what a rank is without: its own pivots and its own
-    counts, over its own values alone, and all ``k`` groups share every pass.
-    The counts of a pass come from the kernel ``kernels.grouped_digit_counts``
-    (``_GROUP_BITS`` a pass: its docstring has why), as each digit's count
-    among the keys that agree with the group's settled bits, and are made
-    the pivots' counts here.  The rank of an empty group selects nothing a
-    caller may use; the NaNs are counted by group, in the neighbours' pass,
-    which a grouped call always makes (``with_high``)."""
+    With ``group=(labels, k)`` the statistics are taken group by group
+    (:func:`_select_by_group`, which has the arguments' meaning there and
+    returns the groups' sizes as a fourth value): every pass is then a call
+    of a Pallas kernel on a packed copy of the table, 17 of them at 2 bits a
+    pass and a 32-bit key, and no fusion reads the copy."""
+    if group is not None:
+        assert x.ndim == 3 and axis == (1, 2) and valid is None and with_high
+        return _select_by_group(x, *group, lows, all_sum, all_min)
     key = _order_key(x)
     top = key.dtype.type(jnp.iinfo(key.dtype).max)
     if valid is not None:
         key = jnp.where(valid, key, top)  # padding sorts last, under no pivot
     nbits = 8 * key.dtype.itemsize
-    axes = axis if isinstance(axis, tuple) else (axis,)
-    kept = tuple(1 if d in axes else s for d, s in enumerate(x.shape))  # the `keepdims` shape
-    if group is None:
-        k = len(lows)
-        ranks = jnp.asarray(lows, jnp.int32).reshape((-1,) + (1,) * x.ndim)
-        member = None
-    else:
-        labels, k = group
-        assert x.ndim == 3 and axes == (1, 2) and valid is None and with_high
-        ranks = lows.astype(jnp.int32)
-        member = [labels == g for g in range(k)]
-
-    def counted(r, pivot, cmp):
-        """Which elements rank ``r`` counts: those that compare with the pivot, of its group."""
-        return cmp(key, pivot) if member is None else cmp(key, pivot) & member[r]
-
+    kept = tuple(1 if d == axis else s for d, s in enumerate(x.shape))  # the `keepdims` shape
+    k = len(lows)
+    ranks = jnp.asarray(lows, jnp.int32).reshape((-1,) + (1,) * x.ndim)
     # every rank's settled bits in ONE array: the pass's reductions then
     # share their operands and the compiler makes them one fusion, one read
     # of the input (a list of arrays a rank read the table three times a pass)
@@ -483,24 +464,9 @@ def _select_ranks(x, axis: int, lows, with_high: bool, valid, all_sum, all_min, 
     def count(mask):
         return jnp.sum(mask, axis=axis, keepdims=True, dtype=jnp.int32)
 
-    def grouped_pass(turn, settled):
-        """One pass by group: each digit's count among the keys in the
-        group's range, made the pivots' counts by what lies under the range
-        (the count at the pivot chosen the pass before)."""
-        pre, below = settled
-        shift = nbits - _GROUP_BITS * (turn + 1)
-        held = all_sum(kernels.grouped_digit_counts(x, labels, pre[:, :, 0, 0], shift, _GROUP_BITS, k))
-        counts = below + jnp.cumsum(held, axis=1, dtype=jnp.int32)[:, :-1, :, None, None]
-        found = counts <= ranks[:, None]
-        digit = jnp.sum(found, axis=1).astype(pre.dtype)
-        return pre | (digit << shift.astype(pre.dtype)), jnp.max(jnp.where(found, counts, below), axis=1, keepdims=True)
-
     with jax.named_scope("quantile.count"):
         nans = None
-        if member is not None:  # the passes as a loop: ONE call of the kernel in the program
-            below = jnp.zeros((k, 1) + kept, jnp.int32)  # a group's keys under its settled bits' range
-            pre, _ = jax.lax.fori_loop(0, nbits // _GROUP_BITS, grouped_pass, (pre, below))
-        shift = nbits if member is None else 0  # by group every bit is settled by now
+        shift = nbits
         step = _select_bits(k)
         while shift > 0:
             bits = step if shift >= step else shift  # `min` is this module's reduction
@@ -520,28 +486,71 @@ def _select_ranks(x, axis: int, lows, with_high: bool, valid, all_sum, all_min, 
         # the smallest above it.  One compare a rank serves both (`above` is
         # its complement), and ONE `reduce` of all the operands is one read of
         # the input by construction: a sum and a minimum as two reductions were
-        # left two fusions by the compiler, two reads.  The groups' NaNs are
-        # counted here, by the key (they lie beyond the infinities)
-        upto = [counted(r, _offset(pre[r]), jnp.less_equal) for r in range(k)]
-        if member is None:
-            sums, beyond = upto, [jnp.where(u, top, key) for u in upto]
-        else:
-            inf = _order_key(jnp.asarray(jnp.inf, x.dtype))
-            sums = upto + [counted(g, inf, jnp.greater) | counted(g, ~inf, jnp.less) for g in range(k)]
-            beyond = [jnp.where(counted(r, _offset(pre[r]), jnp.greater), key, top) for r in range(k)]
-        n_sums = len(sums)
+        # left two fusions by the compiler, two reads
+        under = [key <= _offset(pre[r]) for r in range(k)]
         found = jax.lax.reduce(
-            [u.astype(jnp.int32) for u in sums] + beyond,
-            [jnp.int32(0)] * n_sums + [top] * k,
-            lambda a, b: ([p + q for p, q in zip(a[:n_sums], b[:n_sums])]
-                          + [jnp.minimum(p, q) for p, q in zip(a[n_sums:], b[n_sums:])]),
-            axes)
-        sums = all_sum(jnp.stack(found[:n_sums]))
-        if member is not None:
-            nans = sums[k:].reshape(pre.shape)
-        upto = sums[:k].reshape(pre.shape)
-        above = all_min(_offset(jnp.stack(found[n_sums:]))).reshape(pre.shape)
+            [u.astype(jnp.int32) for u in under] + [jnp.where(u, top, key) for u in under],
+            [jnp.int32(0)] * k + [top] * k,
+            lambda a, b: [p + q for p, q in zip(a[:k], b[:k])] + [jnp.minimum(p, q) for p, q in zip(a[k:], b[k:])],
+            (axis,))
+        upto = all_sum(jnp.stack(found[:k])).reshape(pre.shape)
+        above = all_min(_offset(jnp.stack(found[k:]))).reshape(pre.shape)
         return _key_value(pre, x.dtype), _key_value(jnp.where(upto >= ranks + 2, pre, above), x.dtype), nans
+
+
+def _select_by_group(cols, labels, k: int, lows, all_sum, all_min):
+    """:func:`_select_ranks` group by group: ``cols`` is `kernels.pack_columns`'
+    copy of a table (columns x R x lanes), ``labels`` (R x lanes, int32) name
+    each value's group, any other number none (so a caller's padding).  A
+    group is what a rank is without: its own pivots and its own counts, over
+    its own values alone, and all ``k`` groups share every pass.  Returns the
+    value of each group's rank, of the next rank (of no use where the group
+    holds a NaN), 1 where it holds a NaN and 0 where none, and the group's
+    size (NaNs included), each ``(k, columns, 1, 1)``.
+
+    ``lows`` is one rank a group, which only the device knows: a DEVICE
+    array of shape ``(k, columns, 1, 1)`` (or broadcastable to it), or a
+    function that makes such ranks of the groups' sizes (``(k, columns)``,
+    int32).  The rank of an empty group selects nothing a caller may use.
+
+    The points are read through the two bodies of the kernel alone, on the
+    packed copy: ``32 / _GROUP_BITS`` counting passes
+    (``kernels.grouped_digit_counts``: each digit's count among the keys that
+    agree with the group's settled bits, made the pivots' counts here) in a
+    loop, ONE call of the kernel in the program, and the neighbours' pass
+    (``kernels.grouped_neighbours``: the smallest key above each group's, or
+    the evidence of a NaN in its place).  What the passes have counted is not
+    read again: with no bit settled every member's key is in its group's
+    range, so the first pass's total is the group's size, and the last pass's
+    count at the chosen digit is the count at or under the group's key."""
+    wide = cols.dtype == jnp.float64
+    nbits = 64 if wide else 32
+    shape = (k, cols.shape[0])
+    rank_of = lows if callable(lows) else lambda sizes: jnp.broadcast_to(lows.astype(jnp.int32).reshape(k, -1), shape)
+
+    def counting_pass(turn, settled):
+        pre, below, _, sizes = settled  # the settled bits, the group's keys under their range, (of the last pass) at or under it
+        shift = nbits - _GROUP_BITS * (turn + 1)
+        held = all_sum(kernels.grouped_digit_counts(cols, labels, pre, shift, _GROUP_BITS, k))
+        upto = below[:, None] + jnp.cumsum(held, axis=1, dtype=jnp.int32)  # at or under each digit's range
+        under, total = upto[:, :-1], upto[:, -1]  # under each pivot; in the range and under it
+        sizes = jnp.where(turn == 0, total, sizes)
+        ranks = rank_of(sizes)[:, None]
+        found = under <= ranks
+        digit = jnp.sum(found, axis=1).astype(pre.dtype)
+        return (pre | (digit << shift.astype(pre.dtype)),
+                jnp.max(jnp.where(found, under, below[:, None]), axis=1),
+                jnp.min(jnp.where(upto > ranks, upto, total[:, None]), axis=1),  # the first that passes the rank: the chosen digit's
+                sizes)
+
+    with jax.named_scope("quantile.count"):
+        nothing = jnp.zeros(shape, jnp.int32)
+        pre, _, upto, sizes = jax.lax.fori_loop(
+            0, nbits // _GROUP_BITS, counting_pass, (jnp.zeros(shape, jnp.uint64 if wide else jnp.uint32), nothing, nothing, nothing))
+        above = all_min(_offset(kernels.grouped_neighbours(cols, labels, _offset(pre), k)))
+        nans = (above == 0).astype(jnp.int32)  # the smallest key there is, no number's: a NaN among the members made the minimum that
+        high = jnp.where(upto >= rank_of(sizes) + 2, pre, above)  # the same key where ties reach that far
+        return tuple(v[:, :, None, None] for v in (_key_value(pre, cols.dtype), _key_value(high, cols.dtype), nans, sizes))
 
 
 def _select_passes(dtype, ranks: int, with_high: bool, grouped: bool = False) -> int:

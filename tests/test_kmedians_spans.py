@@ -56,7 +56,8 @@ def test_fit_leaves_its_spans(one_device, clusters, bits=2, passes=18):
     """The root carries the plan: reads of the points an iteration, the
     assignment's and the selection's (16 counting passes of 2 bits, whatever
     the number of clusters: the kernel's cost hardly grows with them; and the
-    neighbours')."""
+    neighbours').  The loop's span says how many of them are calls of a
+    kernel: the selection's, all 17 (PR 38; the counting passes' 16 before)."""
     a = _data()
     _fit(a, clusters)  # the first call compiles: `dispatch.compile` of the eager helpers lands here
     telemetry.clear_spans()
@@ -66,7 +67,7 @@ def test_fit_leaves_its_spans(one_device, clusters, bits=2, passes=18):
     root, kids = spans[0], spans[1:]
     assert root.depth == 0 and root.attrs == {"rows": ROWS, "features": COLS, "clusters": clusters, "max_iter": 5, "passes": passes}
     assert passes == kmedians.passes_an_iteration(np.float32, clusters) == 2 + -(-32 // bits)
-    assert [k.attrs for k in kids] == [{}, {"bits": bits, "passes": passes}, {}]
+    assert [k.attrs for k in kids] == [{}, {"bits": bits, "passes": passes, "kernel_passes": passes - 1}, {}]
     assert bits == statistics._GROUP_BITS
     for kid, after in zip(kids, [root.start_ns] + [_end(k) for k in kids]):
         assert kid.depth == 1 and after <= kid.start_ns and _end(kid) <= _end(root)
@@ -93,7 +94,7 @@ def test_a_resumable_fit_initializes_inside_its_loop(one_device, tmp_path):
     loop, init = by_name["kmedians.loop"], by_name["kmedians.init"]
     assert (loop.depth, init.depth, by_name["kmedians.assign"].depth) == (1, 2, 1)
     assert loop.start_ns <= init.start_ns and _end(init) <= _end(loop)
-    assert by_name[ROOT].attrs["passes"] == loop.attrs["passes"] == 18
+    assert by_name[ROOT].attrs["passes"] == loop.attrs["passes"] == 18 and loop.attrs["kernel_passes"] == 17
 
 
 def test_tracing_off_leaves_nothing_and_changes_no_result(one_device):
@@ -138,13 +139,16 @@ def test_the_benchmarks_readers(one_device, metric, want):
     assert reader.read(run) is None and metric in run["notes"]
 
 
-def test_the_kernels_readers(one_device):
+@pytest.mark.parametrize("second_body", [False, True], ids=["count_alone", "beside_the_neighbours"])
+def test_the_kernels_readers(one_device, second_body):
     """``kmedians_count_ms`` and ``kmedians_count_roofline_pct`` read the
     kernel by its name in the reduced device trace; how many passes a solve
     makes comes from the program's spans (16 a turn, 5 turns), their bytes
     from the driver's work model; the share cannot pass 100 while a pass
-    takes the memory's time or more.  No such operation (the CPU, the
-    program before PR 37): nothing, and the reason."""
+    takes the memory's time or more.  The kernel's second body
+    (``%kmedians_neighbours.N``, PR 38) is another operation by name: with it
+    among the largest the two read what they read without it.  No such
+    operation (the CPU, the program before PR 37): nothing, and the reason."""
     a = _data()
     _fit(a)
     telemetry.clear_spans()
@@ -154,6 +158,8 @@ def test_the_kernels_readers(one_device):
     pass_s = work["count_pass_bytes"] / 819e9
     trace = {"top_ops": [["%kmedians_count.8 custom-call:tpu_custom_call s32[3,4,3,8,512]", 3 * 80 * pass_s * 1.25],
                          ["%fusion.37 fusion s32[1,512]", 0.1]]}
+    if second_body:
+        trace["top_ops"].insert(1, ["%kmedians_neighbours.9 custom-call:tpu_custom_call s32[3,3,8,512]", 3 * 5 * pass_s * 1.3])
     run = {"solves": 3, "notes": {}, "trace": trace, "peaks": {"hbm_bytes_per_s": 819e9, "flops_per_s": 197e12}, "work": work}
     assert load_py("layer_metrics", "kmedians_count_ms").read(run) == pytest.approx(1000 * 80 * pass_s * 1.25)
     assert load_py("layer_metrics", "kmedians_count_roofline_pct").read(run) == pytest.approx(80.0)

@@ -182,7 +182,8 @@ def _grouped_values(dtype):
 
 def _packed(a, labels):
     """The table as the grouped selection takes it: `kernels.pack_columns`'
-    copy, and the labels in one column's shape, no group's behind the last row."""
+    copy, and the labels in one column's shape, no group's behind the last
+    row (``GROUPS`` names none of ``GROUPS`` groups, nor of fewer)."""
     from heat_tpu.core import kernels
 
     cols = kernels.pack_columns(jnp.asarray(a))
@@ -190,14 +191,21 @@ def _packed(a, labels):
     return cols, jnp.pad(jnp.asarray(labels), (0, behind), constant_values=GROUPS).reshape(cols.shape[1:])
 
 
+def _middle(sizes):
+    """The rank of numpy's median (its lower one where the size is even)."""
+    return (jnp.maximum(sizes, 1) - 1) // 2
+
+
 @functools.partial(jax.jit, static_argnames=("bits", "groups"))
 def _grouped(cols, labels, ranks, bits, groups=GROUPS):
-    """(low, high, nans), each groups x columns; ``ranks`` groups x columns or groups x 1."""
+    """(low, high, nans, sizes), each groups x columns; ``ranks`` groups x
+    columns or groups x 1, or None: the selection then makes each group's
+    middle rank of the size it has counted."""
     real = statistics._GROUP_BITS
     statistics._GROUP_BITS = bits
     try:
-        found = statistics._select_ranks(cols, (1, 2), ranks[:, :, None, None], True, None, lambda v: v, lambda v: v,
-                                         group=(labels, groups))
+        found = statistics._select_ranks(cols, (1, 2), _middle if ranks is None else ranks[:, :, None, None], True, None,
+                                         lambda v: v, lambda v: v, group=(labels, groups))
         return tuple(v[:, :, 0, 0] for v in found)
     finally:
         statistics._GROUP_BITS = real
@@ -219,8 +227,9 @@ def test_every_groups_every_rank_bit_for_bit(bits, dtype):
     x, lab = _packed(a, labels)
     for r in range(max(len(m) for m in members)):
         ranks = np.asarray([min(r, len(m) - 1) for m in members], np.int32)
-        low, high, nans = _grouped(x, lab, jnp.asarray(ranks)[:, None], bits=bits)
-        assert low.shape == high.shape == nans.shape == (GROUPS, 5) and not np.asarray(nans).any()
+        low, high, nans, sizes = _grouped(x, lab, jnp.asarray(ranks)[:, None], bits=bits)
+        assert low.shape == high.shape == nans.shape == sizes.shape == (GROUPS, 5) and not np.asarray(nans).any()
+        assert np.array_equal(np.asarray(sizes), np.repeat([[len(m)] for m in members], 5, axis=1))
         for g, m in enumerate(members):
             assert np.array_equal(bits_of(low[g]), bits_of(m[ranks[g]])), (g, r)
             if ranks[g] + 1 < len(m):  # the largest member has no neighbour, and nothing reads one
@@ -231,7 +240,7 @@ def test_ranks_of_a_group_may_differ_by_column():
     a, labels = _grouped_values("float32")
     members = [np.sort(a[labels == g], axis=0) for g in range(GROUPS)]
     ranks = np.random.default_rng(3).integers(0, [[len(m)] for m in members], (GROUPS, 5)).astype(np.int32)
-    low, _, _ = _grouped(*_packed(a, labels), jnp.asarray(ranks), bits=2)
+    low, _, _, _ = _grouped(*_packed(a, labels), jnp.asarray(ranks), bits=2)
     want = np.stack([m[ranks[g], np.arange(5)] for g, m in enumerate(members)])
     assert np.array_equal(np.asarray(low), want)
 
@@ -241,11 +250,12 @@ def test_nans_are_counted_by_group_and_an_empty_group_harms_no_other():
     a[np.flatnonzero(labels == 0)[:3], 2] = np.nan
     a[np.flatnonzero(labels == 3)[0], 4] = -np.nan
     labels[labels == 2] = GROUPS  # group 2 is empty
-    want = np.zeros((GROUPS, 5), np.int32)
-    want[0, 2], want[3, 4] = 3, 1
+    want = np.zeros((GROUPS, 5), np.int32)  # 1 where a NaN is among the members, however many (their count until PR 38)
+    want[0, 2], want[3, 4] = 1, 1
     ranks = jnp.asarray([(np.sum(labels == g) - 1) // 2 if np.any(labels == g) else 0 for g in range(GROUPS)], jnp.int32)
-    low, high, nans = _grouped(*_packed(a, labels), ranks[:, None], bits=2)
+    low, high, nans, sizes = _grouped(*_packed(a, labels), ranks[:, None], bits=2)
     assert np.array_equal(np.asarray(nans), want)
+    assert np.array_equal(np.asarray(sizes)[:, 0], [np.sum(labels == g) for g in range(GROUPS)])  # the NaNs are members too
     for g in (0, 1, 3):
         clean = [c for c in range(5) if not want[g, c]]
         m = np.sort(a[labels == g], axis=0)
@@ -260,7 +270,7 @@ def test_the_ungrouped_selection_is_the_case_of_no_group():
     static = jax.jit(lambda x: statistics._select_ranks(x, 0, (52, 105), True, None, lambda v: v, lambda v: v))(jnp.asarray(a))
     cols, one_group = _packed(a, np.zeros(len(a), np.int32))
     for i, r in enumerate((52, 105)):
-        low, high, _ = _grouped(cols, one_group, jnp.full((1, 1), r, jnp.int32), bits=2, groups=1)
+        low, high, _, _ = _grouped(cols, one_group, jnp.full((1, 1), r, jnp.int32), bits=2, groups=1)
         assert np.array_equal(np.asarray(low[0]), np.asarray(static[0][i, 0]))
         assert np.array_equal(np.asarray(high[0]), np.asarray(static[1][i, 0]))
 
@@ -297,3 +307,112 @@ def test_the_counting_kernel_counts_digits_by_group(dtype, rows):
                     mine = mine[mine >> above == prefix[g, c] >> above]
                 want[g, :, c] = np.bincount(((mine >> u(shift)) & u((1 << bits) - 1)).astype(np.int64), minlength=1 << bits)
         assert np.array_equal(got, want), (shift, bits)
+
+
+def _keys(a):
+    """The signed order key of every value, as numpy integers."""
+    return np.asarray(statistics._order_key(jnp.asarray(a)))
+
+
+@pytest.mark.parametrize("groups,rows", [(1, 1000), (3, 1000), (4, 1000), (4, 140000)],
+                         ids=["one_group", "three_groups", "four_groups", "four_groups_two_blocks"])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_the_neighbours_kernel_finds_the_next_key_by_group(dtype, groups, rows):
+    """``kernels.grouped_neighbours`` (through the interpreter here): for
+    every group and column, over the group's members alone, the smallest key
+    strictly above the group's pivot, or the key type's smallest value, no
+    number's key, where a NaN is among them.  The pivots
+    by column: 0, a key that members tie at (the ties are not above it); 1,
+    the group's largest key (nothing lies above: the key type's largest
+    value); 2, the key of -0.0 (+0.0 lies above it, and compares equal to it
+    as a float).  The last group is empty; column 0 holds a NaN of either
+    sign, +inf and -inf among the first group's members; rows under a label
+    outside ``range(groups)``, and the zeros behind the last row, belong to
+    no group."""
+    from heat_tpu.core import kernels
+
+    rng = np.random.default_rng(5)
+    a = np.round(rng.standard_normal((rows, 3)) * [4, 100, 2]).astype(dtype)  # whole numbers: ties everywhere
+    lab = rng.integers(-1, groups + 2, rows).astype(np.int32)  # -1, groups and groups + 1 name no group
+    if groups > 1:
+        lab[lab == groups - 1] = -1  # the last group is empty
+    first = np.flatnonzero(lab == 0)
+    a[first[:5], 0] = [np.nan, -np.nan, np.inf, -np.inf, np.inf]
+    a[first[5:9], 2] = [-0.0, 0.0, -0.0, 0.0]
+    a[np.flatnonzero(lab == -1)[:2], 1] = [np.nan, 1e30]  # no group's NaN, no group's largest
+    key = _keys(a)
+    top = np.iinfo(key.dtype).max
+    pivots = np.zeros((groups, 3), key.dtype)
+    for g in range(groups):
+        mine = key[lab == g]
+        if len(mine):
+            pivots[g] = [np.sort(mine[:, 0])[len(mine) // 2], mine[:, 1].max(), _keys(np.asarray(-0.0, dtype))]
+    above = kernels.grouped_neighbours(*_packed(a, lab), jnp.asarray(pivots), groups)
+    assert above.shape == (groups, 3) and above.dtype == key.dtype
+    for g in range(groups):
+        for c in range(3):
+            mine = key[lab == g, c]
+            want = np.iinfo(key.dtype).min if np.isnan(a[lab == g, c]).any() else min(mine[mine > pivots[g, c]], default=top)
+            assert int(above[g, c]) == want, (g, c)
+    assert int(above[0, 0]) == np.iinfo(key.dtype).min and int(above[0, 1]) == top and int(above[0, 2]) == _keys(np.asarray(0.0, dtype))
+    a[first[:2], 0] = -1e30  # without the NaNs: the infinities are numbers, +inf the group's largest
+    pivots[0, 0] = _keys(np.asarray(1e30, dtype))
+    above = kernels.grouped_neighbours(*_packed(a, lab), jnp.asarray(pivots), groups)
+    assert int(above[0, 0]) == _keys(np.asarray(np.inf, dtype))
+    if groups > 1:
+        assert np.all(np.asarray(above)[-1] == top)
+
+
+@pytest.mark.parametrize("groups", [1, 3, 4])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_the_sizes_counted_inside_give_numpys_median(dtype, groups):
+    """``_select_ranks`` with a group and the ranks as a function of the
+    sizes: the first counting pass's totals are the groups' sizes, the last
+    one's running count says whether ties at the median reach the next rank
+    (column 3: they do; column 1, whole numbers: some do; the others: none
+    does), and the rule of ``KMedians._medians`` on what comes back is
+    ``numpy.median`` of each group's members.  A group of one member has
+    nothing above its median; the last group is empty."""
+    a, _ = _grouped_values(dtype)
+    rng = np.random.default_rng(21)
+    labels = rng.integers(0, max(groups - 1, 1), len(a)).astype(np.int32)
+    labels[::37] = groups + 3  # no group's
+    if groups > 1:
+        labels[labels == groups - 2] = groups  # leave the group before the last ...
+        labels[11] = groups - 2  # ... one member
+    cols, lab = _packed(a, labels)
+    low, high, nans, sizes = (np.asarray(v) for v in _grouped(cols, lab, None, bits=2, groups=groups))
+    assert not nans.any()
+    for g in range(groups):
+        mine = a[labels == g]
+        assert np.all(sizes[g] == len(mine))
+        if len(mine):
+            got = np.where(len(mine) % 2 == 0, 0.5 * (low[g] + high[g]), low[g])
+            assert np.array_equal(got, np.median(mine, axis=0)), g
+    if groups > 1:
+        assert np.array_equal(low[groups - 2], a[11]) and not sizes[-1].any()
+    # the same elements as with the ranks handed over, as before the sizes were counted inside
+    ranks = jnp.asarray([(max((labels == g).sum(), 1) - 1) // 2 for g in range(groups)], jnp.int32)[:, None]
+    given = _grouped(cols, lab, ranks, bits=2, groups=groups)
+    filled = sizes[:, 0] > 1  # a single member has no upper neighbour, an empty group no member
+    assert np.array_equal(np.asarray(given[0])[filled], low[filled]) and np.array_equal(np.asarray(given[1])[filled], high[filled])
+
+
+def test_a_nan_among_the_members_is_that_groups_alone():
+    """Through ``KMedians._medians``, sizes and ranks made inside: the
+    cluster with a NaN member gets NaN in that column, an empty cluster keeps
+    its center, and labels of zeros everywhere make every place a member of
+    cluster 0 (the benchmark's fault ``unmasked`` is planted on that)."""
+    from heat_tpu.cluster import kmedians
+
+    a, labels = _grouped_values("float32")
+    labels[labels == 2] = GROUPS  # cluster 2 is empty
+    a[np.flatnonzero(labels == 1)[4], 3] = np.nan
+    cols, lab = _packed(a, labels)
+    centers = jnp.asarray(np.arange(GROUPS * 5, dtype=np.float32).reshape(GROUPS, 5))
+    got = np.asarray(jax.jit(lambda *v: kmedians._medians(*v, lambda s: s, lambda s: s))(cols, lab, centers))
+    want = np.stack([np.median(a[labels == g], axis=0) if g != 2 else np.asarray(centers[2]) for g in range(GROUPS)])
+    assert np.isnan(got[1, 3]) and np.isnan(want[1, 3]) and np.array_equal(got, want, equal_nan=True)
+    of_all = np.asarray(jax.jit(lambda *v: kmedians._medians(*v, lambda s: s, lambda s: s))(cols, jnp.zeros_like(lab), centers))
+    every_place = np.concatenate([a, np.zeros((lab.size - len(a), 5), np.float32)])
+    assert np.array_equal(of_all[0], np.median(every_place, axis=0), equal_nan=True) and np.array_equal(of_all[1:], np.asarray(centers)[1:])
