@@ -1,6 +1,7 @@
-"""The hand-written Pallas kernels of the array core: ``gram_syrk`` and the
+"""The hand-written Pallas kernels of the array core: ``gram_syrk``, the
 two bodies of a selection by group, ``grouped_digit_counts`` and
-``grouped_neighbours``.
+``grouped_neighbours``, and ``cd_sweeps``, Lasso's descent on its normal
+equations (its section at the end has the readings).
 
 The framework's compute path is XLA-compiled jnp; a Pallas kernel exists
 only where a trace demands it, that is where a device trace shows XLA
@@ -31,6 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "cd_sweeps",
+    "cd_supported",
     "gram_syrk",
     "grouped_digit_counts",
     "grouped_neighbours",
@@ -99,12 +102,14 @@ def syrk_supported(m: int, n: int, dtype) -> bool:
     )
 
 
-def _syrk_kernel(x_ref, o_ref, comp_ref):
+def _syrk_kernel(*refs, shifted: bool = False):
     """Per-tile bf16x3 rank-k update with Kahan-compensated accumulation:
     a plain sequential f32 sum over the thousands of grid steps costs
     ~grid*eps (measured 1.5e-4 on G at 2^22 rows in 2048 steps); the
     compensation buffer brings it back to ~1e-6, and its (n, n) VPU work
-    hides under the tile's DMA."""
+    hides under the tile's DMA.  ``shifted``: a row of the columns' shifts
+    comes second and is taken from the tile first."""
+    x_ref, o_ref, comp_ref = refs[0], refs[-2], refs[-1]
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -112,7 +117,7 @@ def _syrk_kernel(x_ref, o_ref, comp_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
         comp_ref[...] = jnp.zeros_like(comp_ref)
 
-    blk = x_ref[...]
+    blk = x_ref[...] - refs[1][...] if shifted else x_ref[...]
     hi = blk.astype(jnp.bfloat16)
     lo = (blk - hi.astype(jnp.float32)).astype(jnp.bfloat16)
     dims = (((0,), (0,)), ((), ()))
@@ -131,29 +136,35 @@ def _syrk_kernel(x_ref, o_ref, comp_ref):
     o_ref[...] = t
 
 
-def gram_syrk(x: jax.Array) -> jax.Array:
+def gram_syrk(x: jax.Array, shift=None) -> jax.Array:
     """``x.T @ x`` for tall f32 ``x`` reading x once; the row remainder
     past the last full tile goes through a plain XLA dot and is added.
     The grid stops at the last full tile and ``x`` goes in whole: a slice
-    ``x[:m0]`` in front of the custom call is a copy of all of it."""
+    ``x[:m0]`` in front of the custom call is a copy of all of it.
+    ``shift`` (``(n,)``, optional): the Gram of ``x - shift``, the shift
+    taken from every tile as it is read (``x - shift`` in front of the
+    custom call would be a second table); without it the call and its
+    compiled text are what they were."""
     m, n = x.shape
     rows = _syrk_rows(n)
     steps = m // rows
+    shifted = shift is not None
+    moved = (lambda a: a - shift[None, :]) if shifted else (lambda a: a)
     if steps == 0:  # public guard: short input is just the tail dot
-        return jnp.matmul(x.T, x, precision=jax.lax.Precision.HIGH)
+        return jnp.matmul(moved(x).T, moved(x), precision=jax.lax.Precision.HIGH)
     call = pl.pallas_call(
-        _syrk_kernel,
+        functools.partial(_syrk_kernel, shifted=True) if shifted else _syrk_kernel,
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         grid=(steps,),
-        in_specs=[pl.BlockSpec((rows, n), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((rows, n), lambda i: (i, 0))] + [pl.BlockSpec((1, n), lambda i: (0, 0))] * shifted,
         out_specs=pl.BlockSpec((n, n), lambda i: (0, 0)),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=_interpret(),
         name="gram_syrk",  # the device trace names the custom call by it (%gram_syrk.N)
     )
-    g = call(x)
+    g = call(x, shift[None, :]) if shifted else call(x)
     if steps * rows < m:
-        tail = x[steps * rows :]
+        tail = moved(x[steps * rows :])
         g = g + jnp.matmul(tail.T, tail, precision=jax.lax.Precision.HIGH)
     return g
 
@@ -344,3 +355,111 @@ def grouped_neighbours(packed: jax.Array, labels: jax.Array, pivots: jax.Array, 
     (above,) = _grouped_call(functools.partial(_neighbours_kernel, groups=groups), "kmedians_neighbours",
                              [jax.ShapeDtypeStruct((groups, f, 8, lanes), kdt)], (pivots.reshape(groups * f),), packed, labels)
     return jnp.min(above, axis=(-2, -1))
+
+
+# ----------------------------------------------------------------------
+# cyclic coordinate descent on the normal equations (Lasso's sweeps), the
+# whole of it ONE kernel: G, 66 KB at 129 coordinates, lies in the core's
+# vector memory and the 12,900 dependent turns of a fit run on it there.
+#
+# As XLA operations a turn is a launch or several (chip runs, PR 39, one
+# v5e, 129 coordinates, us a turn): the row of G sliced out and the
+# threshold on scalars, eighteen operations, 3.00; the same with the
+# threshold taken of the whole vector, four, 0.79; ALL coordinates' rho a
+# turn (G theta: the 128 it does not use are cheaper than a dynamic slice),
+# three, 0.24.  No form is under two fusions a turn, and a traced window of
+# 4 s and 206 fits of 25,800 events overran the profiler's 2^22 events: the
+# trace held 161 of them and every share read off it was wrong.  The kernel
+# is one event a fit, and 0.155 us a turn (2.00 ms a fit of 100 sweeps).
+#
+# The equations may stand in a SHIFTED frame (Lasso's: the columns taken
+# about a shift near their means, so that float32 holds the spreads and
+# not the means' squares): the unknowns are then ``t = (u, theta)`` with
+# ``u = theta_0 + shift . theta`` (less the targets' shift), and a step of
+# coordinate ``j`` with the intercept held drags ``u`` along by ``shift_j``
+# times the step.  That is the kernel's ``drag``; all zeros, it is plain
+# cyclic descent.
+# ----------------------------------------------------------------------
+_CD_MAX = 1024  # coordinates: A and the turns' last values, (1024, 1024) float32 each, are 8 MiB of the core's memory
+
+
+def cd_supported(m: int, dtype) -> bool:
+    """float32 normal equations small enough to lie in vector memory whole."""
+    return jnp.dtype(dtype) == jnp.float32 and 0 < m <= _CD_MAX
+
+
+def _cd_kernel(tol_ref, of_ref, off_ref, rows_ref, t_ref, it_ref, moved_ref, last_ref, *, m: int, max_iter: int):
+    """At most ``max_iter`` sweeps of ``m`` turns from ``t0``, ended by a
+    sweep that moves no coordinate by ``tol``.  ``of_ref`` (scalar memory)
+    holds, a row each, ``b``, the thresholds, the columns' sums of squares,
+    the drags and ``t0``; ``off_ref`` is ``A`` less those sums of squares on
+    its diagonal; ``rows_ref`` holds ``t0`` and the row that reads the
+    intercept off ``t`` (1 at place 0, less the drags); ``last_ref`` keeps
+    every coordinate's last value in all places of a row of its own, so that
+    a turn's step is known in place 0 without a second reduction."""
+    t0, reads_intercept = rows_ref[0:1, :], rows_ref[1:2, :]
+    place = jax.lax.broadcasted_iota(jnp.int32, t0.shape, 1)
+    tol = tol_ref[0, 0]
+
+    def remember(j, _):
+        last_ref[pl.ds(j, 1), :] = jnp.full(t0.shape, of_ref[4, j])
+        return 0
+
+    jax.lax.fori_loop(0, m, remember, 0)
+
+    def turn(j, t):
+        # rho_j = b_j - sum_k A_jk t_k + col_sq_j t_j, the same in every place; the threshold; place j takes it and
+        # place 0 the drag's share of the step
+        rho = jnp.full(t.shape, of_ref[0, j]) - jnp.sum(off_ref[pl.ds(j, 1), :] * t)
+        new = jnp.sign(rho) * jnp.maximum(jnp.abs(rho) - of_ref[1, j], 0.0) / of_ref[2, j]
+        step = new - last_ref[pl.ds(j, 1), :]
+        last_ref[pl.ds(j, 1), :] = new
+        return jnp.where(place == j, new, t) + jnp.where(place == 0, of_ref[3, j] * step, 0.0)
+
+    def cond(carry):
+        _, it, moved = carry
+        return jnp.logical_and(it < max_iter, moved >= tol)
+
+    def body(carry):
+        t, it, _ = carry
+        new = jax.lax.fori_loop(0, m, turn, t)
+        # a sweep's largest move, the intercept's own in place 0 (not u's: u moves with every dragged step)
+        intercept_moved = jnp.abs(jnp.sum(reads_intercept * new) - jnp.sum(reads_intercept * t))
+        return new, it + 1, jnp.max(jnp.where(place == 0, intercept_moved, jnp.abs(new - t)))
+
+    t, it, moved = jax.lax.while_loop(cond, body, (t0, jnp.int32(0), jnp.float32(jnp.inf)))
+    t_ref[...] = t
+    it_ref[0, 0] = it
+    moved_ref[0, 0] = moved
+
+
+def cd_sweeps(A: jax.Array, b: jax.Array, lams: jax.Array, col_sq: jax.Array, drag: jax.Array, tol, t0: jax.Array,
+              max_iter: int):
+    """Cyclic coordinate descent, the coordinates in order, at most
+    ``max_iter`` sweeps from ``t0``, ended by a sweep that moves no
+    coordinate by ``tol``: ``(t, sweeps run, the last sweep's largest
+    move)``.  Coordinate ``j``'s turn: ``rho = b_j - sum_k A_jk t_k +
+    col_sq_j t_j``, ``t_j`` becomes ``soft(rho, lams_j) / col_sq_j``, and
+    ``t_0`` moves by ``drag_j`` times ``t_j``'s step (``drag_0`` is 0).  With
+    ``A = G`` symmetric, ``col_sq`` its diagonal and no drag that is
+    coordinate descent on ``1/2 t^T G t - b^T t + sum_j lams_j |t_j|``; with
+    a drag, ``t_0`` stands for ``intercept + drag . t`` and what a sweep
+    moved is read of the intercept itself."""
+    m = A.shape[0]
+    rows_of, lanes_of = -(-m // 8) * 8, -(-m // _LANES) * _LANES
+    col_sq = jnp.maximum(col_sq, 1e-30)
+    off = jnp.pad(A - jnp.diag(col_sq), ((0, rows_of - m), (0, lanes_of - m)))
+    rows = jnp.zeros((8, lanes_of), A.dtype).at[0, :m].set(t0).at[1, :m].set(jnp.zeros((m,), A.dtype).at[0].set(1) - drag)
+    t, it, moved = pl.pallas_call(
+        functools.partial(_cd_kernel, m=m, max_iter=max_iter),
+        out_shape=(jax.ShapeDtypeStruct((1, lanes_of), A.dtype), jax.ShapeDtypeStruct((1, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((1, 1), jnp.float32)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM), pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM), pl.BlockSpec(memory_space=pltpu.SMEM),
+                   pl.BlockSpec(memory_space=pltpu.SMEM)),
+        scratch_shapes=[pltpu.VMEM((rows_of, lanes_of), A.dtype)],
+        interpret=_interpret(),
+        name="lasso_cd",  # the device trace names the custom call by it (%lasso_cd.N)
+    )(jnp.asarray(tol, jnp.float32).reshape(1, 1), jnp.stack([b, lams, col_sq, drag, t0]), off, rows)
+    return t[0, :m], it[0, 0], moved[0, 0]

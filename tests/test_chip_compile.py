@@ -718,3 +718,102 @@ def test_kmedians_over_four_chips_exchanges_counts(comm4, for_the_chip, pad):
     text = final.lower(*args).compile().as_text()
     assert "all-gather" not in text and " sort(" not in text
     assert [re.sub(r"\{[^}]*\}", "", r) for r in re.findall(r"= (.*?) all-reduce(?:-start)?\(", text)] == ["f32[]"]
+
+
+LASSO_ROWS, LASSO_COLS = 10_000_000, 128
+
+
+def _lasso_program(sharding_of, comm=None, rows=LASSO_ROWS, syrk_ok=True):
+    from heat_tpu.regression import lasso
+
+    plan = dict(n=rows, gram=True, syrk_ok=syrk_ok, comm=comm, max_iter=100, phase="fit")
+    return lasso._program.lower(
+        _sds((rows, LASSO_COLS), jnp.float32, sharding_of(0)), _sds((rows,), jnp.float32, sharding_of(0)), (),
+        _sds((), jnp.float32, sharding_of(None)), _sds((), jnp.float32, sharding_of(None)),
+        _sds((LASSO_COLS + 1,), jnp.float32, sharding_of(None)), **plan).compile()
+
+
+def test_lasso_fit_at_the_benchmark_cell(one_chip, for_the_chip):
+    """The Lasso cell, 10^7 x 128 and 100 sweeps, ONE program.  No second
+    table: nothing of the table's size is an operand but the table or a
+    temporary at all (the parent concatenated a column of ones to it:
+    ``f32[10^7, 129]``).  The table is read whole twice: by the Gram kernel
+    (``gram_syrk``, its custom call once in the text, its operands the table
+    and the row of shifts it takes from every tile) and by the moments' loop
+    over blocks of 2^18 rows (ONE fusion a block, which slices the table
+    where it lies); the first 4,096 rows (the shift), the rows past the last
+    tile and the last block go through three small fusions.  The descent is
+    ONE kernel (``lasso_cd``, its custom call once in the text): no loop of
+    XLA operations holds its 12,900 turns (two fusions a turn at the least,
+    25,800 events a fit: a traced window of 4 s overran the profiler), so a
+    fit is a dozen operations on the device and ``gram_syrk`` is among the
+    trace's ten largest, where the benchmark's readers find it."""
+    from heat_tpu.regression import lasso
+
+    compiled = _lasso_program(lambda split: one_chip)
+    n, f = LASSO_ROWS, LASSO_COLS
+    m = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert n * f * 4 + n * 4 <= m.argument_size_in_bytes < n * f * 4 + n * 4 + 2**16
+    assert m.temp_size_in_bytes < 2**26 and m.output_size_in_bytes <= 4096
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert len(re.findall(r"%gram_syrk[.\d]* = ", text)) == len(re.findall(r"%lasso_cd[.\d]* = ", text)) == 1
+    instructions = _entry_instructions(compiled)
+    (table,) = [name for name, opcode, shape, _ in instructions if opcode == "parameter" and shape == f"f32[{n},{f}]"]
+    readers = sorted((opcode, name) for name, opcode, _, operands in instructions if table in operands and opcode not in PASSED_ON)
+    assert [opcode for opcode, _ in readers] == ["custom-call"] + ["fusion"] * 3, readers  # (the loop takes it in a tuple)
+    (kernel,) = [i for i in instructions if i[1] == "custom-call" and i[0].startswith("gram_syrk")]
+    shape_of = {name: shape for name, _, shape, _ in instructions}
+    assert kernel[3][0] == table and [shape_of[o] for o in kernel[3][1:]] == [f"f32[1,{f}]"] and (kernel[1], kernel[0]) in readers
+    # the three fusions read the first rows (4,096: the shift), the rows past the last tile (4,096 rows) and the last
+    # block (2^18): what they take of the table is that small
+    for _, name in readers[1:]:
+        called = _computation_instructions(text, re.search(r"%" + re.escape(name) + r" = [^\n]* calls=%([^ ,\n]+)", text).group(1))
+        (inside,) = [i[0] for i in called if i[1] == "parameter" and i[2] == f"f32[{n},{f}]"]
+        taken = [int(re.match(r"f32\[(\d+),128\]", shape).group(1)) for _, _, shape, operands in called if inside in operands]
+        assert taken and max(taken) < lasso._SUM_BLOCK_ROWS, (name, taken)
+    loops = _while_bodies(text)
+    tall = {body: v for body, v in loops.items() if any(f"[{n}" in shape for _, _, shape, _ in v[0])}
+    (blocks,) = tall.values()  # the moments' loop alone carries the table
+    (held,) = [name for name, opcode, shape, _ in blocks[0] if opcode == "get-tuple-element" and shape == f"f32[{n},{f}]"]
+    assert [opcode for _, opcode, _, operands in blocks[0] if held in operands and opcode not in PASSED_ON] == ["fusion"]
+    assert set(loops) == set(tall)  # no other loop: the sweeps are inside the kernel
+    (descent,) = [i for i in instructions if i[1] == "custom-call" and i[0].startswith("lasso_cd")]
+    assert not any(f"[{n}" in shape for name, _, shape, _ in instructions if name in descent[3])
+    assert len([i for i in instructions if i[1] not in PASSED_ON + ("constant",)]) < 60
+    assert _device_bytes(compiled) < 0.4 * HBM_BYTES
+
+
+@pytest.mark.parametrize("coordinates", [129, 130, 1024], ids=["cell", "spills_a_lane", "the_bound"])
+def test_lasso_descent_kernel_up_to_its_bound(one_chip, for_the_chip, coordinates):
+    """``cd_sweeps`` at the cell's 129 coordinates, one lane on, and at
+    ``_CD_MAX`` itself, the most `cd_supported` admits: ``A`` and the turns'
+    last values, (1024, 1024) float32 each, are 8 MiB, and the kernel with
+    them fits the chip's fast memory under the default limit.  One more
+    coordinate, or another type, and `lasso._gram_form` takes the residual
+    form."""
+    from heat_tpu.core import kernels
+
+    assert kernels.cd_supported(coordinates, jnp.float32) and kernels._CD_MAX == 1024
+    assert not kernels.cd_supported(kernels._CD_MAX + 1, jnp.float32) and not kernels.cd_supported(129, jnp.float64)
+    small = lambda *shape: _sds(shape, jnp.float32, one_chip)
+    compiled = jax.jit(kernels.cd_sweeps, static_argnums=7).lower(
+        small(coordinates, coordinates), small(coordinates), small(coordinates), small(coordinates), small(coordinates), small(),
+        small(coordinates), 100).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%lasso_cd[.\d]* = ", text)) == 1 and " while(" not in text
+    assert _device_bytes(compiled) < 2**26
+
+
+def test_lasso_over_four_chips_sums_the_parts(comm4, for_the_chip):
+    """Rows split over four chips: each chip's Gram (an XLA product: the
+    gate keeps the kernel for one device, R8) and moments, all-reduced;
+    nothing is gathered, nothing of a chip's rows crosses, and the descent's
+    kernel runs on every chip alike.  (Rows no four chips divide come padded and
+    zeroed by the caller: the same program.)"""
+    compiled = _lasso_program(comm4.sharding, comm=comm4, syrk_ok=False)
+    text = compiled.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text and "gram_syrk" not in text
+    reduced = [re.sub(r"\{[^}]*\}", "", r) for r in re.findall(r"= (.*?) all-reduce(?:-start)?\(", text)]
+    assert reduced and all(str(LASSO_ROWS // 4) not in r for r in reduced), reduced
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**26

@@ -80,6 +80,25 @@ class TestSyrk:
         assert rel(g, want) < rel(three, want) + summing, rel(g, want)
         np.testing.assert_allclose(g, g.T, rtol=1e-5, atol=1e-4)
 
+    @pytest.mark.parametrize("m", [2048, 3000], ids=["whole_tiles", "with_a_tail"])
+    def test_about_a_shift(self, ht, m):
+        """``gram_syrk(x, shift)`` (PR 39): the Gram of ``x - shift``, the
+        shift taken from every tile and from the rows past the last one; a
+        column that stands 50 spreads off zero is then held to its SPREAD
+        (as the table lies the same three terms hold it to its mean's
+        square, 2,500 times as much)."""
+        from heat_tpu.core import kernels
+
+        x = np.random.default_rng(5).standard_normal((m, 512)).astype(np.float32) + 50 * (np.arange(512) % 7 == 0).astype(np.float32)
+        shift = x[:256].mean(axis=0)
+        g = np.asarray(jax.jit(kernels.gram_syrk)(jnp.asarray(x), jnp.asarray(shift)))
+        xc = x.astype(np.float64) - shift.astype(np.float64)
+        want = xc.T @ xc
+        assert np.diag(want).max() < 2 * m  # spreads of 1, wherever the columns stand
+        np.testing.assert_allclose(g, want, rtol=0, atol=5e-6 * np.diag(want).max())
+        plain = np.asarray(jax.jit(kernels.gram_syrk)(jnp.asarray(x)))
+        np.testing.assert_allclose(plain, x.astype(np.float64).T @ x.astype(np.float64), rtol=0, atol=5e-6 * 2501 * m)
+
     def test_unsupported_shapes(self, ht):
         from heat_tpu.core import kernels
 
