@@ -13,7 +13,9 @@ last pass (their section below has the readings; ``kmedians-spheres3d.loop1``
 times them).  ``gram_syrk`` is hSVD's Gram pass: XLA lowers
 ``x.T @ x`` as a generic dot with two operand streams, the kernel reads each
 row tile of ``x`` once, at the rate the chip's memory streams (8.51 ms for
-6.44 GB, 757 GB/s; ``hsvd-tallskinny.loop1`` times it, ``gram_syrk_ms``).
+6.44 GB, 757 GB/s; ``hsvd-tallskinny.loop1`` times it, ``gram_syrk_ms``);
+given ``y`` it is Lasso's one read of its table, the Gram and the moments
+from the same tiles (its second body, PR 40; ``lasso-1e7x128.loop1``).
 On non-TPU backends it runs through the Pallas interpreter, so the tests on
 the virtual CPU mesh exercise the same code.
 
@@ -79,6 +81,20 @@ def _interpret() -> bool:
 # `syrk_supported` admits all fit the default fast-memory limit (the (n, n)
 # output, the Kahan buffer and the products' results grow with n^2 beside
 # the input's two buffers; 2048 rows did not compile at 384 and 512).
+#
+# The moments' body (PR 40, `_syrk_moments_kernel`, Lasso's): with ``y`` the
+# same step also sums, from the shifted tile it holds, the columns, their
+# products with ``y`` and their squares, float32 on the VPU, so that Lasso
+# reads its table once where it read it twice.  ``y`` comes 4,096 targets a
+# tile as a row of lanes (a (m, 1) column's own bytes) and is put on the
+# sublanes by a transpose in the kernel (`_along_rows`).  Step 0 (chip run,
+# PR 40, call 1; 10^7 x 128 f32, device time by the trace, ten calls each):
+#
+#   gram_syrk, the shift, no y                       6.765 ms  (757 GB/s)
+#   the moments' body, y by a batched transpose      6.847     (+1.2%)
+#   the same, y by a transpose and lane broadcasts   6.945     (+2.7%)
+#   the same, no broadcast of y at all (not a sum)   6.787
+#   XLA's moments' loop alone, the parent's second read  7.128
 # ----------------------------------------------------------------------
 _SYRK_TILE_BYTES = 2 * 1024 * 1024
 
@@ -102,13 +118,38 @@ def syrk_supported(m: int, n: int, dtype) -> bool:
     )
 
 
+def _kahan_add(acc_ref, comp_ref, contrib):
+    """``acc_ref += contrib``, the rounding of each step kept in ``comp_ref``
+    and taken from the next: a plain sequential f32 sum over the thousands
+    of grid steps costs ~grid*eps (measured 1.5e-4 on G at 2^22 rows in 2048
+    steps); the compensation brings it back to ~1e-6, and its VPU work hides
+    under the tile's DMA."""
+    acc = acc_ref[...]
+    y = contrib - comp_ref[...]
+    t = acc + y
+    comp_ref[...] = (t - acc) - y
+    acc_ref[...] = t
+
+
+def _bf16x3_gram(blk):
+    """One tile's ``blk^T blk`` in compensated bf16x3: (hi+lo)^T (hi+lo)
+    dropping the lo^T lo term (below f32 eps); the two cross terms are one
+    product and its transpose, so ``hi`` is the only operand that stands on
+    the left of a row-contracting product."""
+    hi = blk.astype(jnp.bfloat16)
+    lo = (blk - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    dims = (((0,), (0,)), ((), ()))
+    dot = lambda a, b: jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32
+    )
+    cross = dot(hi, lo)
+    return dot(hi, hi) + cross + cross.T
+
+
 def _syrk_kernel(*refs, shifted: bool = False):
-    """Per-tile bf16x3 rank-k update with Kahan-compensated accumulation:
-    a plain sequential f32 sum over the thousands of grid steps costs
-    ~grid*eps (measured 1.5e-4 on G at 2^22 rows in 2048 steps); the
-    compensation buffer brings it back to ~1e-6, and its (n, n) VPU work
-    hides under the tile's DMA.  ``shifted``: a row of the columns' shifts
-    comes second and is taken from the tile first."""
+    """Per-tile bf16x3 rank-k update with Kahan-compensated accumulation
+    (`_kahan_add`).  ``shifted``: a row of the columns' shifts comes second
+    and is taken from the tile first."""
     x_ref, o_ref, comp_ref = refs[0], refs[-2], refs[-1]
     i = pl.program_id(0)
 
@@ -118,25 +159,46 @@ def _syrk_kernel(*refs, shifted: bool = False):
         comp_ref[...] = jnp.zeros_like(comp_ref)
 
     blk = x_ref[...] - refs[1][...] if shifted else x_ref[...]
-    hi = blk.astype(jnp.bfloat16)
-    lo = (blk - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    dims = (((0,), (0,)), ((), ()))
-    dot = lambda a, b: jax.lax.dot_general(
-        a, b, dims, preferred_element_type=jnp.float32
-    )
-    # (hi+lo)^T (hi+lo) dropping the lo^T lo term (below f32 eps); the two
-    # cross terms are one product and its transpose, so ``hi`` is the only
-    # operand that stands on the left of a row-contracting product
-    cross = dot(hi, lo)
-    contrib = dot(hi, hi) + cross + cross.T
-    acc = o_ref[...]
-    y = contrib - comp_ref[...]
-    t = acc + y
-    comp_ref[...] = (t - acc) - y
-    o_ref[...] = t
+    _kahan_add(o_ref, comp_ref, _bf16x3_gram(blk))
 
 
-def gram_syrk(x: jax.Array, shift=None) -> jax.Array:
+def _along_rows(yt, n: int):
+    """A tile's targets, ``(rows / 128, 128)``, as ``(rows, n)``: row ``r``
+    holds target ``r`` in every lane.  Each row of 128 targets is
+    put on the sublanes by a transpose of it broadcast down a (128, 128)
+    block; wider tiles take the same block again along the lanes."""
+    k = yt.shape[0]
+    down = jnp.broadcast_to(yt[:, None, :], (k, _LANES, _LANES))
+    along = jnp.swapaxes(down, 1, 2).reshape(k * _LANES, _LANES)
+    return along if n == _LANES else jnp.concatenate([along] * (n // _LANES), axis=1)
+
+
+def _syrk_moments_kernel(x_ref, shift_ref, y_ref, cy_ref, o_ref, m_ref, comp_ref, m_comp_ref):
+    """`_syrk_kernel`'s shifted step and, from the same shifted tile, its
+    moments: the columns' sums, their products with the targets less
+    ``cy``, their sums of squares and the targets' sum, each as (8, n)
+    partial sums a row of sublanes, all float32 on the VPU and compensated
+    across the grid as the Gram is.  ``y_ref`` holds the tile's targets in a
+    row of lanes (`_along_rows` puts them on the sublanes), ``cy_ref``
+    (scalar memory) their shift."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        for ref in (o_ref, m_ref, comp_ref, m_comp_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    blk = x_ref[...] - shift_ref[...]
+    rows, n = blk.shape
+    yt = (y_ref[...] - cy_ref[0, 0]).reshape(rows // _LANES, _LANES)
+    yb = _along_rows(yt, n)
+    by8 = lambda a: jnp.sum(a.reshape(rows // 8, 8, n), axis=0)
+    contrib = jnp.stack([by8(blk), by8(blk * yb), by8(blk * blk), by8(yb)])
+    _kahan_add(m_ref, m_comp_ref, contrib)
+    _kahan_add(o_ref, comp_ref, _bf16x3_gram(blk))
+
+
+def gram_syrk(x: jax.Array, shift=None, y=None, y_shift=None):
     """``x.T @ x`` for tall f32 ``x`` reading x once; the row remainder
     past the last full tile goes through a plain XLA dot and is added.
     The grid stops at the last full tile and ``x`` goes in whole: a slice
@@ -144,10 +206,23 @@ def gram_syrk(x: jax.Array, shift=None) -> jax.Array:
     ``shift`` (``(n,)``, optional): the Gram of ``x - shift``, the shift
     taken from every tile as it is read (``x - shift`` in front of the
     custom call would be a second table); without it the call and its
-    compiled text are what they were."""
+    compiled text are what they were.
+
+    ``y`` (``(m,)`` or ``(m, 1)``, optional): the moments too, from the
+    tiles the Gram reads: ``(G, s1, bxy, q, sy)``, with ``xc = x - shift``
+    (zeros if None) and ``yc = y - y_shift`` (0 if None), ``xc^T xc``,
+    ``xc``'s column sums, ``xc^T yc``, ``xc``'s column sums of squares and
+    ``yc``'s sum, the last four float32 sums on the VPU
+    (`_syrk_moments_kernel`).  ``y`` goes in as ONE row of lanes, the bytes
+    a ``(m, 1)`` column lies in: as ``(m, 1)`` rows of lanes it would be a
+    second table, each target padded to 128.  Without ``y`` nothing of this
+    is in the program."""
     m, n = x.shape
     rows = _syrk_rows(n)
     steps = m // rows
+    if y is not None:
+        return _syrk_moments(x, jnp.zeros((n,), x.dtype) if shift is None else shift, y,
+                             0.0 if y_shift is None else y_shift, rows, steps)
     shifted = shift is not None
     moved = (lambda a: a - shift[None, :]) if shifted else (lambda a: a)
     if steps == 0:  # public guard: short input is just the tail dot
@@ -167,6 +242,34 @@ def gram_syrk(x: jax.Array, shift=None) -> jax.Array:
         tail = moved(x[steps * rows :])
         g = g + jnp.matmul(tail.T, tail, precision=jax.lax.Precision.HIGH)
     return g
+
+
+def _syrk_moments(x, shift, y, y_shift, rows: int, steps: int):
+    """`gram_syrk` with ``y``: the moments' body over the whole tiles; the
+    rows past the last one, their Gram and their moments, in XLA."""
+    m, n = x.shape
+    y = y.astype(x.dtype).reshape(1, m)  # as a (m, 1) column lies: the same bytes
+    y_shift = jnp.asarray(y_shift, x.dtype)
+    done = steps * rows
+    tail, yt = x[done:] - shift[None, :], y[0, done:] - y_shift
+    g = jnp.matmul(tail.T, tail, precision=jax.lax.Precision.HIGH)
+    s1, bxy, q, sy = jnp.sum(tail, axis=0), jnp.sum(tail * yt[:, None], axis=0), jnp.sum(tail * tail, axis=0), jnp.sum(yt)
+    if steps:
+        g_, mo = pl.pallas_call(
+            _syrk_moments_kernel,
+            out_shape=(jax.ShapeDtypeStruct((n, n), jnp.float32), jax.ShapeDtypeStruct((4, 8, n), jnp.float32)),
+            grid=(steps,),
+            in_specs=[pl.BlockSpec((rows, n), lambda i: (i, 0)), pl.BlockSpec((1, n), lambda i: (0, 0)),
+                      pl.BlockSpec((1, rows), lambda i: (0, i)),
+                      pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=(pl.BlockSpec((n, n), lambda i: (0, 0)), pl.BlockSpec((4, 8, n), lambda i: (0, 0, 0))),
+            scratch_shapes=[pltpu.VMEM((n, n), jnp.float32), pltpu.VMEM((4, 8, n), jnp.float32)],
+            interpret=_interpret(),
+            name="gram_syrk_moments",  # holds ``gram_syrk``: the benchmark's readers find the pass by that
+        )(x, shift[None, :], y, y_shift.reshape(1, 1))
+        mo = jnp.sum(mo, axis=1)
+        g, s1, bxy, q, sy = g + g_, s1 + mo[0], bxy + mo[1], q + mo[2], sy + mo[3, 0]
+    return g, s1, bxy, q, sy
 
 
 # ----------------------------------------------------------------------

@@ -9,11 +9,12 @@ numbers.  On a tall table (`_gram_form`) that is the covariance form: with
 ``G = X^T X`` and ``b = X^T y``, coordinate ``j``'s update is
 ``rho = b_j - sum_{k != j} G_jk theta_k``, the soft threshold and the division
 by ``G_jj``: the iterates of the definition (the residual recomputed for every
-coordinate) from one read of the table for the Gram (`_gram`: the Pallas kernel
-``gram_syrk`` that hSVD's Gram uses, on one device), one for the moments
-(the columns' sums, their products with ``y`` and their sums of squares) and
-``max_iter`` sweeps over ``(f + 1)^2`` numbers in ONE kernel
-(`kernels.cd_sweeps`).  The intercept is no column of the table: its row of
+coordinate) from ONE read of the table (`_gram_and_moments`: the Pallas kernel
+``gram_syrk`` that hSVD's Gram uses, on one device, takes the moments, the
+columns' sums, their products with ``y`` and their sums of squares, from the
+tiles it reads for the Gram; over a mesh, or on a table the kernel refuses, an
+XLA product and a second read for the moments) and ``max_iter`` sweeps over
+``(f + 1)^2`` numbers in ONE kernel (`kernels.cd_sweeps`).  The intercept is no column of the table: its row of
 ``G`` is the columns' sums and the number of rows.  Where ``G`` would not be
 small beside the table, or the kernel does not take it, the residual form
 runs: ONE residual kept up to date coordinate by coordinate, recomputed once a
@@ -29,7 +30,7 @@ would lose what an uncentred table loses: a column with mean ``mu`` and spread
 ``mu / sigma`` of 50 (a Kelvin temperature), whatever computes the products.
 So the equations stand about a SHIFT near the columns' means (`_shift`; any
 shift gives the same iterates in exact arithmetic), taken from the values as
-both passes read them, and the sweeps run in that frame (`_normal_equations`):
+the table's read takes them, and the sweeps run in that frame (`_normal_equations`):
 such a table then reads 2e-7 to 6e-6 of its largest coefficient from a float64
 descent, as near as the residual form (`tests/test_lasso_reference.py`).
 """
@@ -110,7 +111,9 @@ def _moments(x, y, c, cy, real):
     columns' sums, their products with the targets and their sums of squares
     (``(3, f)``), and the targets' sum.  ``real`` (None: every row) is 1 on
     the rows that count, 0 on padding.  Partial sums over blocks of
-    `_SUM_BLOCK_ROWS` rows, read where they lie, then the sum of those."""
+    `_SUM_BLOCK_ROWS` rows, read where they lie, then the sum of those.
+    Where the Gram's kernel takes the table it takes these too, and this
+    pass is not made (`_gram_and_moments`)."""
     m, bs = x.shape[0], _SUM_BLOCK_ROWS
     nb = m // bs
 
@@ -132,25 +135,38 @@ def _moments(x, y, c, cy, real):
         return of_x, of_y
 
 
-def _gram(x, c, syrk_ok: bool, real):
-    """The Gram of the columns less ``c``, Lasso's own rule: the one-read
-    kernel ``gram_syrk`` (which hSVD's Gram uses too) where one device holds
-    the rows (``syrk_ok``, the caller's static layout gate) and the kernel
-    takes the shape, else an XLA product in float32 (``HIGHEST``).  No
-    environment variable steers it: hSVD's ``HEAT_TPU_HSVD_*`` and the
-    reason it gives for them (a truncation error that covers the Gram's) are
-    hSVD's."""
-    if syrk_ok and real is None and kernels.syrk_supported(*x.shape, x.dtype):
-        return kernels.gram_syrk(x, c)
-    xc = x - c if real is None else (x - c) * real[:, None]
-    return jnp.matmul(xc.T, xc, precision=jax.lax.Precision.HIGHEST)
+def _one_read(m: int, f: int, dtype, syrk_ok: bool) -> bool:
+    """Lasso's own rule for the one-read kernel ``gram_syrk`` (which hSVD's
+    Gram uses too): where one device holds the rows (``syrk_ok``, the
+    caller's static layout gate) and the kernel takes the shape, it reads the
+    table once for the Gram and the moments both.  No environment variable
+    steers it: hSVD's ``HEAT_TPU_HSVD_*`` and the reason it gives for them (a
+    truncation error that covers the Gram's) are hSVD's."""
+    return syrk_ok and kernels.syrk_supported(m, f, dtype)
+
+
+def _gram_and_moments(x, y, c, cy, syrk_ok: bool, real):
+    """The Gram of the columns less ``c`` and the moments (`_moments`):
+    ``(G, s1, bxy, q, sy)``.  Where `_one_read` holds, ONE read of the table:
+    the kernel takes the moments from the tiles it reads for the Gram.
+    Elsewhere (over a mesh, or a table the kernel refuses) two: an XLA
+    product in float32 (``HIGHEST``) and the moments' own pass."""
+    if real is None and _one_read(*x.shape, x.dtype, syrk_ok):
+        with jax.named_scope("lasso.gram"):
+            return kernels.gram_syrk(x, c, y, cy)
+    with jax.named_scope("lasso.gram"):
+        xc = x - c if real is None else (x - c) * real[:, None]
+        gc = jnp.matmul(xc.T, xc, precision=jax.lax.Precision.HIGHEST)
+    (s1, bxy, q), sy = _moments(x, y, c, cy, real)
+    return gc, s1, bxy, q, sy
 
 
 def _normal_equations(x, y, n: int, syrk_ok: bool, all_sum, real=None):
     """The normal equations of ``X = [1, x]`` in the SHIFTED frame, from the
     rows held here (``all_sum`` adds the devices' parts; ``n`` counts the
-    rows of all of them), in two reads of the table: ``(A, b, col_sq, drag,
-    cy)`` as `kernels.cd_sweeps` takes them.  With ``xc = x - c``, ``yc =
+    rows of all of them), in one read of the table where the kernel takes it
+    and two elsewhere (`_gram_and_moments`): ``(A, b, col_sq, drag, cy)`` as
+    `kernels.cd_sweeps` takes them.  With ``xc = x - c``, ``yc =
     y - cy`` and the unknowns ``t = (u, theta)``, ``u = theta_0 + c . theta -
     cy``, the residual is ``yc - u - xc theta``; ``G' = [1, xc]^T [1, xc]``
     and ``b' = [1, xc]^T yc`` hold numbers of the size of the columns'
@@ -162,10 +178,7 @@ def _normal_equations(x, y, n: int, syrk_ok: bool, all_sum, real=None):
     reads a diagonal 2.76e-6 low (PERF.md section 4), which is what values
     rounded to bfloat16 read high."""
     c, cy = _shift(x, y, real, all_sum)
-    with jax.named_scope("lasso.gram"):
-        gc = _gram(x, c, syrk_ok, real)
-    (s1, bxy, q), syc = _moments(x, y, c, cy, real)
-    gc, s1, bxy, q, syc = all_sum((gc, s1, bxy, q, syc))
+    gc, s1, bxy, q, syc = all_sum(_gram_and_moments(x, y, c, cy, syrk_ok, real))
     f, count = x.shape[1], jnp.full((1,), n, x.dtype)
     gc = jnp.where(jnp.eye(f, dtype=bool), q[None, :], gc)
     top = jnp.concatenate([count, s1])
@@ -400,8 +413,10 @@ class Lasso(BaseEstimator, RegressionMixin):
         Where the ``(features + 1)^2`` numbers of the normal equations are at
         most a quarter of the table's and the descent's kernel takes them
         (float32, up to 1,024 coordinates: `_gram_form`), the fit reads the
-        table twice, for the Gram and for the moments, both about a shift
-        near the columns' means, and descends on those (the covariance form:
+        table for the Gram and the moments, both about a shift near the
+        columns' means (once where the Gram's kernel takes the table: one
+        device, a tile of rows and more, 128 to 512 columns; else twice), and
+        descends on those (the covariance form:
         the same iterates as recomputing the residual for every coordinate,
         wherever the columns stand).  Otherwise it descends on the table with
         one residual kept up to date (the residual form).  One program either
@@ -415,9 +430,11 @@ class Lasso(BaseEstimator, RegressionMixin):
         gram = _gram_form(n, f, dtype)
         with _span("ht.regression.Lasso.fit", rows=n, features=f, split=x.split, max_iter=self.max_iter,
                    form="gram" if gram else "residual") as root:
-            # reads of the table a fit: the Gram's and the moments', or the
-            # sums of squares' and a column a turn and the residual a sweep
-            root.attrs.update(passes=2 if gram else 1 + 2 * self.max_iter)
+            # reads of the table a fit: the kernel's one for the Gram and the
+            # moments both, else the Gram's and the moments', or the sums of
+            # squares' and a column a turn and the residual a sweep
+            one = _one_read(n, f, dtype, x.comm.size == 1)
+            root.attrs.update(passes=(1 if one else 2) if gram else 1 + 2 * self.max_iter)
             self._fit(x, y, gram)
         return self
 

@@ -723,61 +723,68 @@ def test_kmedians_over_four_chips_exchanges_counts(comm4, for_the_chip, pad):
 LASSO_ROWS, LASSO_COLS = 10_000_000, 128
 
 
-def _lasso_program(sharding_of, comm=None, rows=LASSO_ROWS, syrk_ok=True):
+def _lasso_program(sharding_of, comm=None, rows=LASSO_ROWS, syrk_ok=True, y_column=False):
     from heat_tpu.regression import lasso
 
     plan = dict(n=rows, gram=True, syrk_ok=syrk_ok, comm=comm, max_iter=100, phase="fit")
     return lasso._program.lower(
-        _sds((rows, LASSO_COLS), jnp.float32, sharding_of(0)), _sds((rows,), jnp.float32, sharding_of(0)), (),
+        _sds((rows, LASSO_COLS), jnp.float32, sharding_of(0)),
+        _sds((rows, 1) if y_column else (rows,), jnp.float32, sharding_of(0)), (),
         _sds((), jnp.float32, sharding_of(None)), _sds((), jnp.float32, sharding_of(None)),
         _sds((LASSO_COLS + 1,), jnp.float32, sharding_of(None)), **plan).compile()
 
 
 def test_lasso_fit_at_the_benchmark_cell(one_chip, for_the_chip):
-    """The Lasso cell, 10^7 x 128 and 100 sweeps, ONE program.  No second
-    table: nothing of the table's size is an operand but the table or a
-    temporary at all (the parent concatenated a column of ones to it:
-    ``f32[10^7, 129]``).  The table is read whole twice: by the Gram kernel
-    (``gram_syrk``, its custom call once in the text, its operands the table
-    and the row of shifts it takes from every tile) and by the moments' loop
-    over blocks of 2^18 rows (ONE fusion a block, which slices the table
-    where it lies); the first 4,096 rows (the shift), the rows past the last
-    tile and the last block go through three small fusions.  The descent is
-    ONE kernel (``lasso_cd``, its custom call once in the text): no loop of
-    XLA operations holds its 12,900 turns (two fusions a turn at the least,
-    25,800 events a fit: a traced window of 4 s overran the profiler), so a
-    fit is a dozen operations on the device and ``gram_syrk`` is among the
-    trace's ten largest, where the benchmark's readers find it."""
-    from heat_tpu.regression import lasso
-
-    compiled = _lasso_program(lambda split: one_chip)
+    """The Lasso cell, 10^7 x 128 and 100 sweeps, ``y`` the ``(rows, 1)``
+    column the cell hands in: ONE program, ONE read of the table (PR 40; two
+    until then).  No second table: nothing of the table's size is an operand
+    but the table or a temporary at all (the parent of PR 39 concatenated a
+    column of ones to it: ``f32[10^7, 129]``), and ``y`` goes to the kernel as
+    it lies, a ``(1, rows)`` view of the column's own bytes (no copy: until
+    PR 40 the program made ``y`` a vector, 40 MB, 0.25 ms a fit, and the
+    column as ``(rows, 1)`` in rows of lanes would be a second table).  The
+    table's readers are the Gram kernel with the moments' body
+    (``gram_syrk_moments``, its custom call once in the text, its operands
+    the table, the row of shifts it takes from every tile, ``y`` and ``y``'s
+    shift) and the small fusions of the first 4,096 rows (the shift) and the
+    rows past the last tile (their Gram and their moments); no loop carries
+    it.  The descent is ONE kernel (``lasso_cd``, its custom call once in the
+    text): no loop of XLA operations holds its 12,900 turns (two fusions a
+    turn at the least, 25,800 events a fit: a traced window of 4 s overran
+    the profiler), so a fit is a dozen operations on the device and the
+    kernels are among the trace's ten largest, where the benchmark's readers
+    find them by the names that hold ``gram_syrk`` and ``lasso_cd``."""
+    compiled = _lasso_program(lambda split: one_chip, y_column=True)
     n, f = LASSO_ROWS, LASSO_COLS
     m = compiled.memory_analysis()
     text = compiled.as_text()
     assert n * f * 4 + n * 4 <= m.argument_size_in_bytes < n * f * 4 + n * 4 + 2**16
     assert m.temp_size_in_bytes < 2**26 and m.output_size_in_bytes <= 4096
     assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert len(re.findall(r"%gram_syrk[.\d]* = ", text)) == len(re.findall(r"%lasso_cd[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%gram_syrk_moments[.\d]* = ", text)) == len(re.findall(r"%lasso_cd[.\d]* = ", text)) == 1
+    assert " while(" not in text  # neither the moments nor the sweeps are a loop of XLA operations
     instructions = _entry_instructions(compiled)
     (table,) = [name for name, opcode, shape, _ in instructions if opcode == "parameter" and shape == f"f32[{n},{f}]"]
+    (column,) = [name for name, opcode, shape, _ in instructions if opcode == "parameter" and shape == f"f32[{n},1]"]
     readers = sorted((opcode, name) for name, opcode, _, operands in instructions if table in operands and opcode not in PASSED_ON)
-    assert [opcode for opcode, _ in readers] == ["custom-call"] + ["fusion"] * 3, readers  # (the loop takes it in a tuple)
+    assert [opcode for opcode, _ in readers] == ["custom-call"] + ["fusion"] * 3, readers
     (kernel,) = [i for i in instructions if i[1] == "custom-call" and i[0].startswith("gram_syrk")]
     shape_of = {name: shape for name, _, shape, _ in instructions}
-    assert kernel[3][0] == table and [shape_of[o] for o in kernel[3][1:]] == [f"f32[1,{f}]"] and (kernel[1], kernel[0]) in readers
-    # the three fusions read the first rows (4,096: the shift), the rows past the last tile (4,096 rows) and the last
-    # block (2^18): what they take of the table is that small
+    opcode_of = {name: opcode for name, opcode, _, _ in instructions}
+    operands_of = {name: operands for name, _, _, operands in instructions}
+    assert kernel[3][0] == table and (kernel[1], kernel[0]) in readers
+    assert [shape_of[o] for o in kernel[3][1:]] == [f"f32[1,{f}]", f"f32[1,{n}]", "f32[1,1]"]
+    assert opcode_of[kernel[3][2]] == "bitcast" and operands_of[kernel[3][2]] == [column]  # y as it lies: no copy
+    # the fusions read the first rows (4,096: the shift) and the rows past the last tile (1,664 rows, twice: their
+    # Gram and their moments): what they take of the table is that small
     for _, name in readers[1:]:
         called = _computation_instructions(text, re.search(r"%" + re.escape(name) + r" = [^\n]* calls=%([^ ,\n]+)", text).group(1))
         (inside,) = [i[0] for i in called if i[1] == "parameter" and i[2] == f"f32[{n},{f}]"]
         taken = [int(re.match(r"f32\[(\d+),128\]", shape).group(1)) for _, _, shape, operands in called if inside in operands]
-        assert taken and max(taken) < lasso._SUM_BLOCK_ROWS, (name, taken)
-    loops = _while_bodies(text)
-    tall = {body: v for body, v in loops.items() if any(f"[{n}" in shape for _, _, shape, _ in v[0])}
-    (blocks,) = tall.values()  # the moments' loop alone carries the table
-    (held,) = [name for name, opcode, shape, _ in blocks[0] if opcode == "get-tuple-element" and shape == f"f32[{n},{f}]"]
-    assert [opcode for _, opcode, _, operands in blocks[0] if held in operands and opcode not in PASSED_ON] == ["fusion"]
-    assert set(loops) == set(tall)  # no other loop: the sweeps are inside the kernel
+        assert taken and max(taken) <= 4096, (name, taken)
+    # nothing of the column's size but the parameter and views of its bytes (the kernel's, the tail fusion's)
+    assert {(opcode_of[name], tuple(operands_of[name])) for name, _, shape, _ in instructions
+            if str(n) in shape and f"{n},{f}" not in shape and name != column} == {("bitcast", (column,))}
     (descent,) = [i for i in instructions if i[1] == "custom-call" and i[0].startswith("lasso_cd")]
     assert not any(f"[{n}" in shape for name, _, shape, _ in instructions if name in descent[3])
     assert len([i for i in instructions if i[1] not in PASSED_ON + ("constant",)]) < 60

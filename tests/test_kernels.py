@@ -1,6 +1,6 @@
 """Pallas kernel tests (core/kernels.py) — run through the Pallas
 interpreter on the virtual CPU mesh, same code path as Mosaic on TPU.
-The module holds one kernel, ``gram_syrk``."""
+The module's first kernel, ``gram_syrk``, and since PR 40 its moments' body."""
 
 import numpy as np
 import pytest
@@ -98,6 +98,63 @@ class TestSyrk:
         np.testing.assert_allclose(g, want, rtol=0, atol=5e-6 * np.diag(want).max())
         plain = np.asarray(jax.jit(kernels.gram_syrk)(jnp.asarray(x)))
         np.testing.assert_allclose(plain, x.astype(np.float64).T @ x.astype(np.float64), rtol=0, atol=5e-6 * 2501 * m)
+
+    @pytest.mark.parametrize("off_zero", [False, True], ids=["no_shift", "shift_far_off_zero"])
+    @pytest.mark.parametrize("kind", ["two_tiles", "two_tiles_and_137"])
+    @pytest.mark.parametrize("n", [128, 256])
+    def test_moments_from_the_grams_tiles(self, ht, n, kind, off_zero):
+        """``gram_syrk(x, c, y, cy)`` (PR 40): the moments' body reads each
+        tile once for the Gram and for the columns' sums, their products with
+        ``y - cy``, their sums of squares and ``y - cy``'s sum, the rows past
+        the last tile in XLA.  Its Gram is the one without ``y``, bit for bit;
+        the moments are as near float64 as `lasso._moments`' blocks are held
+        (`test_the_normal_equations_stand_in_a_shifted_frame`'s tolerances).
+        Off zero, every seventh column stands 50 spreads away and ``y`` 40,
+        and the shift is the first rows' means; else no shift at all."""
+        from heat_tpu.core import kernels
+
+        tile = kernels._syrk_rows(n)
+        m = 2 * tile + (137 if kind.endswith("137") else 0)
+        rng = np.random.default_rng(11)
+        x = rng.standard_normal((m, n)).astype(np.float32)
+        y = (x[:, :3].sum(axis=1) + 0.5 + rng.standard_normal(m)).astype(np.float32)
+        if off_zero:
+            x += 50 * (np.arange(n) % 7 == 0).astype(np.float32)
+            y += 40
+            c, cy = x[:256].mean(axis=0), y[:256].mean()
+            args = (jnp.asarray(x), jnp.asarray(c), jnp.asarray(y), jnp.asarray(cy))
+            g0 = jax.jit(kernels.gram_syrk)(jnp.asarray(x), jnp.asarray(c))
+        else:
+            c, cy = np.zeros(n, np.float32), np.float32(0)
+            args = (jnp.asarray(x), None, jnp.asarray(y))
+            g0 = jax.jit(kernels.gram_syrk)(jnp.asarray(x))
+        g, s1, bxy, q, sy = (np.asarray(a, np.float64) for a in jax.jit(kernels.gram_syrk)(*args))
+        np.testing.assert_array_equal(g, np.asarray(g0))
+
+        xc = x.astype(np.float64) - c
+        yc = y.astype(np.float64) - np.float64(cy)
+        want = xc.T @ xc
+        off = ~np.eye(n, dtype=bool)
+        np.testing.assert_allclose(g[off], want[off], rtol=0, atol=5e-6 * np.diag(want).max())
+        np.testing.assert_allclose(q, (xc * xc).sum(axis=0), rtol=3e-7)
+        np.testing.assert_allclose(s1, xc.sum(axis=0), rtol=0, atol=2e-6 * m)
+        np.testing.assert_allclose(bxy, xc.T @ yc, rtol=0, atol=2e-6 * m * max(1.0, np.abs(yc).max() / 10))
+        np.testing.assert_allclose(sy, yc.sum(), rtol=0, atol=2e-6 * m * max(1.0, np.abs(yc).max() / 10))
+
+    @pytest.mark.parametrize("m", [2048, 3000], ids=["whole_tiles", "with_a_tail"])
+    def test_without_y_the_kernel_is_what_it_was(self, ht, m):
+        """Without ``y`` the call is the one hSVD and PR 39 made: ``gram_syrk(x)``
+        and ``gram_syrk(x, c)`` are the three products of ``x`` and of ``x - c``
+        bit for bit (the moments' body is a kernel of its own)."""
+        from heat_tpu.core import kernels
+
+        x = np.random.default_rng(6).standard_normal((m, 512)).astype(np.float32) + 3
+        c = x[:256].mean(axis=0)
+        tile = kernels._syrk_rows(512)
+        np.testing.assert_array_equal(np.asarray(kernels.gram_syrk(jnp.asarray(x))),
+                                      np.asarray(_three_products(jnp.asarray(x), tile)))
+        np.testing.assert_array_equal(np.asarray(kernels.gram_syrk(jnp.asarray(x), jnp.asarray(c))),
+                                      np.asarray(_three_products(jnp.asarray(x) - jnp.asarray(c), tile)))
 
     def test_unsupported_shapes(self, ht):
         from heat_tpu.core import kernels

@@ -231,13 +231,24 @@ def test_columns_far_off_zero_cost_no_precision(form, devices, bound, seed):
     off = np.max(np.abs(np.asarray(got, np.float64) - want)) / np.max(np.abs(want))
     assert off < bound, off
 
-@pytest.mark.parametrize("rows, features", [(301, 5), (37, 50)], ids=["gram", "residual"])
-def test_the_checkpointed_path_is_the_uninterrupted_fit_bit_for_bit(tmp_path, rows, features):
+@pytest.mark.parametrize("rows, features, devices", [(301, 5, None), (37, 50, None), (4099, 128, 1)],
+                         ids=["gram", "residual", "gram_through_the_kernel"])
+def test_the_checkpointed_path_is_the_uninterrupted_fit_bit_for_bit(tmp_path, rows, features, devices):
+    """A checkpointed fit prepares its equations in a program of their own
+    (``phase="prepare"``) through the same function as the plain fit's one
+    program; on one device and a table of a tile and more, that is the
+    kernel's one read for the Gram and the moments both (PR 40)."""
     a, y = _problem(rows, features)
-    x, y = ht.array(a, split=0), ht.array(y, split=0)
-    kw = dict(lam=0.05 * rows, max_iter=11, tol=-1.0)
-    plain = ht.regression.Lasso(**kw).fit(x, y)
-    chunked = ht.regression.Lasso(**kw, checkpoint_every=4, checkpoint_dir=str(tmp_path / "ck")).fit(x, y)
+    if devices:
+        ht.use_comm(Communication(jax.devices()[:devices]))
+    try:
+        x, y = ht.array(a, split=0), ht.array(y, split=0)
+        kw = dict(lam=0.05 * rows, max_iter=11, tol=-1.0)
+        plain = ht.regression.Lasso(**kw).fit(x, y)
+        chunked = ht.regression.Lasso(**kw, checkpoint_every=4, checkpoint_dir=str(tmp_path / "ck")).fit(x, y)
+    finally:
+        ht.use_comm(ht.WORLD)
+    assert lasso._one_read(rows, features, jnp.float32, True) == bool(devices)
     assert np.array_equal(plain.theta.numpy(), chunked.theta.numpy())
     assert plain.n_iter == chunked.n_iter == 11
 

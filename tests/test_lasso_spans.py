@@ -55,11 +55,14 @@ def _end(rec):
     return rec.start_ns + rec.duration_ns
 
 
-@pytest.mark.parametrize("rows, cols, form, passes", [(ROWS, COLS, "gram", 2), (12, 30, "residual", 19)])
+@pytest.mark.parametrize("rows, cols, form, passes", [(ROWS, COLS, "gram", 2), (4099, 128, "gram", 1), (12, 30, "residual", 19)],
+                         ids=["gram", "gram_through_the_kernel", "residual"])
 def test_fit_leaves_its_spans(one_device, rows, cols, form, passes):
     """The root carries the plan: which form runs, and the reads of the table
-    a fit makes (the Gram's and the moments'; on a wide table the sums of
-    squares', and a sweep's residual and its columns)."""
+    a fit makes (the Gram's and the moments'; ONE where the kernel takes the
+    table, a tile of rows and more at a width of 128, and reads the moments
+    from the Gram's tiles (PR 40); on a wide table the sums of squares', and a
+    sweep's residual and its columns)."""
     x, y = _data(rows, cols)
     _fit(x, y)  # the first call compiles
     telemetry.clear_spans()
