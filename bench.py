@@ -1448,9 +1448,6 @@ def bench_telemetry(ht, sync_floor, roofline=None):
     recorder is a passive excepthook, so this must be ~1.0x);
     ``cost_accounting_miss_us`` — per-miss dispatch cost with
     ``HEAT_TPU_COST_ANALYSIS`` on vs off, plus the recorded flops.
-    Observatory additions (ISSUE 14): ``observatory_note_ns`` — the
-    per-dispatch ledger-note tax on a warm cached key, armed vs
-    disarmed; ``rooflinez_report_us`` — one full roofline-join report.
     The headline value is the enabled span cost — the number that bounds
     how densely the stack can afford to be instrumented."""
     import shutil
@@ -1536,42 +1533,6 @@ def bench_telemetry(ht, sync_floor, roofline=None):
         dispatch.set_cost_accounting(prev_cost)
         dispatch.clear_cache()
 
-    # roofline observatory (ISSUE 14): per-dispatch ledger-note cost on
-    # a warm cached key, armed vs disarmed (the dispatch hot-path tax
-    # the observatory_overhead perf gate bounds at <3% of a whole fit),
-    # one /rooflinez scrape against the live report path, and the
-    # fenced-sample share at the default HEAT_TPU_PERF_SYNC_EVERY
-    from heat_tpu.telemetry import observatory as obsv
-
-    buf2 = jnp.ones((512,), jnp.float32)
-    dispatch.eager_apply(jnp.tanh, (buf2,))  # compile the probe key once
-
-    def dispatch_ns(n: int = 20_000) -> float:
-        t0 = time.perf_counter()
-        for _ in range(n):
-            dispatch.eager_apply(jnp.tanh, (buf2,))
-        return (time.perf_counter() - t0) / n * 1e9
-
-    prev_obs = obsv.set_enabled(True)
-    prev_sync = obsv.set_sync_every(16)
-    try:
-        dispatch_ns(2_000)  # warm
-        obs_on_ns = min(dispatch_ns() for _ in range(3))
-        obsv.set_enabled(False)
-        obs_off_ns = min(dispatch_ns() for _ in range(3))
-        obsv.set_enabled(True)
-        obsv.rooflinez_report(calibrate=False)  # warm
-        t0 = time.perf_counter()
-        for _ in range(50):
-            obsv.rooflinez_report(calibrate=False)
-        rooflinez_report_us = (time.perf_counter() - t0) / 50 * 1e6
-        ledger_rows = len(obsv.ledger_report())
-        sync_share = obsv.sync_every()
-    finally:
-        obsv.set_enabled(prev_obs)
-        obsv.set_sync_every(prev_sync)
-        obsv.reset()
-
     return {
         "metric": "telemetry_span_ns",
         "value": round(enabled_ns, 1),
@@ -1588,12 +1549,6 @@ def bench_telemetry(ht, sync_floor, roofline=None):
         "cost_accounting_miss_us": round(cost_on_us, 2),
         "cost_accounting_off_miss_us": round(cost_off_us, 2),
         "cost_accounting_flops_recorded": flops_recorded,
-        "observatory_note_ns": round(obs_on_ns - obs_off_ns, 1),
-        "observatory_dispatch_ns_armed": round(obs_on_ns, 1),
-        "observatory_dispatch_ns_disarmed": round(obs_off_ns, 1),
-        "observatory_sync_every": sync_share,
-        "observatory_ledger_rows": ledger_rows,
-        "rooflinez_report_us": round(rooflinez_report_us, 1),
     }
 
 

@@ -73,18 +73,6 @@ LOCK_REGISTRY = {
         "structures": ("telemetry.server.singleton", "telemetry.server.routes", "telemetry.server.readiness"),
         "doc": "the process's single IntrospectionServer handle (start_server/stop_server swap it), the registered extra-route map (register_route/unregister_route mutate, handler threads take it briefly for the prefix lookup and call the handler outside it), and the readiness-provider slot /readyz consults",
     },
-    "telemetry.observatory": {
-        "file": "heat_tpu/telemetry/observatory.py",
-        "spellings": ("_LEDGER_LOCK",),
-        "structures": ("telemetry.observatory.ledger",),
-        "doc": "the roofline observatory's execution ledger + resolved device peaks + watermark state: written per dispatch on whichever thread dispatches (fit thread, coalescer batcher), read by /rooflinez//statusz handler threads, the crash excepthook and the atexit metrics dump; the block_until_ready fence and the calibration kernels always run OUTSIDE it",
-    },
-    "telemetry.observatory.profiler": {
-        "file": "heat_tpu/telemetry/observatory.py",
-        "spellings": ("_PROF_LOCK",),
-        "structures": ("telemetry.observatory.profiler",),
-        "doc": "the single-in-flight /profilez capture slot + completed-capture history: started/stopped from HTTP handler threads, auto-stopped by the deadline timer thread; jax.profiler start/stop runs outside it",
-    },
     "telemetry.flight_recorder.hooks": {
         "file": "heat_tpu/telemetry/flight_recorder.py",
         "spellings": ("_LOCK",),

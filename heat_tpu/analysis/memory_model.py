@@ -55,7 +55,6 @@ __all__ = [
     "hbm_budget_bytes",
     "note_estimate",
     "peak_summary",
-    "predicted_peak_bytes",
     "reset_estimates",
     "shard_shapes_of",
 ]
@@ -390,20 +389,6 @@ def note_estimate(label: str, est: PeakEstimate) -> None:
             "temp_bytes": est.temp_bytes,
             "n_eqns": est.n_eqns,
         }
-
-
-def predicted_peak_bytes() -> int:
-    """The worst (largest) per-device peak across the recorded
-    estimates — the static prediction the runtime observatory's HBM
-    watermark cross-checks its *measured* bytes against (0 before any
-    program was walked).  The ``analysis.hbm_predicted_peak_bytes``
-    gauge tracks only the LATEST estimate; the cross-check wants the
-    worst one still live in the table."""
-    with _EST_LOCK:
-        _tsan.note_access("analysis.memory_model.estimates", write=False)
-        if not _ESTIMATES:
-            return 0
-        return max(int(e["per_device_bytes"]) for e in _ESTIMATES.values())
 
 
 def peak_summary() -> Dict[str, Any]:

@@ -75,15 +75,6 @@ KNOBS = {
     "HEAT_TPU_HEALTH_MAX_AGE_S": ("float", "0", "/healthz flips unhealthy when the fit heartbeat is older than this many seconds (0 = staleness check off)"),
     "HEAT_TPU_FLIGHT_RECORDER": ("path", "", "crash flight recorder: write atomic crash bundles into this directory on unhandled exceptions (empty = off)"),
     "HEAT_TPU_COST_ANALYSIS": ("bool", "0", "record per-executable XLA cost/memory analysis at dispatch compile time (/statusz cost accounting)"),
-    # -- roofline observatory (telemetry/observatory.py, /rooflinez) ----
-    "HEAT_TPU_OBSERVATORY": ("bool", "1", "kernel roofline observatory: the dispatch layer notes every cached-executable call's wall time into the per-key execution ledger /rooflinez reports (0 = the dispatch hot path pays one flag check and records nothing)"),
-    "HEAT_TPU_PERF_SYNC_EVERY": ("int", "16", "fenced-sample period of the execution ledger: every Nth call per dispatch key is block_until_ready-fenced so the sample measures device time instead of async enqueue, and piggybacks a throttled HBM watermark cross-check (0 = never fence)"),
-    "HEAT_TPU_PEAK_FLOPS": ("float", "0", "device peak FLOP/s the roofline verdicts compare against (with HEAT_TPU_PEAK_GBPS; 0 = resolve from the calibration cache or the one-shot matmul/copy micro-calibration)"),
-    "HEAT_TPU_PEAK_GBPS": ("float", "0", "device peak memory bandwidth in GB/s for the roofline verdicts (with HEAT_TPU_PEAK_FLOPS; 0 = resolve from the calibration cache or micro-calibration)"),
-    "HEAT_TPU_PEAK_CACHE": ("path", "", "persist the micro-calibrated device peaks to this file (atomic + CRC32 sidecar, invalidated on a jax/backend/device fingerprint change) so fresh processes skip the calibration kernels (empty = in-process only)"),
-    "HEAT_TPU_HBM_ALERT_MARGIN": ("float", "1.25", "measured-vs-predicted watermark margin: the hbm:watermark alert fires when measured memory in use exceeds the static estimator's predicted per-device peak by this factor (or the armed HEAT_TPU_HBM_BUDGET_BYTES at any margin)"),
-    "HEAT_TPU_PROFILE_DIR": ("path", "", "base directory of /profilez on-demand jax.profiler captures (empty = a per-pid directory under the system temp dir)"),
-    "HEAT_TPU_PROFILE_MAX_S": ("float", "30", "hard duration cap of one /profilez capture: every capture auto-stops at min(requested, this) seconds so a forgotten capture can never trace forever"),
     # -- quality signals: SLOs, drift, alerts (docs/observability.md) ---
     "HEAT_TPU_SLO_TICK_S": ("float", "0", "background SLO-monitor evaluation interval in seconds (0 = manual evaluate() only, except a serving process, which defaults its monitor to 1s when the /v1 routes mount)"),
     "HEAT_TPU_SLO_FAST_WINDOW_S": ("float", "60", "fast burn-rate window of the SLO monitors (page-latency window)"),

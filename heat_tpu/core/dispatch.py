@@ -86,7 +86,6 @@ from ..resilience.errors import ChecksumError as _ChecksumError
 from ..resilience.errors import PermanentFault as _PermanentFault
 from ..resilience.faults import inject as _inject
 from ..telemetry import metrics as _tm
-from ..telemetry import observatory as _obsv
 from ..telemetry.spans import span as _span
 from . import _env as _env
 from . import aot_cache as _aot
@@ -814,8 +813,8 @@ def _run(compiled, leaves, n_ops: int, sp: _launch, donated: bool = False,
     else:
         out = call()
     dt = time.perf_counter() - t0
-    # the launch ends with the enqueue: what follows may wait for the
-    # device (every Nth note() of a key is fenced) or lower again (cost)
+    # the launch ends with the enqueue; what follows never waits for the
+    # device: a miss's compile time and cost record, then the meter
     sp.end()
     if fresh:
         # record the wall time so ``where did the compile time go?`` is
@@ -827,11 +826,6 @@ def _run(compiled, leaves, n_ops: int, sp: _launch, donated: bool = False,
             with warnings.catch_warnings():
                 warnings.filterwarnings("ignore", message=".*[Dd]onat")
                 _record_cost(key, compiled, leaves)
-    elif key is not None and _obsv.armed():
-        # roofline observatory: every warm call is a measurement
-        # (monotonic enqueue time; every Nth per key is fenced
-        # inside note() so the sample measures device time)
-        _obsv.note(key, dt, out)
     _meter_note(key)
     return out
 

@@ -331,17 +331,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         res = svc.prewarm(path=args.prewarm)
         print(f"replica prewarm: {json.dumps(res)}", flush=True)
     svc.set_state("ready")
-    # prime the observatory before the first routed request: one forced
-    # watermark sample so the router's very first /rooflinez poll sees a
-    # real memory number, and a provenance line for the replica log
-    from ..telemetry import observatory
-
-    observatory.watermark_tick(force=True)
-    print(
-        f"replica observatory: enabled={observatory.armed()} "
-        f"sync_every={observatory.sync_every()}",
-        flush=True,
-    )
     print(f"replica ready on {url}", flush=True)
 
     # SIGTERM -> graceful drain: readiness flips to "draining", the

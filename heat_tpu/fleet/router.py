@@ -50,17 +50,15 @@ Run in-process (tests, the autoscaler harness) or as its own process::
 
 Routes: ``/v1/*`` proxied with failover; ``/fleet/statusz`` (replica
 table, breaker states, counters), ``/fleet/healthz`` (200 iff >= 1
-ready replica), ``/fleetz`` (fleet-wide roofline rollup: the health
-poller collects each ready replica's ``/rooflinez`` observatory
-snapshot and this route renders the merged per-kernel utilization +
-watermark table, slowest replica per key highlighted via the PR 6
-straggler score, plus each ready replica's ``/canaryz`` canary
-decision-plane snapshot rolled into a fleet-wide per-model verdict
-table with divergent-replica highlighting; ``?format=json`` for the
-machine form), ``/tenantz`` (the fleet-merged per-tenant cost ledger:
-each ready replica's ``/tenantz`` accounts summed per tenant via
-``aggregate.merge_tenant_accounts`` — the fleet answer to "which tenant
-cost what"), ``/metrics`` (the router process's own registry).
+ready replica), ``/fleetz`` (fleet rollup: the health poller collects
+each ready replica's ``/canaryz`` canary decision-plane snapshot,
+rolled into a fleet-wide per-model verdict table with divergent-replica
+highlighting, its ``/tenantz`` accounts and its ``/decisionz`` journal;
+``?format=json`` for the machine form), ``/tenantz`` (the
+fleet-merged per-tenant cost ledger: each ready replica's ``/tenantz``
+accounts summed per tenant via ``aggregate.merge_tenant_accounts`` —
+the fleet answer to "which tenant cost what"), ``/metrics`` (the
+router process's own registry).
 """
 
 from __future__ import annotations
@@ -121,8 +119,7 @@ class _Replica:
     __slots__ = (
         "url", "ready", "state", "models", "not_models", "inflight", "fails",
         "cb_open", "cb_open_until", "probing", "last_poll_ok", "added_at",
-        "observatory", "observatory_ts", "canary", "canary_ts",
-        "tenants", "tenants_ts", "journal", "journal_ts",
+        "sweep_ts", "canary", "tenants", "journal",
     )
 
     def __init__(self, url: str):
@@ -138,23 +135,18 @@ class _Replica:
         self.probing = False
         self.last_poll_ok = 0.0
         self.added_at = time.time()
-        #: last /rooflinez?format=json snapshot the health poller pulled
-        #: (None until the replica answers one) — the /fleetz rollup's
-        #: per-replica half
-        self.observatory: Optional[Dict[str, Any]] = None
-        self.observatory_ts = 0.0
-        #: last /canaryz?format=json snapshot (same throttled cadence) —
-        #: the fleet-wide canary rollup's per-replica half
+        #: when the health poller last swept this replica's snapshots
+        #: (the throttled cadence of the three below)
+        self.sweep_ts = 0.0
+        #: last /canaryz?format=json snapshot (None until the replica
+        #: answers one) — the fleet-wide canary rollup's per-replica half
         self.canary: Optional[Dict[str, Any]] = None
-        self.canary_ts = 0.0
-        #: last /tenantz?format=json snapshot (same throttled cadence) —
-        #: the fleet-wide per-tenant cost rollup's per-replica half
+        #: last /tenantz?format=json snapshot — the fleet-wide
+        #: per-tenant cost rollup's per-replica half
         self.tenants: Optional[Dict[str, Any]] = None
-        self.tenants_ts = 0.0
-        #: last /decisionz?format=json snapshot (same throttled cadence) —
-        #: the fleet-wide decision-timeline rollup's per-replica half
+        #: last /decisionz?format=json snapshot — the fleet-wide
+        #: decision-timeline rollup's per-replica half
         self.journal: Optional[Dict[str, Any]] = None
-        self.journal_ts = 0.0
 
     def doc(self) -> Dict[str, Any]:
         return {
@@ -349,22 +341,20 @@ class FleetRouter:
         with self._lock:
             _tsan.note_access("fleet.router.replicas", write=False)
             urls = list(self._replicas)
-            obs_ts = {u: self._replicas[u].observatory_ts for u in urls}
+            sweep_ts = {u: self._replicas[u].sweep_ts for u in urls}
         now = time.time()
-        # the observatory sweep runs on its own (slower) cadence: the
-        # readiness poll can tick sub-second, but re-pulling a ledger
-        # snapshot that fast buys nothing and the replica's first
-        # /rooflinez answer may include its one-shot peak calibration
-        obs_period = max(self.health_period_s, 2.0)
+        # the snapshot sweep runs on its own (slower) cadence: the
+        # readiness poll can tick sub-second, but re-pulling three
+        # snapshots that fast buys nothing
+        sweep_period = max(self.health_period_s, 2.0)
         for url in urls:
             ready, state, models = self._probe_readyz(url)
-            # the same sweep collects the replica's roofline-observatory
-            # and canary-decision-plane snapshots (the per-replica halves
-            # of the /fleetz fleet rollup) on the throttled cadence.
-            # Only ready replicas are asked: a warming/draining replica's
-            # ledger and windows are noise.
-            due = ready and now - obs_ts.get(url, 0.0) >= obs_period
-            obs = self._probe_rooflinez(url) if due else None
+            # the same sweep collects the replica's canary, tenant and
+            # journal snapshots (the per-replica halves of the /fleetz
+            # fleet rollup) on the throttled cadence.  Only ready
+            # replicas are asked: a warming/draining replica's windows
+            # are noise.
+            due = ready and now - sweep_ts.get(url, 0.0) >= sweep_period
             can = self._probe_canaryz(url) if due else None
             ten = self._probe_tenantz(url) if due else None
             jnl = self._probe_decisionz(url) if due else None
@@ -373,18 +363,14 @@ class FleetRouter:
                 r = self._replicas.get(url)
                 if r is None:
                     continue
-                if obs is not None:
-                    r.observatory = obs
-                    r.observatory_ts = time.time()
+                if due:
+                    r.sweep_ts = now
                 if can is not None:
                     r.canary = can
-                    r.canary_ts = time.time()
                 if ten is not None:
                     r.tenants = ten
-                    r.tenants_ts = time.time()
                 if jnl is not None:
                     r.journal = jnl
-                    r.journal_ts = time.time()
                 if r.state == "draining" and state not in ("ready",):
                     # a locally initiated drain sticks until the replica
                     # itself reports ready again (a cancelled drain)
@@ -416,18 +402,6 @@ class FleetRouter:
         models = doc.get("models")
         models = frozenset(str(m) for m in models) if isinstance(models, list) else None
         return code == 200 and bool(doc.get("ready", code == 200)), state, models
-
-    def _probe_rooflinez(self, url: str) -> Optional[Dict[str, Any]]:
-        """One replica's observatory snapshot, or None (replica without
-        the route, unreachable, or malformed — never raises)."""
-        try:
-            with urllib.request.urlopen(
-                url + "/rooflinez?format=json&limit=64", timeout=2.0
-            ) as resp:
-                doc = json.load(resp)
-            return doc if isinstance(doc, dict) else None
-        except Exception:  # lint: allow H501(an observatory-less replica is a rollup gap, not an error)
-            return None
 
     def _probe_canaryz(self, url: str) -> Optional[Dict[str, Any]]:
         """One replica's canary decision-plane snapshot, or None
@@ -873,24 +847,13 @@ class FleetRouter:
             return 200, _tm.expose(), OPENMETRICS_CONTENT_TYPE, {}
         return 404, json.dumps({"error": f"unknown route {path!r}"}), "application/json", {}
 
-    # -- fleet-wide roofline rollup (/fleetz) ---------------------------
+    # -- fleet-wide rollup (/fleetz) -------------------------------------
     def fleetz_report(self) -> Dict[str, Any]:
-        """The fleet-wide observatory rollup: every polled replica's
-        watermark + calibration provenance, and each dispatch key's
-        per-replica roofline rows merged into one record with the
-        slowest replica named and its relative excess scored by the
-        PR 6 straggler machinery (``aggregate.straggler_score`` over
-        the per-replica fenced means — ``0`` balanced, ``1`` = the
-        slowest replica takes 2x the median)."""
-        from ..telemetry.aggregate import straggler_score
-
+        """The fleet-wide rollup of every polled replica's snapshots:
+        per-model canary verdicts (divergent replicas flagged), the
+        merged tenant accounts, and the interleaved decision timeline."""
         with self._lock:
             _tsan.note_access("fleet.router.replicas", write=False)
-            snaps = {
-                r.url: (dict(r.observatory), r.observatory_ts)
-                for r in self._replicas.values()
-                if r.observatory is not None
-            }
             canary_snaps = {
                 r.url: dict(r.canary)
                 for r in self._replicas.values()
@@ -906,38 +869,6 @@ class FleetRouter:
                 for r in self._replicas.values()
                 if r.journal is not None
             }
-        replicas: Dict[str, Any] = {}
-        kernels: Dict[str, Dict[str, Any]] = {}
-        now = time.time()
-        for url in sorted(snaps):
-            obs, ts = snaps[url]
-            replicas[url] = {
-                "watermark": obs.get("watermark"),
-                "peaks": obs.get("peaks"),
-                "ledger_rows": obs.get("ledger_total", len(obs.get("ledger") or [])),
-                "snapshot_age_s": round(now - ts, 3),
-            }
-            for row in obs.get("ledger") or []:
-                key = row.get("key")
-                if not key:
-                    continue
-                kernels.setdefault(key, {"replicas": {}})["replicas"][url] = {
-                    "calls": row.get("calls"),
-                    "mean_ms": row.get("mean_ms"),
-                    "timing": row.get("timing"),
-                    "gflops_per_s": row.get("gflops_per_s"),
-                    "gbytes_per_s": row.get("gbytes_per_s"),
-                    "utilization": row.get("utilization"),
-                    "bound": row.get("bound"),
-                }
-        for key, entry in kernels.items():
-            per = entry["replicas"]
-            means = [(u, per[u].get("mean_ms")) for u in sorted(per)]
-            known = [(u, m) for u, m in means if m is not None]
-            entry["slowest"] = max(known, key=lambda um: um[1])[0] if known else None
-            entry["straggler_score"] = round(
-                straggler_score([m for _u, m in means]), 4
-            )
         # fleet-wide canary rollup: each replica runs its own decision
         # plane over its own shadow traffic — a model whose replicas
         # disagree on the canary version or verdict is DIVERGENT, the
@@ -984,90 +915,24 @@ class FleetRouter:
             + [("router", _journal.journal_snapshot())]
         )
         return {
-            "timestamp": now,
+            "timestamp": time.time(),
             "ready_replicas": self._count_ready(),
-            "replicas": replicas,
-            "kernels": dict(sorted(kernels.items())),
             "canary": dict(sorted(canary_models.items())),
             "tenants": tenants,
             "decisions": decisions,
         }
 
     def render_fleetz_html(self) -> str:
-        """The human form of ``/fleetz``: per-replica watermark header +
-        the fleet-wide per-kernel utilization table, the slowest replica
-        per key highlighted."""
+        """The human form of ``/fleetz``: the fleet canary state, tenant
+        accounts and decision timeline."""
         import html as _html
 
         doc = self.fleetz_report()
         parts = [
             "<html><head><title>/fleetz</title></head><body>",
-            "<h1>/fleetz — fleet roofline rollup</h1>",
-            f"<p>{doc['ready_replicas']} ready replica(s), "
-            f"{len(doc['replicas'])} with observatory snapshots</p>",
-            "<table border=1 cellpadding=3><tr><th>replica</th><th>in use MiB</th>"
-            "<th>predicted MiB</th><th>budget MiB</th><th>peaks</th>"
-            "<th>ledger rows</th><th>age s</th></tr>",
+            "<h1>/fleetz — fleet rollup</h1>",
+            f"<p>{doc['ready_replicas']} ready replica(s)</p>",
         ]
-        for url, rep in doc["replicas"].items():
-            wm = rep.get("watermark") or {}
-            peaks = rep.get("peaks")
-            peaks_s = (
-                f"{float(peaks['flops']) / 1e9:.0f} GF/s · "
-                f"{float(peaks['bytes_per_s']) / 1e9:.0f} GB/s ({peaks['source']})"
-                if peaks
-                else "—"
-            )
-            parts.append(
-                "<tr>"
-                f"<td>{_html.escape(url)}</td>"
-                f"<td>{float(wm.get('bytes_in_use') or 0) / 2**20:.1f}</td>"
-                f"<td>{float(wm.get('predicted_peak_bytes') or 0) / 2**20:.1f}</td>"
-                f"<td>{float(wm.get('budget_bytes') or 0) / 2**20:.1f}</td>"
-                f"<td>{_html.escape(peaks_s)}</td>"
-                f"<td>{rep.get('ledger_rows')}</td>"
-                f"<td>{rep.get('snapshot_age_s')}</td>"
-                "</tr>"
-            )
-        parts.append("</table><h2>per-kernel utilization</h2>")
-        parts.append(
-            "<table border=1 cellpadding=3><tr><th>executable</th><th>replica</th>"
-            "<th>calls</th><th>mean ms</th><th>GFLOP/s</th><th>GB/s</th>"
-            "<th>util</th><th>bound</th><th>straggler</th></tr>"
-        )
-        for key, entry in doc["kernels"].items():
-            per = entry["replicas"]
-            first = True
-            for url in sorted(per):
-                row = per[url]
-                slow = url == entry.get("slowest") and len(per) > 1
-                cell = _html.escape(url)
-                if slow:
-                    cell = f"<b style='color:#b00'>{cell} ⟵ slowest</b>"
-                parts.append(
-                    "<tr>"
-                    + (
-                        f"<td rowspan={len(per)}>{_html.escape(str(key))}</td>"
-                        if first
-                        else ""
-                    )
-                    + f"<td>{cell}</td>"
-                    f"<td>{row.get('calls')}</td><td>{row.get('mean_ms')}</td>"
-                    f"<td>{row.get('gflops_per_s') if row.get('gflops_per_s') is not None else '—'}</td>"
-                    f"<td>{row.get('gbytes_per_s') if row.get('gbytes_per_s') is not None else '—'}</td>"
-                    f"<td>{row.get('utilization') if row.get('utilization') is not None else '—'}</td>"
-                    f"<td>{_html.escape(str(row.get('bound')))}</td>"
-                    + (
-                        f"<td rowspan={len(per)}>{entry.get('straggler_score')}</td>"
-                        if first
-                        else ""
-                    )
-                    + "</tr>"
-                )
-                first = False
-        parts.append("</table>")
-        if not doc["kernels"]:
-            parts.append("<p>no per-kernel snapshots collected yet</p>")
         parts.append("<h2>fleet canary state</h2>")
         canary = doc.get("canary") or {}
         if canary:

@@ -186,17 +186,14 @@ class InferenceService:
         #: ``load(activate=False)``), compares online, auto-promotes /
         #: auto-rolls-back — see serving/canary.py and /canaryz
         self.canary = _canary.CanaryController(self)
-        # roofline join: with the observatory armed, every predict
-        # bucket's compile records its XLA flops/bytes so /rooflinez can
-        # pair them with measured time.  Serving compiles are bounded
-        # (one per (model, bucket)), so the per-miss accounting cost is
-        # a warmup-only tax; processes with the observatory disabled
-        # keep cost accounting at its knob default.
+        # tenant metering (/tenantz, telemetry/tenants.py) bills each
+        # batch by the analyzed FLOPs/bytes of what it dispatched, and
+        # those exist only where a compile recorded its cost.  Serving
+        # compiles are bounded (one per (model, bucket)), so the
+        # per-miss accounting is a warmup-only tax.
         from ..core import dispatch as _dispatch
-        from ..telemetry import observatory as _observatory
 
-        if _observatory.armed() and not _dispatch.cost_accounting_enabled():
-            _dispatch.set_cost_accounting(True)
+        _dispatch.set_cost_accounting(True)
 
     # -- model lifecycle (thin registry delegates) ----------------------
     def load(self, name: str, directory: str, **kwargs) -> int:

@@ -71,7 +71,6 @@ from . import slo
 from . import sketch
 from . import aggregate
 from . import flight_recorder
-from . import observatory
 from . import server
 from .metrics import (
     Counter,
@@ -114,13 +113,6 @@ from .aggregate import (
     write_worker_snapshot,
 )
 from .flight_recorder import dump_bundle
-from .observatory import (
-    device_peaks,
-    rooflinez_report,
-    start_capture,
-    stop_capture,
-    watermark_tick,
-)
 from .server import start_server, stop_server
 from .alerts import active_alerts, alert_events, alerts_snapshot
 from .journal import (
@@ -193,7 +185,6 @@ __all__ = [
     "counter",
     "current_context",
     "current_trace_id",
-    "device_peaks",
     "dump_bundle",
     "dump_json",
     "exchange_exposure",
@@ -209,14 +200,11 @@ __all__ = [
     "record_span",
     "request_span",
     "reset_all",
-    "rooflinez_report",
     "set_tracing",
     "snapshot",
     "span",
-    "start_capture",
     "start_server",
     "start_trace",
-    "stop_capture",
     "stop_server",
     "stop_trace",
     "summary_line",
@@ -225,7 +213,6 @@ __all__ = [
     "tracez_report",
     "tracing_enabled",
     "use_context",
-    "watermark_tick",
     "write_worker_snapshot",
 ]
 
@@ -246,12 +233,10 @@ _DOMAIN_PREFIXES = {
     "alerts": ("alerts.",),
     "slo": ("slo.",),
     "drift": ("drift.",),
-    "observatory": ("observatory.",),
     "journal": ("journal.",),
     "tsdb": ("tsdb.",),
     "telemetry": ("spans.", "tracing.", "fit.", "telemetry.", "flight.",
-                  "checkpoint.", "alerts.", "slo.", "drift.", "observatory.",
-                  "journal.", "tsdb."),
+                  "checkpoint.", "alerts.", "slo.", "drift.", "journal.", "tsdb."),
 }
 
 
@@ -273,7 +258,6 @@ def reset_all(domain: Optional[str] = None) -> None:
         alerts.clear_alerts()
         slo.reset_monitors()
         sketch.SKETCHES.clear()
-        observatory.reset()
         journal.reset_journal()
         tsdb.reset_tsdb()
         return
@@ -294,8 +278,6 @@ def reset_all(domain: Optional[str] = None) -> None:
         slo.reset_monitors()
     if domain in ("drift", "telemetry"):
         sketch.SKETCHES.clear()
-    if domain in ("observatory", "telemetry"):
-        observatory.reset()
     if domain in ("journal", "telemetry"):
         journal.reset_journal()
     if domain in ("tsdb", "telemetry"):

@@ -24,8 +24,6 @@ One bundle carries everything the post-mortem needs::
     drift       per-model input-drift scores vs their baselines
     canary      the canary decision plane: per-model shadow evidence
                 windows, decision history, veto reasons, retained events
-    observatory the roofline execution ledger + the last HBM watermark
-                sample vs the static prediction + calibration provenance
     journal     the decision journal's hot ring: the control-plane
                 actions (scale, rollback, preempt, reshard) that led
                 into the crash, each with causal link + evidence
@@ -276,20 +274,6 @@ def _analysis_state() -> Optional[Dict[str, Any]]:
         return None
 
 
-def _observatory_state() -> Optional[Dict[str, Any]]:
-    """The roofline observatory at crash time: execution ledger (was a
-    kernel suddenly slow?), the last HBM watermark sample vs the static
-    prediction (was this an OOM the watermark saw coming?), and the
-    calibration provenance.  Never calibrates — a crash dump must not
-    run device kernels."""
-    try:
-        from . import observatory as _observatory
-
-        return _observatory.snapshot(calibrate=False)
-    except Exception:  # lint: allow H501(forensics degrade field-by-field, never abort the bundle)
-        return None
-
-
 def _elastic_state() -> Optional[Dict[str, Any]]:
     """World size + loss/reshape counters at crash time — the first
     question a preemption postmortem asks."""
@@ -358,7 +342,6 @@ def build_bundle(
             "findings": _tsan.findings(),
         },
         "analysis": _analysis_state(),
-        "observatory": _observatory_state(),
         "elastic": _elastic_state(),
         "journal": _journal_state(),
         "tsdb": _tsdb_state(),

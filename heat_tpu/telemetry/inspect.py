@@ -316,45 +316,6 @@ def format_bundle(doc: Dict[str, Any], n_metrics: int = 20, n_spans: int = 15) -
                     f"    {rec.get('per_device_bytes', 0):>14,} B  {label}"
                 )
 
-    obs = doc.get("observatory") or {}
-    obs_ledger = obs.get("ledger") or []
-    if obs:
-        lines.append(_rule(
-            f"observatory ({len(obs_ledger)} tracked executable(s), "
-            f"sync_every={obs.get('sync_every')})"
-        ))
-        peaks = obs.get("peaks")
-        if peaks:
-            lines.append(
-                f"device peaks [{peaks.get('source')}]: "
-                f"{float(peaks.get('flops') or 0) / 1e9:.1f} GFLOP/s · "
-                f"{float(peaks.get('bytes_per_s') or 0) / 1e9:.1f} GB/s"
-            )
-        wm = obs.get("watermark")
-        if wm:
-            lines.append(
-                f"watermark [{wm.get('source')}]: "
-                f"{float(wm.get('bytes_in_use') or 0) / 2**20:.1f} MiB in use, "
-                f"peak seen {float(wm.get('peak_seen_bytes') or 0) / 2**20:.1f} MiB, "
-                f"predicted {float(wm.get('predicted_peak_bytes') or 0) / 2**20:.1f} MiB, "
-                f"budget {float(wm.get('budget_bytes') or 0) / 2**20:.1f} MiB"
-            )
-        for r in obs_ledger[:10]:
-            util = r.get("utilization")
-            lines.append(
-                f"  {r.get('calls'):>7} calls  {r.get('mean_ms')} ms "
-                f"[{r.get('timing')}]  "
-                + (
-                    f"{r.get('gflops_per_s')} GFLOP/s " if r.get("gflops_per_s") else ""
-                )
-                + (f"{r.get('gbytes_per_s')} GB/s " if r.get("gbytes_per_s") else "")
-                + f"{r.get('bound')}"
-                + (f" util={util}" if util is not None else "")
-                + f"  {r.get('key')}"
-            )
-        if len(obs_ledger) > 10:
-            lines.append(f"  ... {len(obs_ledger) - 10} more")
-
     rt = doc.get("runtime") or {}
     lines.append(_rule("runtime"))
     lines.append(

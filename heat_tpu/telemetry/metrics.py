@@ -65,9 +65,9 @@ __all__ = [
 
 #: extra named sections embedded in the ``HEAT_TPU_METRICS_DUMP``
 #: atexit JSON beside the metrics snapshot: name -> zero-arg provider
-#: (the observatory registers its ledger/watermark/calibration section
-#: here).  Registered at import time on the main thread, read only at
-#: dump time; a provider failure drops its section, never the dump.
+#: (the tenant meter registers its accounts here).  Registered at
+#: import time on the main thread, read only at dump time; a provider
+#: failure drops its section, never the dump.
 _DUMP_SECTIONS: "Dict[str, Callable[[], Any]]" = {}
 
 

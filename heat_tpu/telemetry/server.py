@@ -34,18 +34,10 @@ route     payload
           the verdict + veto reasons, and the retained comparison/
           decision event timeline with exemplar trace_ids; HTML by
           default, ``?format=json`` for the machine form
-/rooflinez  kernel roofline observatory: per-executable measured time
-          joined with cost-accounting FLOPs/bytes — achieved GFLOP/s,
-          GB/s, intensity and bound-class vs the device peaks, plus the
-          live HBM watermark; HTML by default, ``?format=json``
 /tenantz  per-tenant cost accounts (QoS scheduling): rows, analyzed
           FLOPs/bytes and device-ms per serving tenant, pro-rata split
           of every coalesced batch, summing to the process total; HTML
           by default, ``?format=json`` for the machine form
-/profilez on-demand bounded ``jax.profiler`` capture: POST
-          ``/profilez/start[?duration_s=]`` / ``/profilez/stop``
-          (single in-flight, 409 on conflict), GET lists completed
-          captures with downloadable artifacts
 /decisionz  control-plane decision journal: every autonomous action
           (autoscaler, canary, refresh driver, preemption, circuit
           breakers, reshape, reshard, alert transitions) as a typed
@@ -89,7 +81,6 @@ from ..analysis import tsan as _tsan
 from . import alerts as _alerts
 from . import journal as _journal
 from . import metrics as _metrics
-from . import observatory as _observatory
 from . import sketch as _sketch
 from . import slo as _slo
 from . import spans as _spans
@@ -130,7 +121,7 @@ BUILTIN_ROUTES = (
      "purpose": "tail-sampled request traces per route; `?trace_id=` for one span tree",
      "knobs": ("HEAT_TPU_TRACE_KEEP", "HEAT_TPU_TRACE_MAX_SPANS")},
     {"route": "/statusz", "owner": "server", "html": False,
-     "purpose": "every knob's effective value, dispatch cache + cost accounting, analysis + observatory + elastic sections, runtime/build info",
+     "purpose": "every knob's effective value, dispatch cache + cost accounting, analysis + elastic sections, runtime/build info",
      "knobs": ()},
     {"route": "/sloz", "owner": "server", "html": True,
      "purpose": "SLO burn-rate monitors + active alert table",
@@ -141,13 +132,6 @@ BUILTIN_ROUTES = (
     {"route": "/canaryz", "owner": "server", "html": True,
      "purpose": "canary decision plane: per-model shadow evidence window (rows compared, mismatch rate, latency ratio), verdict + veto reasons, retained comparison/decision events with exemplar trace_ids",
      "knobs": ("HEAT_TPU_SHADOW_*", "HEAT_TPU_CANARY_*")},
-    {"route": "/rooflinez", "owner": "server", "html": True,
-     "purpose": "kernel roofline observatory: per-executable measured GFLOP/s, GB/s, intensity, bound-class + HBM watermark",
-     "knobs": ("HEAT_TPU_OBSERVATORY", "HEAT_TPU_PERF_SYNC_EVERY",
-               "HEAT_TPU_PEAK_*", "HEAT_TPU_HBM_*")},
-    {"route": "/profilez", "owner": "server", "html": True,
-     "purpose": "on-demand bounded `jax.profiler` capture: `POST /profilez/start` / `/stop`, artifact download",
-     "knobs": ("HEAT_TPU_PROFILE_DIR", "HEAT_TPU_PROFILE_MAX_S")},
     {"route": "/tenantz", "owner": "server", "html": True,
      "purpose": "per-tenant cost accounts: analyzed FLOPs/bytes + device-ms per tenant, pro-rata by rows over coalesced batches; accounts sum to the derived total (the fleet router serves the same route merged across replicas)",
      "knobs": ("HEAT_TPU_QOS_METER",)},
@@ -159,7 +143,7 @@ BUILTIN_ROUTES = (
      "knobs": ("HEAT_TPU_TSDB_INTERVAL_S", "HEAT_TPU_TSDB_RETENTION",
                "HEAT_TPU_TSDB_SERIES")},
     {"route": "/fleetz", "owner": "fleet.router", "html": True,
-     "purpose": "*(router)* fleet-wide per-kernel utilization + watermark rollup (slowest replica per key highlighted) + per-model canary verdicts across replicas (divergent replicas highlighted) + the merged tenant-account table + the interleaved cross-replica decision timeline",
+     "purpose": "*(router)* fleet rollup: per-model canary verdicts across replicas (divergent replicas highlighted) + the merged tenant-account table + the interleaved cross-replica decision timeline",
      "knobs": ("HEAT_TPU_FLEET_HEALTH_PERIOD_S",)},
     {"route": "/v1/*", "owner": "serving.service", "html": False,
      "purpose": "serving: `/v1/models`, `POST /v1/predict`, per-model `/v1/models/<name>/healthz`",
@@ -431,11 +415,6 @@ def statusz_report() -> Dict[str, Any]:
     except Exception:  # lint: allow H501(introspection page degrades, never breaks the process)
         doc["analysis"] = None
     try:
-        # compact embed: never calibrates or runs device work from a scrape
-        doc["observatory"] = _observatory.snapshot(calibrate=False, max_rows=20)
-    except Exception:  # lint: allow H501(introspection page degrades, never breaks the process)
-        doc["observatory"] = None
-    try:
         doc["alerts"] = {
             "active": _alerts.active_alerts(),
             "recent_events": _alerts.alert_events(limit=10),
@@ -597,16 +576,6 @@ class _Handler(BaseHTTPRequestHandler):
                     self._send_json(_canary.canaryz_report())
                 else:
                     self._send(200, _canary.render_canaryz_html(), "text/html")
-            elif path == "/rooflinez":
-                params = self._query_params()
-                if params.get("format") == "json":
-                    try:
-                        limit = int(params["limit"]) if "limit" in params else None
-                    except ValueError:
-                        limit = None
-                    self._send_json(_observatory.rooflinez_report(limit=limit))
-                else:
-                    self._send(200, _observatory.render_rooflinez_html(), "text/html")
             elif path == "/tenantz":
                 from . import tenants as _tenants
 
@@ -619,29 +588,6 @@ class _Handler(BaseHTTPRequestHandler):
                     self._send_json(_tenants.tenantz_report(limit=limit))
                 else:
                     self._send(200, _tenants.render_tenantz_html(), "text/html")
-            elif path == "/profilez":
-                if self._query_params().get("format") == "json":
-                    self._send_json(_observatory.capture_status())
-                else:
-                    self._send(200, _observatory.render_profilez_html(), "text/html")
-            elif path == "/profilez/artifact":
-                name = self._query_params().get("name", "")
-                try:
-                    p = _observatory.artifact_path(name)
-                except (FileNotFoundError, PermissionError) as e:
-                    self._send_json({"error": str(e)}, 404)
-                else:
-                    with open(p, "rb") as f:
-                        data = f.read()
-                    self.send_response(200)
-                    self.send_header("Content-Type", "application/octet-stream")
-                    self.send_header("Content-Length", str(len(data)))
-                    self.send_header(
-                        "Content-Disposition",
-                        f'attachment; filename="{os.path.basename(p)}"',
-                    )
-                    self.end_headers()
-                    self.wfile.write(data)
             elif path == "/decisionz":
                 params = self._query_params()
                 event_id = params.get("event_id")
@@ -681,7 +627,7 @@ class _Handler(BaseHTTPRequestHandler):
                     200,
                     "heat_tpu runtime introspection: "
                     "/metrics /varz /healthz /readyz /trace /tracez /sloz /driftz "
-                    "/canaryz /rooflinez /tenantz /profilez /decisionz /queryz "
+                    "/canaryz /tenantz /decisionz /queryz "
                     "/statusz"
                     + (f" | mounted: {extra}" if extra else "")
                     + "\n",
@@ -701,24 +647,6 @@ class _Handler(BaseHTTPRequestHandler):
 
     def do_POST(self) -> None:  # noqa: N802 - http.server API
         try:
-            path = self.path.split("?", 1)[0].rstrip("/")
-            if path in ("/profilez/start", "/profilez/stop"):
-                try:
-                    if path.endswith("start"):
-                        raw = self._query_params().get("duration_s")
-                        doc = _observatory.start_capture(
-                            float(raw) if raw is not None else None
-                        )
-                    else:
-                        doc = _observatory.stop_capture()
-                    self._send_json(doc)
-                except RuntimeError as e:
-                    # single in-flight / nothing running: a state
-                    # conflict, not a server error
-                    self._send_json({"error": str(e)}, 409)
-                except ValueError as e:
-                    self._send_json({"error": str(e)}, 400)
-                return
             length = int(self.headers.get("Content-Length") or 0)
             body = self.rfile.read(length) if length else b""
             if not self._dispatch_route("POST", self.path.split("?", 1)[0], body):

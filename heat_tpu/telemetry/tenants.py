@@ -19,7 +19,7 @@ merge_tenant_accounts`), and included in the metrics dump bundle.
 Totals are *derived* — :func:`tenantz_report` sums the tenant rows —
 so "accounts sum to the total" holds by construction; the interesting
 invariant (asserted by the QoS tests) is that the total matches the
-fleet-wide work the observatory saw.
+analyzed cost of the batches the process dispatched.
 """
 
 from __future__ import annotations

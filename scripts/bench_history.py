@@ -449,8 +449,7 @@ def render_markdown(records: list, out_path: str) -> None:
         " bytes-moved-model / time / stream-anchor — a dimensionless"
         " fraction of the kernel's *minimal regime traffic* at the"
         " runner's own measured bandwidth, not a bare one-pass ratio"
-        " (ROADMAP 5b).  The models (validated against the roofline"
-        " observatory's per-key bytes×time ledger, `/rooflinez`):",
+        " (ROADMAP 5b).  The models:",
         "",
         "| kernel | bytes-moved model |",
         "|---|---|",
@@ -476,8 +475,7 @@ def render_markdown(records: list, out_path: str) -> None:
         " so the bytes model stays per-axis-pass · 2 · elsize |",
         "",
         "Each record also carries `model_gbytes_per_s` (the model over"
-        " the measured time) so the anchored ratio is auditable against"
-        " the observatory's achieved-GB/s numbers.",
+        " the measured time) so the anchored ratio is auditable.",
         "",
         "See also: [observability](observability.md), the committed gate"
         " record `BENCH_CI.json`, and `scripts/perf_gate.py` for the"

@@ -36,7 +36,6 @@ MODULES = [
     ("Decision journal", "heat_tpu.telemetry.journal", "typed control-plane decision events with causal links + evidence, bounded hot ring + durable atomic/CRC segment log (/decisionz; docs/observability.md)"),
     ("Metric history (TSDB)", "heat_tpu.telemetry.tsdb", "embedded fixed-interval metric history: allowlisted series sampled into bounded rings, range queries + window stats (/queryz; docs/observability.md)"),
     ("Journal replay", "heat_tpu.telemetry.replay", "offline reconstruction of the decision timeline and causal chains from a durable journal directory (python -m heat_tpu.telemetry.replay; docs/observability.md)"),
-    ("Roofline observatory", "heat_tpu.telemetry.observatory", "per-executable runtime attribution: sampled execution ledger, device-peak calibration, live HBM watermarks, on-demand profiler capture (/rooflinez + /profilez; docs/observability.md)"),
     ("Static analysis", "heat_tpu.analysis", "SPMD program lint (J101-J105) + framework-invariant AST lint (H101-H601, H701-H705) (docs/static_analysis.md)"),
     ("Dtype-flow lint", "heat_tpu.analysis.dtype_flow", "jaxpr precision lint: silent truncation, low-precision accumulation, unpinned contractions, policy violations (J201-J204; docs/static_analysis.md)"),
     ("Peak-HBM estimator", "heat_tpu.analysis.memory_model", "static per-device peak-memory prediction from the jaxpr (liveness + donation + sharding), J301 against HEAT_TPU_HBM_BUDGET_BYTES (docs/static_analysis.md)"),

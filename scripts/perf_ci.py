@@ -216,9 +216,8 @@ def main():
     # PSRS touches every f32 key ~7 times (local sort read+write, pivot
     # partition read, all-to-all exchange read+write, final merge
     # read+write), so the ratio now reads as "fraction of the minimal
-    # PSRS traffic the kernel sustains vs the stream anchor" (the same
-    # quantity /rooflinez reports per dispatch key from its bytes×time
-    # ledger; docs/perf_history.md "Regime anchors").
+    # PSRS traffic the kernel sustains vs the stream anchor"
+    # (docs/perf_history.md "Regime anchors").
     def bench_sort():
         n_el = 1 << 18
         xs = ht.random.randn(n_el, split=0).astype(ht.float32)
@@ -1523,225 +1522,6 @@ def main():
         }
 
     guarded("kmeans_predict_bf16", bench_kmeans_predict_bf16)
-
-    # roofline-observatory overhead (ISSUE 14): the SAME kmeans lloyd
-    # kernel with the execution ledger + fenced sampling + watermark
-    # cross-checks armed (HEAT_TPU_PERF_SYNC_EVERY at its default 16)
-    # vs the observatory disarmed — paired per-round median like the
-    # other overhead gates.  Hard cap <3%: the observatory is ON BY
-    # DEFAULT in production, so its per-dispatch tax must be noise.
-    def bench_observatory_overhead():
-        from heat_tpu.telemetry import observatory as obsv
-
-        prev_sync = obsv.set_sync_every(16)
-
-        def fit_observed():
-            obsv.set_enabled(True)
-            return fit()
-
-        def fit_plain():
-            obsv.set_enabled(False)
-            return fit()
-
-        try:
-            fetch = lambda km: float(km.cluster_centers_.sum())
-            overhead_pct, on_per, off_per, sp = _paired_overhead_pct(
-                fit_observed, fit_plain, fetch
-            )
-        finally:
-            obsv.set_enabled(True)
-            obsv.set_sync_every(prev_sync)
-            obsv.reset()
-        results["observatory_overhead"] = {
-            "overhead_pct": round(overhead_pct, 2),
-            "max_overhead_pct": 3.0,
-            "enabled_s": round(on_per, 5),
-            "disabled_s": round(off_per, 5),
-            "spread_pct": sp,
-        }
-
-    guarded("observatory_overhead", bench_observatory_overhead)
-
-    # roofline sanity (ISSUE 14): the calibrated matmul kernel driven
-    # through the dispatch cache with every call fenced must report at
-    # least 20% of this runner's own measured peak — the end-to-end
-    # proof that the ledger's time, the cost join's FLOPs and the
-    # calibration all describe the same machine.  A broken fence (enqueue
-    # time mistaken for device time), a dropped cost join, or a
-    # miscalibrated peak all push the utilization off this floor.
-    def bench_roofline_sanity():
-        from heat_tpu.core import dispatch as disp
-        from heat_tpu.telemetry import observatory as obsv
-
-        obsv.reset_peaks()
-        peaks = obsv.device_peaks(calibrate=True)
-        prev_cost = disp.set_cost_accounting(True)
-        prev_sync = obsv.set_sync_every(1)
-        obsv.reset()
-        try:
-            side = 512
-            buf = jax.device_put(np.ones((side, side), np.float32))
-            for _ in range(12):
-                disp.eager_apply(jnp.matmul, (buf, buf))
-            rows = [
-                r for r in obsv.ledger_report(peaks)
-                if "matmul" in r["key"] and r.get("utilization") is not None
-            ]
-            assert rows, "the matmul must land in the ledger with a cost join"
-            best = max(rows, key=lambda r: r["utilization"])
-            results["roofline_sanity"] = {
-                "value": round(best["utilization"], 4),
-                "min_value": 0.2,
-                "gflops_per_s": best["gflops_per_s"],
-                "peak_gflops": round(peaks["flops"] / 1e9, 1),
-                "bound": best["bound"],
-                "calibration_source": peaks["source"],
-            }
-        finally:
-            disp.set_cost_accounting(prev_cost)
-            obsv.set_sync_every(prev_sync)
-            obsv.reset()
-
-    guarded("roofline_sanity", bench_roofline_sanity)
-
-    # per-kernel roofline floors (ISSUE 16): roofline_sanity generalized
-    # from the calibration matmul to the flagship kernels.  Each
-    # kernel's computational core runs through dispatch.eager_apply with
-    # every call fenced, and the gate is a min_value on the ledger's
-    # utilization (achieved GFLOP/s or GB/s against this runner's own
-    # calibrated peaks and the key's XLA cost model) — a regression in
-    # DELIVERED bandwidth fails CI even when wall-time ratios drift
-    # inside tolerance.  Values above 1.0 are expected for kernels whose
-    # logical cost model overcounts physical traffic (kmeans' fused
-    # distance matrix, spgemm's ELL expansion); the floor is calibrated
-    # per kernel at roughly 0.4x the utilization measured at gate
-    # introduction on this runner, so it trips on structural
-    # regressions (a lost fusion, a dead fast path, a dropped cost
-    # join), not on runner weather.
-    def bench_kernel_floors():
-        import scipy.sparse as sp_m
-
-        from heat_tpu.core import dispatch as disp
-        from heat_tpu.fft import _planar
-        from heat_tpu.sparse import _planes as spl
-        from heat_tpu.telemetry import observatory as obsv
-
-        obsv.reset_peaks()
-        peaks = obsv.device_peaks(calibrate=True)
-        prev_cost = disp.set_cost_accounting(True)
-        prev_sync = obsv.set_sync_every(1)
-        obsv.reset()
-        try:
-            kf = jax.random.PRNGKey(7)
-
-            # named pure-jax kernel cores: the ledger joins rows by the
-            # callable's __name__, so each name below IS the gate key
-            def fftn_leading(xx):
-                fre, fim = _planar.real_fftn(xx, [0, 1, 2], None)
-                return fre + fim
-
-            def kmeans_lloyd(xx, cc):
-                d = (
-                    (xx * xx).sum(1)[:, None]
-                    - 2.0 * xx @ cc.T
-                    + (cc * cc).sum(1)[None, :]
-                )
-                oh = jax.nn.one_hot(jnp.argmin(d, 1), cc.shape[0], dtype=xx.dtype)
-                return (oh.T @ xx) / jnp.maximum(oh.sum(0)[:, None], 1.0)
-
-            def sort_psrs(xx):
-                return jnp.sort(xx)
-
-            def hsvd_leaf(xx):
-                g = jnp.matmul(xx.T, xx, precision=jax.lax.Precision.HIGHEST)
-                _lam, vv = jnp.linalg.eigh(g)
-                return jnp.matmul(
-                    xx, vv[:, ::-1][:, :10], precision=jax.lax.Precision.HIGHEST
-                )
-
-            # the PRODUCTION output-sparse SpGEMM step program (ELL
-            # expand + canonicalize), single-shard instance
-            A = sp_m.random(
-                2048, 2048, density=0.01, random_state=0, format="csr",
-                dtype=np.float32,
-            )
-            sa = ht.sparse.sparse_csr_matrix(A)
-            r_max = spl.max_row_occupancy(
-                sa._comp, sa._nshards, sa._capacity, sa._comp_pad,
-                sa._dist, sa.comm,
-            )
-            step = spl._spgemm_step_prog(
-                sa.comm, 1, sa._capacity, sa._capacity, sa._comp_pad,
-                sa._comp_pad, r_max, "float32", False,
-            )
-
-            def spgemm_ring(ac, ao, av, t):
-                return step(ac, ao, av, ac, ao, av, t)
-
-            drives = {
-                "fftn_leading": (
-                    fftn_leading,
-                    (jax.random.normal(kf, (64, 64, 64), jnp.float32),),
-                ),
-                "kmeans_lloyd": (
-                    kmeans_lloyd,
-                    (jax.random.normal(kf, (1 << 16, 16), jnp.float32),
-                     jax.random.normal(kf, (8, 16), jnp.float32)),
-                ),
-                "sort_psrs": (
-                    sort_psrs,
-                    (jax.random.normal(kf, (1 << 20,), jnp.float32),),
-                ),
-                "hsvd_leaf": (
-                    hsvd_leaf,
-                    (jax.random.normal(kf, (1 << 14, 64), jnp.float32),),
-                ),
-                "spgemm_ring": (
-                    spgemm_ring,
-                    (sa._comp, sa._other, sa._val, jnp.asarray(0, jnp.int32)),
-                ),
-            }
-            # floors sit ~3x under the WORST utilization observed across
-            # calibration runs on this runner class (run-to-run swing is
-            # ~2.5x — the peaks and the kernels calibrate at different
-            # moments of a shared-host job), while a route regression (a
-            # kernel falling off its engine onto a fallback) costs 5-20x:
-            # noise clears the floor, a lost engine does not
-            floors = {
-                "fftn_leading": 0.0012,
-                "kmeans_lloyd": 0.35,
-                "sort_psrs": 0.0007,
-                "hsvd_leaf": 0.07,
-                "spgemm_ring": 0.55,
-            }
-            for _ in range(8):
-                for opf, opargs in drives.items():
-                    disp.eager_apply(opargs[0], opargs[1])
-            rows = obsv.ledger_report(peaks)
-            for name, floor in floors.items():
-                cand = [
-                    r for r in rows
-                    if name in r["key"] and r.get("utilization") is not None
-                ]
-                if not cand:
-                    results[f"kernel_floor_{name}"] = {
-                        "error": "no ledger row with a cost join"
-                    }
-                    continue
-                best = max(cand, key=lambda r: r["utilization"])
-                results[f"kernel_floor_{name}"] = {
-                    "value": round(best["utilization"], 4),
-                    "min_value": floor,
-                    "gflops_per_s": best["gflops_per_s"],
-                    "gbytes_per_s": best["gbytes_per_s"],
-                    "bound": best["bound"],
-                }
-        finally:
-            disp.set_cost_accounting(prev_cost)
-            obsv.set_sync_every(prev_sync)
-            obsv.reset()
-
-    guarded("kernel_floors", bench_kernel_floors)
 
     # sanitized test lane: the threaded test subset (test_overlap /
     # test_introspection / test_telemetry) in a subprocess under

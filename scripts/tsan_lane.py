@@ -13,9 +13,7 @@ tail-store concurrency (``test_tracing.py``), the quality-signal
 layer's SLO tick thread / alert table / sketch registry
 (``test_slo.py``, ``test_drift.py``), the fleet layer's router
 handler/health-poller threads, circuit breakers, AOT-cache config and
-autoscaler tick (``test_fleet.py``), the roofline observatory's
-dispatch-thread ledger vs /rooflinez scrapes plus the /profilez
-capture slot vs its auto-stop timer (``test_observatory.py``), and the
+autoscaler tick (``test_fleet.py``), and the
 streaming layer's segment-log producer/consumer split, refresh-driver
 poll thread and 4-thread live-traffic e2e (``test_streaming.py``,
 ``test_streaming_resume.py``), and the QoS layer's priority-lane
@@ -61,7 +59,6 @@ LANE_FILES = (
     "tests/test_slo.py",
     "tests/test_drift.py",
     "tests/test_fleet.py",
-    "tests/test_observatory.py",
     "tests/test_streaming.py",
     "tests/test_streaming_resume.py",
     "tests/test_qos.py",

@@ -455,8 +455,6 @@ class _FakeCanaryReplica:
                                      "models": ["km"]})
                 elif self.path.startswith("/canaryz"):
                     self._send(200, outer.canary_doc)
-                elif self.path.startswith("/rooflinez"):
-                    self._send(200, {"ledger": [], "ledger_total": 0})
                 else:
                     self._send(404, {"error": "?"})
 

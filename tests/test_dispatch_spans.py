@@ -108,17 +108,26 @@ def test_each_entrance_leaves_one_span_a_program(one_device, entrance, warm):
         assert span.start_ns <= c.start_ns and _end(c) <= _end(span) and c.depth == 1
 
 
-def test_the_span_ends_before_the_observatorys_fence(one_device, monkeypatch):
-    """``_obsv.note`` may block until the output is ready (every sixteenth
-    warm call of a key): the launch's record is in the ring before it runs."""
-    seen = []
-    monkeypatch.setattr(dispatch._obsv, "armed", lambda: True)
-    monkeypatch.setattr(dispatch._obsv, "note", lambda key, dt, out: seen.append(len(_launches())))
-    x = _table()
-    ((x + 1.0) * 2.0).larray_padded  # a miss is not noted
-    telemetry.clear_spans()
-    ((x + 1.0) * 2.0).larray_padded
-    assert seen == [1] and len(_launches()) == 1
+@pytest.mark.parametrize("entrance", sorted(ENTRANCES), ids=[ENTRANCES[e][2] for e in sorted(ENTRANCES)])
+def test_no_entrance_waits_for_the_device(one_device, monkeypatch, entrance):
+    """An entrance returns at its enqueue: over 40 warm launches of one key,
+    neither ``jax.block_until_ready`` nor the array type's own
+    ``block_until_ready`` is called between the entrance and its return, and
+    each launch's record is in the ring when it returns."""
+    operands, go, kind = ENTRANCES[entrance]
+    go(*operands())  # the miss
+    array_type = type(jnp.zeros(1))
+    waits = []
+    for _ in range(40):
+        args = operands()
+        telemetry.clear_spans()
+        with monkeypatch.context() as m:
+            m.setattr(jax, "block_until_ready", lambda *a, **k: waits.append("jax.block_until_ready"))
+            m.setattr(array_type, "block_until_ready", lambda self: waits.append("Array.block_until_ready"))
+            go(*args)
+        (span,) = _launches()
+        assert span.attrs["kind"] == kind and not span.attrs["fresh"]
+    assert waits == []
 
 
 # ------------------------------------------------------------------- a store that waited

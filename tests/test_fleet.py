@@ -54,6 +54,7 @@ class FakeReplica:
         self.die_mid_request = False
         self.delay = float(delay)
         self.served = 0
+        self.gets = []
         outer = self
 
         class _H(BaseHTTPRequestHandler):
@@ -71,6 +72,7 @@ class FakeReplica:
                 self.wfile.write(body)
 
             def do_GET(self):
+                outer.gets.append(self.path.split("?", 1)[0])
                 if self.path == "/readyz":
                     self._send(
                         200 if outer.ready else 503,
@@ -290,6 +292,29 @@ class TestRouterRouting:
         with urllib.request.urlopen(req, timeout=10) as resp:
             assert resp.getcode() == 200
             assert json.load(resp)["predictions"] == [0]
+
+
+class TestSnapshotSweep:
+    def test_sweep_keeps_its_cadence_for_ready_replicas(self, replicas, make_router):
+        """The /canaryz, /tenantz and /decisionz sweep asks ready replicas
+        only, once a period, whether or not they answer."""
+        ready, warming = replicas(), replicas()
+        warming.ready, warming.state = False, "warming"
+        router = make_router()
+        router._stop.set()  # the test polls alone
+        router._poll_thread.join(timeout=5)
+        router.add_replica(ready.url)
+        router.add_replica(warming.url)
+        router.poll_health()  # one sweep
+        router.poll_health()  # inside the period: readiness only
+        snaps = ["/canaryz", "/tenantz", "/decisionz"]
+        assert [p for p in ready.gets if p != "/readyz"] == snaps
+        assert set(warming.gets) == {"/readyz"}
+        with router._lock:
+            router._replicas[ready.url].sweep_ts -= router.health_period_s
+        router.poll_health()
+        assert [p for p in ready.gets if p != "/readyz"] == snaps * 2
+        assert "tenants" in router.fleetz_report()
 
 
 class TestCircuitBreaker:

@@ -138,6 +138,25 @@ class TestEndpoints:
         status, body = _get(live_server, "/")
         assert status == 200 and "/statusz" in body
 
+    def test_root_index_lists_every_server_route(self, live_server):
+        status, body = _get(live_server, "/")
+        assert status == 200
+        for entry in SERVER_ROUTES:
+            assert entry["route"] in body.split(), entry["route"]
+
+    def test_metrics_exposition_hygiene(self, live_server):
+        """/metrics declares OpenMetrics (the payload carries exemplar
+        syntax) and terminates with ``# EOF``."""
+        x = ht.random.randn(64, 4, split=0).astype(ht.float32)
+        float((x * 2.0 + 1.0).sum())
+        with urllib.request.urlopen(f"{live_server.url}/metrics", timeout=10) as r:
+            status, ctype, body = r.status, r.headers["Content-Type"], r.read().decode()
+        assert status == 200
+        assert ctype == "application/openmetrics-text; version=1.0.0; charset=utf-8"
+        assert body.rstrip("\n").endswith("# EOF")
+        # the dispatch layer's gauges ride in the same payload
+        assert "heat_tpu_dispatch_cache_size" in body
+
     def test_start_is_idempotent_and_stop_clears(self):
         a = tserver.start_server(0)
         b = tserver.start_server(0)
@@ -271,6 +290,49 @@ class TestFlightRecorder:
         out = res.stdout.decode()
         assert "PermanentFault" in out and "fit.chunk" in out
         assert "last durable step: 4" in out
+
+    def test_crashed_process_leaves_bundle_and_metrics_dump(self, tmp_path):
+        """A crashed subprocess leaves BOTH a flight-recorder bundle and the
+        CRC-verified ``HEAT_TPU_METRICS_DUMP`` atexit JSON, the latter with
+        its registered sections (the tenant meter's accounts)."""
+        bundles = tmp_path / "bundles"
+        dump = tmp_path / "metrics.json"
+        child = (
+            "import jax\n"
+            "jax.config.update('jax_platforms', 'cpu')\n"
+            "import heat_tpu as ht\n"
+            "from heat_tpu.telemetry import tenants\n"
+            "x = ht.random.randn(64, 4, split=0).astype(ht.float32)\n"
+            "for _ in range(6):\n"
+            "    float((x * 2.0 + 1.0).sum())\n"
+            "from heat_tpu.resilience.errors import PermanentFault\n"
+            "raise PermanentFault('boom')\n"
+        )
+        env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
+        env["HEAT_TPU_FLIGHT_RECORDER"] = str(bundles)
+        env["HEAT_TPU_METRICS_DUMP"] = str(dump)
+        proc = subprocess.run(
+            [sys.executable, "-c", child], env=env, capture_output=True,
+            cwd=REPO_ROOT, timeout=300,
+        )
+        assert proc.returncode != 0
+        assert b"PermanentFault" in proc.stderr
+
+        paths = sorted(bundles.glob("flight_*.json"))
+        assert len(paths) == 1
+        doc = tinspect.load_bundle(str(paths[0]))  # CRC-verified
+        assert BUNDLE_KEYS <= set(doc)
+        assert doc["exception"]["type"] == "PermanentFault"
+        assert doc["dispatch"]["stats"]["hits"] >= 5
+
+        from heat_tpu.resilience.atomic import verify_checksum
+
+        verify_checksum(str(dump))
+        with open(dump) as f:
+            dumped = json.load(f)
+        assert dumped["metrics"]["dispatch.hits"] >= 5
+        assert dumped["tenants"]["total"]["rows"] == 0
 
 
 # ----------------------------------------------------------------------
@@ -484,7 +546,11 @@ def _get_full(srv, route):
 
 class TestAllRoutesScrape:
     def test_route_registry_covers_every_server_route(self):
-        assert len(SERVER_ROUTES) >= 15
+        assert {r["route"] for r in SERVER_ROUTES} == {
+            "/metrics", "/varz", "/healthz", "/readyz", "/trace", "/tracez",
+            "/statusz", "/sloz", "/driftz", "/canaryz", "/tenantz",
+            "/decisionz", "/queryz",
+        }
         assert len({r["route"] for r in tserver.BUILTIN_ROUTES}) == len(
             tserver.BUILTIN_ROUTES
         )

@@ -429,6 +429,16 @@ class TestService:
             for _ in range(3):  # in-quota tenant unaffected
                 svc.predict("km", PTS[:4], tenant="rich")
 
+    def test_service_turns_cost_accounting_on(self):
+        """Tenant metering bills analyzed cost, which a compile records
+        only with cost accounting on: a service turns it on."""
+        prev = dispatch.set_cost_accounting(False)
+        try:
+            with serving.InferenceService():
+                assert dispatch.cost_accounting_enabled()
+        finally:
+            dispatch.set_cost_accounting(prev)
+
     def test_latency_histogram_populated(self, kmeans_dir):
         with serving.InferenceService(max_delay_ms=0.5) as svc:
             svc.load("km", kmeans_dir)
