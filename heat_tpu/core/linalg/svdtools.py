@@ -524,29 +524,52 @@ def rsvd(
     dtype = jnp.float32 if not types.heat_type_is_inexact(A.dtype) else A.dtype.jax_type()
     omega = ht_random.randn(n, ell, dtype=types.canonical_heat_type(dtype), comm=A.comm)._dense()
     k = min(rank, min(ell, m))
-    u_k, s_k, v_k = _rsvd_jit(A._dense(), omega, power_iter, k, str(jnp.dtype(dtype)))
+    u_k, s_k, vt_k = _centered_rsvd(A._dense(), jnp.zeros((n,), dtype), omega, power_iter, k, with_u=True)
     U = DNDarray.from_dense(u_k, A.split if A.split == 0 else None, A.device, A.comm)
     S = DNDarray.from_dense(s_k, None, A.device, A.comm)
-    V = DNDarray.from_dense(v_k, None, A.device, A.comm)
+    V = DNDarray.from_dense(vt_k.T, None, A.device, A.comm)
     return U, S, V
 
 
-@_partial(jax.jit, static_argnames=("power_iter", "k", "dtype_name"))
-def _rsvd_jit(dense, omega, power_iter: int, k: int, dtype_name: str):
-    """The whole randomized factorization (range sampling, power
-    iterations, CholeskyQR2-style orthonormalization, small SVD, rank-k
-    truncation) as one device program — the eager version pays one
-    host dispatch per matmul."""
-    dense = dense.astype(jnp.dtype(dtype_name))
-    omega = omega.astype(dense.dtype)
-    y = jnp.matmul(dense, omega, precision=jax.lax.Precision.HIGHEST)
-    q = _gram_orthonormalize(y)
-    for _ in range(power_iter):
-        z = jnp.matmul(dense.T, q, precision=jax.lax.Precision.HIGHEST)
-        q = _gram_orthonormalize(z)
-        y = jnp.matmul(dense, q, precision=jax.lax.Precision.HIGHEST)
-        q = _gram_orthonormalize(y)
-    b = jnp.matmul(q.T, dense, precision=jax.lax.Precision.HIGHEST)
+@_partial(jax.jit, static_argnames=("power_iter", "k", "with_u"))
+def _centered_rsvd(x, shift, omega, power_iter: int, k: int, real=None, with_u: bool = False):
+    """``(U or None, s, vt)``, the rank-``k`` randomized factorization of
+    ``x - shift`` as ONE device program (range sampling, power iterations,
+    small SVD); ``x`` and ``omega`` are taken in ``shift``'s dtype.
+
+    Each product takes ``x - shift`` (times ``real``, 1 on the rows that
+    count, where the caller hands it) as it reads ``x``: the compiler puts
+    the subtraction into the product's own fusion, so the centring costs
+    neither a read nor a write of the table, and the products see the
+    columns' spreads and not their means (``rsvd`` hands a zero shift).
+    Every product is at ``highest`` and every sketch is orthonormalized by
+    :func:`_gram_orthonormalize`, in ONE loop of ``power_iter + 1`` turns: a
+    turn reads the table twice, ``Y = Xc Q_f`` (``Q_f = omega`` on the first
+    turn) and ``B = Q_n^T Xc`` with ``Q_n`` the basis of ``Y``, and on every
+    turn but the last orthonormalizes ``B^T`` into the next ``Q_f``.  The
+    last turn's ``B``'s small SVD gives ``s`` and ``vt``, and with
+    ``with_u`` ``U = Q_n u_B``, which a caller such as PCA that reads no
+    ``U`` neither computes nor writes: ``2 power_iter + 2`` reads of the
+    table in all, and one loop body whose operations the device trace names
+    once however many turns it runs.  ``B`` is written ``ell``-first, as
+    ``Y`` is: the trace tells the products from the sketch's own passes,
+    which write ``rows x ell``, by their shapes."""
+    hi = jax.lax.Precision.HIGHEST
+    x, omega = x.astype(shift.dtype), omega.astype(shift.dtype)
+
+    def centred():
+        xc = x - shift[None, :]
+        return xc if real is None else xc * real[:, None]
+
+    def turn(i, carry):
+        q_f, _, _ = carry
+        q_n = _gram_orthonormalize(jnp.matmul(q_f.T, centred().T, precision=hi).T)
+        b = jnp.matmul(q_n.T, centred(), precision=hi)
+        q_f = jax.lax.cond(i < power_iter, _gram_orthonormalize, lambda z: z, b.T)
+        return q_f, b, q_n if with_u else None
+
+    q_n = jnp.zeros((x.shape[0], omega.shape[1]), x.dtype) if with_u else None
+    _, b, q_n = jax.lax.fori_loop(0, power_iter + 1, turn, (omega, jnp.zeros_like(omega.T), q_n))
     u_b, s, vt = jnp.linalg.svd(b, full_matrices=False)
-    u = jnp.matmul(q, u_b, precision=jax.lax.Precision.HIGHEST)
-    return u[:, :k], s[:k], vt[:k].T
+    u = jnp.matmul(q_n, u_b[:, :k], precision=hi) if with_u else None
+    return u, s[:k], vt[:k]

@@ -2,18 +2,21 @@
 (pca.py:19-496).
 
 svd_solver options match the reference: 'full' (tall-skinny exact SVD),
-'hierarchical' (hsvd_rank / hsvd_rtol) and 'randomized' (rsvd).
+'hierarchical' (hsvd_rank / hsvd_rtol) and 'randomized' (the steps of rsvd,
+taken about the column means as each product reads the table).
 """
 
 from __future__ import annotations
 
 import sys
+from functools import partial
 from typing import Optional, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import types
+from ..core import dispatch, types
 from ..core.base import BaseEstimator, TransformMixin, lazy_scalar_property, validate_resume_params
 from ..core.dndarray import DNDarray
 from ..core.linalg.svd import svd as _exact_svd
@@ -27,8 +30,43 @@ _STAGE_MEAN = 0
 _STAGE_FITTED = 1
 
 
+@partial(jax.jit, static_argnames=("n", "k", "ell", "power_iter"))
+def _randomized_program(x, mean, var, key, *, n: int, k: int, ell: int, power_iter: int):
+    """The randomized solver after the moments, ONE program: the sketch
+    ``omega`` (``features x ell`` standard normals from ``key``, what
+    ``ht.random.randn`` draws from it), the factorization of ``x - mean``
+    (:func:`svdtools._centered_rsvd`: the centring inside each product's
+    read of ``x``, no ``U``) and everything a user reads of the fit:
+    ``(components, singular values, explained variance, its ratio to the
+    total, which is the sum of ``var``, and the ratios' sum)``.  ``x`` comes
+    as it lies; rows from ``n`` on are padding and count for nothing."""
+    real = None if x.shape[0] == n else (jnp.arange(x.shape[0]) < n).astype(mean.dtype)
+    omega = jax.random.normal(key, (x.shape[1], ell), mean.dtype)
+    _, s, vt = svdtools._centered_rsvd(x, mean, omega, power_iter, k, real)
+    ev = s * s / max(n - 1, 1)
+    ratio = ev / jnp.maximum(jnp.sum(var), 1e-30)
+    return vt, s, ev, ratio, jnp.sum(ratio)
+
+
+def _randomized_fit(x, mean, var, key, **plan):
+    """The randomized solver's one launch; looked up by name where ``fit``
+    calls it."""
+    return _randomized_program(x, mean, var, key, **plan)
+
+
 class PCA(BaseEstimator, TransformMixin):
-    """Linear dimensionality reduction via SVD of centered data (pca.py:19)."""
+    """Linear dimensionality reduction via SVD of centered data (pca.py:19).
+
+    The randomized solver departs from scikit-learn's in two places.
+    ``iterated_power="auto"`` means 0 power iterations here, as in the
+    reference, where scikit-learn's rule takes 7 (``n_components`` under a
+    tenth of the smaller extent) or 4: on data whose spectrum has a gap after
+    the components asked for, 0 leaves the singular values some 1e-3 off and
+    1 leaves them at float32 rounding, so pass ``iterated_power`` where
+    accuracy matters.  And every sketch is orthonormalized by
+    ``svdtools._gram_orthonormalize`` (the eigendecomposition of its Gram,
+    twice, which the chip's matrix unit runs), not by QR:
+    ``power_iteration_normalizer`` is accepted and not used."""
 
     def __init__(
         self,
@@ -123,25 +161,39 @@ class PCA(BaseEstimator, TransformMixin):
     def fit(self, X: DNDarray, y=None) -> "PCA":
         """Estimate principal components (pca.py:210).
 
-        With ``checkpoint_every``/``checkpoint_dir`` set, the two fit
-        stages (column mean, SVD solve) each commit a checkpoint;
-        ``resume_from=dir`` skips every completed stage — a fit killed
-        between the stages resumes with only the solver left, and a
-        fully fitted checkpoint restores without touching the data.
-        The recomputed stages are deterministic functions of X and the
-        restored state, so a resumed fit reproduces the uninterrupted
-        result exactly."""
+        Two stages: the moments (the column means and variances from ONE
+        read of ``X``, :func:`~heat_tpu.core.statistics.mean_var`), then the
+        solver.  The randomized solver is ONE program that makes neither a
+        centred copy of ``X`` nor ``U`` (:func:`_randomized_program`), so a
+        fit reads ``X`` ``2 iterated_power + 3`` times and holds beside it
+        only the ``rows x (n_components + n_oversamples)`` sketches.
+
+        With ``checkpoint_every``/``checkpoint_dir`` set, the two stages
+        each commit a checkpoint; ``resume_from=dir`` skips every completed
+        stage — a fit killed between the stages resumes with only the
+        solver left, and a fully fitted checkpoint restores without
+        touching the data.  The recomputed stages are deterministic
+        functions of X and the restored state, so a resumed fit reproduces
+        the uninterrupted result exactly."""
         if not isinstance(X, DNDarray):
             raise TypeError(f"X must be a DNDarray, got {type(X)}")
         if X.ndim != 2:
             raise ValueError(f"X must be 2D, got {X.ndim}D")
         if y is not None:
             raise ValueError("PCA is an unsupervised transform; y must be None")
+        n, f = X.shape
+        with _span("ht.decomposition.PCA.fit", rows=n, features=f, split=X.split, solver=self.svd_solver,
+                   components=self.n_components) as root:
+            self._fit(X, root)
+        return self
+
+    def _fit(self, X: DNDarray, root) -> None:
+        from ..core import random as ht_random
         from ..core import statistics
         from ..resilience.faults import inject
 
         writer = self._checkpointer(for_write=True)
-        restored_mean = None
+        restored = None
         if self.resume_from is not None:
             reader = self._checkpointer(for_write=False)
             step = reader.latest_step() if reader is not None else None
@@ -149,53 +201,54 @@ class PCA(BaseEstimator, TransformMixin):
                 saved = reader.restore(step)
                 if saved.get("stage") == "fitted":
                     self._restore_fitted(saved, X)
-                    return self
-                restored_mean = saved["mean"]
+                    return
+                restored = saved
+
+        n, f = X.shape
+        k, rtol = self._rank(min(n, f))
+        if self.random_state is not None:
+            ht_random.seed(self.random_state)
+        if self.svd_solver == "randomized":
+            if k is None:
+                raise ValueError("randomized solver requires an integer n_components")
+            # the sketch's key is drawn before the moments: after them the fit is ONE program
+            key = ht_random._next_key()
+            power_iter = 0 if self.iterated_power == "auto" else int(self.iterated_power)
+            # reads of X: the moments' one (unless restored) and the solver's 2 per turn
+            root.attrs.update(passes=(restored is None or "var" not in restored) + 2 * power_iter + 2)
 
         # async stage writes are drained on every exit path, so a
         # caller (or a test) listing the checkpoint directory right
         # after fit() raises/returns sees a deterministic step set
         solver_span = None
         try:
-            n, f = X.shape
-            if restored_mean is None:
-                inject("pca.stage", stage="mean")
-                with _span("pca.stage", stage="mean"):
-                    mean = statistics.mean(X, axis=0)
-                self.mean_ = mean
+            wrap = lambda a: DNDarray.from_dense(a, None, X.device, X.comm)
+            if restored is None:
+                inject("pca.stage", stage="moments")
+                with _span("pca.stage", stage="moments"):
+                    mean, var = statistics.mean_var(X, axis=0, ddof=1)
                 if writer is not None:
-                    # device reference, not a host copy: the snapshot is free and
+                    # device references, not host copies: the snapshot is free and
                     # the device-to-host transfer runs on the writer thread
-                    writer.save(_STAGE_MEAN, {"stage": "mean", "mean": mean._dense()})
+                    writer.save(_STAGE_MEAN, {"stage": "mean", "mean": mean._dense(), "var": var._dense()})
+            elif "var" in restored:
+                mean, var = (wrap(jnp.asarray(restored[key])) for key in ("mean", "var"))
             else:
-                mean = DNDarray.from_dense(jnp.asarray(restored_mean), None, X.device, X.comm)
-                self.mean_ = mean
+                # a checkpoint of an older fit holds the mean alone: the variances take one read
+                mean = wrap(jnp.asarray(restored["mean"]))
+                var = statistics.var(X, axis=0, ddof=1)
+            self.mean_ = mean
             inject("pca.stage", stage="solver")
             # stage heartbeat; closed in the finally so an aborted solve
             # still records its span (and never leaks nesting depth)
             solver_span = _span("pca.stage", stage="solver", solver=self.svd_solver)
             solver_span.__enter__()
-            centered = X - mean
-
-            if self.random_state is not None:
-                from ..core import random as ht_random
-
-                ht_random.seed(self.random_state)
-
-            rank_cap = min(n, f)
-            if isinstance(self.n_components, float):
-                if not 0.0 < self.n_components <= 1.0:
-                    raise ValueError("float n_components must be in (0, 1]")
-                k = None
-                rtol = (1 - self.n_components) ** 0.5
-            else:
-                k = min(self.n_components, rank_cap) if self.n_components else rank_cap
-                rtol = None
 
             if self.svd_solver == "full":
+                centered = X - mean
                 U, S, V = _exact_svd(centered)
                 s = S._dense()
-                kk = k if k is not None else rank_cap
+                kk = k if k is not None else min(n, f)
                 self.components_ = DNDarray.from_dense(V._dense()[:, :kk].T, None, X.device, X.comm)
                 self.singular_values_ = DNDarray.from_dense(s[:kk], None, X.device, X.comm)
                 ev = s**2 / max(n - 1, 1)
@@ -205,6 +258,7 @@ class PCA(BaseEstimator, TransformMixin):
                 self._tevr = jnp.sum(ratio[:kk])
                 self.n_components_ = kk
             elif self.svd_solver == "hierarchical":
+                centered = X - mean
                 if rtol is not None:
                     U, S, V, err = svdtools.hsvd_rtol(centered, rtol=rtol, compute_sv=True)
                 else:
@@ -214,30 +268,25 @@ class PCA(BaseEstimator, TransformMixin):
                 s = S._dense()
                 ev = s**2 / max(n - 1, 1)
                 self.explained_variance_ = DNDarray.from_dense(ev, None, X.device, X.comm)
-                total_var = jnp.sum(centered._dense().astype(jnp.float32) ** 2) / max(n - 1, 1)
-                ratio = ev / jnp.maximum(total_var, 1e-30)
+                ratio = ev / jnp.maximum(jnp.sum(var._dense()), 1e-30)
                 self.explained_variance_ratio_ = DNDarray.from_dense(ratio, None, X.device, X.comm)
                 self._tevr = 1.0 - err**2
                 self.n_components_ = int(s.shape[0])
             else:  # randomized
-                if k is None:
-                    raise ValueError("randomized solver requires an integer n_components")
-                p_iter = 0 if self.iterated_power == "auto" else int(self.iterated_power)
-                U, S, V = svdtools.rsvd(centered, rank=k, n_oversamples=self.n_oversamples, power_iter=p_iter)
-                self.components_ = DNDarray.from_dense(V._dense().T, None, X.device, X.comm)
-                self.singular_values_ = S
-                s = S._dense()
-                ev = s**2 / max(n - 1, 1)
-                self.explained_variance_ = DNDarray.from_dense(ev, None, X.device, X.comm)
-                total_var = jnp.sum(centered._dense().astype(jnp.float32) ** 2) / max(n - 1, 1)
-                self.explained_variance_ratio_ = DNDarray.from_dense(
-                    ev / jnp.maximum(total_var, 1e-30), None, X.device, X.comm
-                )
-                self._tevr = jnp.sum(ev) / jnp.maximum(total_var, 1e-30)
+                # over a mesh the rows as they lie, the padding counted out inside the program
+                x = X.larray_padded if X.split == 0 else X._dense()
+                dispatch.record_external_dispatch()
+                vt, s, ev, ratio, tevr = _randomized_fit(
+                    x, mean._dense(), var._dense(), key, n=n, k=k,
+                    ell=min(k + self.n_oversamples, n, f), power_iter=power_iter)
+                self.components_ = wrap(vt)
+                self.singular_values_ = wrap(s)
+                self.explained_variance_ = wrap(ev)
+                self.explained_variance_ratio_ = wrap(ratio)
+                self._tevr = tevr
                 self.n_components_ = k
             if writer is not None:
                 writer.save(_STAGE_FITTED, self._fitted_payload())
-            return self
         finally:
             if solver_span is not None:
                 solver_span.__exit__(*sys.exc_info())
@@ -249,6 +298,15 @@ class PCA(BaseEstimator, TransformMixin):
                         writer.close()
                     except BaseException:  # lint: allow H501(body exception wins over a writer error)
                         pass
+
+    def _rank(self, rank_cap: int):
+        """``(k, rtol)``: the number of components asked for (None for a
+        float ``n_components``), or the hierarchical solver's tolerance."""
+        if isinstance(self.n_components, float):
+            if not 0.0 < self.n_components <= 1.0:
+                raise ValueError("float n_components must be in (0, 1]")
+            return None, (1 - self.n_components) ** 0.5
+        return (min(self.n_components, rank_cap) if self.n_components else rank_cap), None
 
     def transform(self, X: DNDarray) -> DNDarray:
         """Project onto the principal axes (pca.py:380).

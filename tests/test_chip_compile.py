@@ -824,3 +824,88 @@ def test_lasso_over_four_chips_sums_the_parts(comm4, for_the_chip):
     reduced = [re.sub(r"\{[^}]*\}", "", r) for r in re.findall(r"= (.*?) all-reduce(?:-start)?\(", text)]
     assert reduced and all(str(LASSO_ROWS // 4) not in r for r in reduced), reduced
     assert compiled.memory_analysis().temp_size_in_bytes < 2**26
+
+
+# ---------------------------------------------------------------- the PCA cell
+PCA_ROWS, PCA_COLS, PCA_K, PCA_POWER = 1_281_167, 2048, 256, 4
+#: the sketch, ``f32[1281167,266]``, as the chip lays it out: 266 on the sublanes (272), the rows on the lanes
+PCA_SKETCH_BYTES = 272 * 1_281_280 * 4
+
+
+def test_pca_randomized_fit_at_the_benchmark_cell(topo, for_the_chip, programs_of_the_eager_path, monkeypatch):
+    """The PCA cell: ``PCA(n_components=256, svd_solver="randomized",
+    iterated_power=4).fit`` of a resident 1,281,167 x 2048 float32 table,
+    every program compiled for the described chip.  The moments are ONE
+    program of the dispatch layer (``statistics.mean_var``) that reads the
+    table in one fusion; the solver is ONE program (`pca._randomized_fit`)
+    whose loop of ``iterated_power + 1`` turns reads it twice a turn, in the
+    two products (``X Q``, ``Q^T X``, each one fusion with the centring
+    inside) and nowhere else, and that holds no buffer of the table's size
+    but the table (``X - mean`` made as an array would be a second 10.5 GB
+    table; no ``U`` is made): beside it two sketches.  The reads add up to the root span's
+    ``passes``, which the benchmark's ``pca_table_reads`` reads, and the
+    products' results have the shapes ``pca_products_ms`` finds them by."""
+    import functools
+
+    import heat_tpu as ht
+    from heat_tpu import telemetry
+    from heat_tpu.decomposition import pca
+    from heat_tpu.parallel.comm import Communication
+
+    comm = Communication([topo.devices[0]])
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    solvers = []
+
+    def compile_not_run(*args, **plan):
+        shapes = [_sds(a.shape, a.dtype, one_chip) for a in args]
+        solvers.append((plan, pca._randomized_program.lower(*shapes, **plan).compile()))
+        return jax.eval_shape(functools.partial(pca._randomized_program, **plan), *shapes)
+
+    monkeypatch.setattr(pca, "_randomized_fit", compile_not_run)
+    n, f, ell = PCA_ROWS, PCA_COLS, PCA_K + 10
+    x = _on_shapes(comm, (n, f), 0)
+    was = telemetry.set_tracing(True)
+    telemetry.clear_spans()
+    try:
+        ht.decomposition.PCA(n_components=PCA_K, svd_solver="randomized", iterated_power=PCA_POWER,
+                             n_oversamples=10, random_state=7).fit(x)
+        (root,) = [r for r in telemetry.get_spans() if r.name == "ht.decomposition.PCA.fit"]
+    finally:
+        telemetry.set_tracing(was)
+        telemetry.clear_spans()
+    table = f"f32[{n},{f}]"
+    # the moments: one program, one fusion over the whole table (and eight rows of it for the shift; a loop of
+    # at most one turn would read it again where that shift proved far off the mean)
+    ((kind, donated, moments),) = programs_of_the_eager_path
+    m = moments.memory_analysis()
+    assert not donated and n * f * 4 <= m.argument_size_in_bytes < n * f * 4 + 2**20 and m.temp_size_in_bytes < 2**26
+    instructions = _entry_instructions(moments)
+    (param,) = [i[0] for i in instructions if i[1] == "parameter" and i[2] == table]
+    whole = [i for i in instructions if param in i[3] and i[1] == "fusion" and "[1," not in i[2] and "[8," not in i[2]]
+    assert [i[2] for i in whole] == [f"(f32[{f}], f32[{f}])"], whole
+    # the solver: no table-sized buffer, the two sketches beside the table
+    ((plan, compiled),) = solvers
+    assert plan == {"n": n, "k": PCA_K, "ell": ell, "power_iter": PCA_POWER}
+    m = compiled.memory_analysis()
+    assert n * f * 4 <= m.argument_size_in_bytes < n * f * 4 + 2**16 and m.output_size_in_bytes < 2**22
+    assert 2 * PCA_SKETCH_BYTES <= m.temp_size_in_bytes < 2 * PCA_SKETCH_BYTES + 2**26, m.temp_size_in_bytes
+    assert _device_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    fused = set(re.findall(r"kind=k\w+, calls=%([^ ,]+)", text))
+    entry = _entry_instructions(compiled)
+    computations = {c: _computation_instructions(text, c) for c in set(re.findall(r"^%([^ ]+) \(", text, re.M)) - fused}
+    for name, instructions in [("ENTRY", entry), *computations.items()]:
+        made = [i for i in instructions if i[2] == table and i[1] not in PASSED_ON + ("while",)]
+        assert not made, (name, made)
+    # the reads: none outside the loop, two a turn inside it
+    (param,) = [i[0] for i in entry if i[1] == "parameter" and i[2] == table]
+    assert {i[1] for i in entry if param in i[3]} <= {"tuple", "while"}
+    reads = {}
+    for body, cond in _while_bodies(text).values():
+        if any(i[1] == "parameter" and table in i[2] for i in body):
+            (got,) = [i[0] for i in body if i[1] == "get-tuple-element" and i[2] == table]
+            (turns,) = re.findall(r"constant\((\d+)\)", cond)
+            reads[int(turns)] = sorted((i[1], i[2]) for i in body if got in i[3] and i[1] not in PASSED_ON)
+    assert reads == {PCA_POWER + 1: [("fusion", f"f32[{ell},{n}]"), ("fusion", f"f32[{ell},{f}]")]}, reads
+    # the span's count is the compiled programs': the moments' fusion and the loop's two readers a turn
+    assert root.attrs["passes"] == len(whole) + len(reads[PCA_POWER + 1]) * (PCA_POWER + 1) == 11
